@@ -90,7 +90,7 @@ def _legacy_amp(ops_, x_b, fcw, gcw, assignment, w):
     grad_hat = jax.lax.stop_gradient(
         reconstruct(gcw, assignment, ops_.rev_ids))
     x_b = inject_context_grad_materialized(x_b, ops_.rev_vals, grad_hat, w)
-    m = intra_messages(ops_.in_pos, ops_.in_vals, x_b, ops_.stripe_index)
+    m = intra_messages(ops_.in_pos, ops_.in_vals, x_b)
     return m + context_messages_reconstruct(
         ops_.out_vals, ops_.out_ids, fcw, assignment)
 

@@ -41,7 +41,9 @@ code first, regenerate, then commit the baselines on top.
 Each suite runs in its own subprocess: a single long-lived process
 accumulating hundreds of distinct jit executables eventually trips XLA's
 CPU JIT ("Failed to materialize symbols"); per-suite isolation bounds that
-state and also keeps wall-time numbers independent.
+state and also keeps wall-time numbers independent.  The parent never
+imports jax and must not: on a TPU host the first process to initialise
+the backend holds the chip, and each suite's child then could not reach it.
 """
 from __future__ import annotations
 

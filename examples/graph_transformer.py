@@ -12,6 +12,7 @@ import argparse
 from repro.core.codebook import CodebookConfig
 from repro.graph.datasets import synthetic_arxiv
 from repro.models.gnn import GNNConfig
+from repro import hostenv
 from repro.train.gnn_trainer import train_full, train_vq
 
 
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--n", type=int, default=1200)
     ap.add_argument("--epochs", type=int, default=30)
     args = ap.parse_args()
+    hostenv.enable_compile_cache()
 
     g = synthetic_arxiv(n=args.n)
     cfg = GNNConfig(backbone="transformer", f_in=g.f, hidden=64,

@@ -8,6 +8,7 @@ import argparse
 from repro.core.codebook import CodebookConfig
 from repro.graph.datasets import synthetic_collab
 from repro.models.gnn import GNNConfig
+from repro import hostenv
 from repro.train.gnn_trainer import train_full, train_vq
 
 
@@ -16,6 +17,7 @@ def main():
     ap.add_argument("--n", type=int, default=2000)
     ap.add_argument("--epochs", type=int, default=40)
     args = ap.parse_args()
+    hostenv.enable_compile_cache()
 
     g = synthetic_collab(n=args.n)
     print(f"graph: {g.n} nodes, {g.m} message edges, "

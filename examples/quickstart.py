@@ -8,6 +8,7 @@ import argparse
 from repro.core.codebook import CodebookConfig
 from repro.graph.datasets import synthetic_arxiv
 from repro.models.gnn import GNNConfig
+from repro import hostenv
 from repro.train.gnn_trainer import train_full, train_vq, vq_inference
 
 
@@ -18,6 +19,7 @@ def main():
     ap.add_argument("--backbone", default="gcn",
                     choices=["gcn", "sage", "gat", "gin", "transformer"])
     args = ap.parse_args()
+    hostenv.enable_compile_cache()
 
     g = synthetic_arxiv(n=args.n)
     print(f"graph: {g.n} nodes, {g.m} edges, {g.num_classes} classes")

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro import hostenv
 from repro.models.lm import init_lm, init_serve_cache, serve_step
 
 
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--context", type=int, default=4096,
                     help="pre-allocated context length for the exact cache")
     args = ap.parse_args()
+    hostenv.enable_compile_cache()
 
     base = ArchConfig(name="serve-demo", family="dense", n_layers=4,
                       d_model=128, n_heads=4, n_kv_heads=2, d_ff=512,

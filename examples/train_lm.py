@@ -11,6 +11,7 @@ Default is CPU-sized; pass --preset 100m for the ~100M-parameter run
 import argparse
 
 from repro.configs.base import ArchConfig
+from repro import hostenv
 from repro.train.loop import train
 
 PRESETS = {
@@ -33,6 +34,7 @@ def main():
                     help="enable VQ-Attention (codebook context)")
     ap.add_argument("--ckpt", default="/tmp/repro_lm_ckpt")
     args = ap.parse_args()
+    hostenv.enable_compile_cache()
 
     cfg = PRESETS[args.preset]
     if args.vq:

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.analysis import Finding
 from repro.analysis import registry
@@ -50,12 +51,11 @@ def _sub_jaxprs(eqn):
     subs = []
     for v in eqn.params.values():
         leaves = jax.tree_util.tree_leaves(
-            v, is_leaf=lambda x: isinstance(
-                x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)))
+            v, is_leaf=lambda x: isinstance(x, (Jaxpr, ClosedJaxpr)))
         for leaf in leaves:
-            if isinstance(leaf, jax.core.ClosedJaxpr):
+            if isinstance(leaf, ClosedJaxpr):
                 subs.append(leaf.jaxpr)
-            elif isinstance(leaf, jax.core.Jaxpr):
+            elif isinstance(leaf, Jaxpr):
                 subs.append(leaf)
     return subs
 
@@ -73,6 +73,13 @@ def iter_eqns(jaxpr, in_kernel: bool = False):
 def pallas_calls(closed_jaxpr):
     return [eqn for eqn, ink in iter_eqns(closed_jaxpr.jaxpr)
             if eqn.primitive.name == "pallas_call" and not ink]
+
+
+def kernel_name(eqn) -> str:
+    """A ``pallas_call``'s kernel function name, from the traced body's
+    debug info."""
+    src = eqn.params["jaxpr"].debug_info.func_src_info or "?"
+    return src.split(" ")[0]
 
 
 def _aval_bytes(aval) -> int:
@@ -108,7 +115,7 @@ def check_entry(entry) -> list[Finding]:
     # REPRO101 -- exact dispatch count
     calls = pallas_calls(cj)
     if entry.pallas_count is not None and len(calls) != entry.pallas_count:
-        names = [e.params["name_and_src_info"].name for e in calls]
+        names = [kernel_name(e) for e in calls]
         findings.append(Finding(
             "REPRO101", loc, 0,
             f"expected exactly {entry.pallas_count} pallas_call "

@@ -34,25 +34,36 @@ import jax.numpy as jnp
 
 from repro.analysis import Finding
 from repro.analysis import registry
-from repro.analysis.jaxpr_checks import pallas_calls
+from repro.analysis.jaxpr_checks import kernel_name, pallas_calls
 from repro.distributed.quantization import dtype_nbits
+
+
+def _block_dims(bm) -> tuple:
+    """Block extents as ints (``Blocked(block_size=n)`` -> n; squeezed or
+    unsized dims -> 1)."""
+    dims = []
+    for d in bm.block_shape:
+        d = getattr(d, "block_size", d)
+        dims.append(int(d) if isinstance(d, int) else 1)
+    return tuple(dims)
 
 
 def _block_bytes(bm) -> int:
     total = 1
-    for d in bm.block_shape:
-        total *= int(d) if isinstance(d, int) else 1
+    for d in _block_dims(bm):
+        total *= d
     try:
-        nbits = dtype_nbits(bm.array_shape_dtype.dtype)
+        nbits = dtype_nbits(bm.array_aval.dtype)
     except (KeyError, TypeError):
         return 0
     return (total * nbits + 7) // 8
 
 
 def _is_vmem(bm) -> bool:
-    """Default-space blocks live in VMEM; 'any' means HBM-resident."""
+    """Default-space blocks live in VMEM; 'any' means HBM-resident and
+    'smem' the scalar memory (ids and values that drive row reads)."""
     space = getattr(bm.block_aval, "memory_space", None)
-    return space is None or "any" not in str(space).lower()
+    return space is None or "vmem" in str(space).lower()
 
 
 def _scratch_bytes(eqn) -> int:
@@ -84,10 +95,6 @@ def dispatch_footprint(eqn) -> int:
     return blocks + _scratch_bytes(eqn)
 
 
-def _kernel_name(eqn) -> str:
-    return eqn.params["name_and_src_info"].name
-
-
 def _envelope_bytes(kops) -> int:
     budget = max(
         kops._vmem_budget_mb(kops._dispatch_overrides,
@@ -102,7 +109,7 @@ def check_dispatches(closed_jaxpr, where: str,
     """REPRO201/202 over every pallas_call of one traced jaxpr."""
     findings = []
     for eqn in pallas_calls(closed_jaxpr):
-        name = _kernel_name(eqn)
+        name = kernel_name(eqn)
         fp = dispatch_footprint(eqn)
         if fp > envelope:
             findings.append(Finding(
@@ -112,10 +119,10 @@ def check_dispatches(closed_jaxpr, where: str,
         for bm in eqn.params["grid_mapping"].block_mappings:
             if not _is_vmem(bm):
                 continue
-            arr = bm.array_shape_dtype.shape
-            blk = bm.block_shape
+            arr = bm.array_aval.shape
+            blk = _block_dims(bm)
             for a, b in zip(arr, blk):
-                if isinstance(b, int) and b > 0 and int(a) % b != 0:
+                if b > 0 and int(a) % b != 0:
                     findings.append(Finding(
                         "REPRO202", where, 0,
                         f"'{name}' BlockSpec {tuple(blk)} does not tile "
@@ -159,16 +166,16 @@ def _crossover_findings() -> list[Finding]:
     above = pallas_calls(spmm_probe(n_above, f))
     resident_x = [
         e for e in above
-        if any(_is_vmem(bm) and tuple(bm.block_shape) == (  # whole x in VMEM
-            bm.array_shape_dtype.shape) and
-            bm.array_shape_dtype.shape[0] >= n_above
+        if any(_is_vmem(bm) and _block_dims(bm) == (  # whole x in VMEM
+            tuple(bm.array_aval.shape)) and        # (either orientation)
+            max(bm.array_aval.shape) >= n_above
             for bm in e.params["grid_mapping"].block_mappings)]
     if resident_x:
         findings.append(Finding(
             "REPRO203", "<crossover:spmm_ell>", 0,
             f"above the SpMM crossover ([{n_above}, {f}] f32) the "
             f"dispatcher still VMEM-blocks the whole source matrix "
-            f"({[_kernel_name(e) for e in resident_x]})"))
+            f"({[kernel_name(e) for e in resident_x]})"))
 
     def ctx_probe(n, nb):
         k, fb = 8, 4
@@ -184,21 +191,20 @@ def _crossover_findings() -> list[Finding]:
     n_below = int(cbudget * 0.9) // (nb * 4)
     n_above = int(cbudget * 1.2) // (nb * 4)
     below = pallas_calls(ctx_probe(n_below, nb))
-    if (len(below) != 1 or "context" not in _kernel_name(below[0])
+    if (len(below) != 1 or "context" not in kernel_name(below[0])
             or dispatch_footprint(below[0]) > envelope):
         findings.append(Finding(
             "REPRO203", "<crossover:context_ell>", 0,
             f"below the context crossover ([{nb}, {n_below}] int32) "
             f"expected ONE fused dispatch within the envelope, traced "
-            f"{[(_kernel_name(e), dispatch_footprint(e)) for e in below]}"
+            f"{[(kernel_name(e), dispatch_footprint(e)) for e in below]}"
         ))
     above = pallas_calls(ctx_probe(n_above, nb))
-    if any("context" in _kernel_name(e) for e in above):
+    if any("context" in kernel_name(e) for e in above):
         findings.append(Finding(
             "REPRO203", "<crossover:context_ell>", 0,
             f"above the context crossover ([{nb}, {n_above}] int32) the "
-            f"fused kernel (whole assignment table VMEM-resident) is "
-            f"still dispatched"))
+            f"fused kernel is still dispatched"))
     return findings
 
 

@@ -16,12 +16,10 @@ serve step (the latter two across all five precision tiers).  Each
     the entry must bump exactly once per trace.
 
 Kernel-forcing note: on CPU the dispatchers route to the jnp oracles, so
-the inference-side entries trace under ``REPRO_FORCE_PALLAS=1`` (set
-host-side around the trace; ``repro.hostenv`` snapshots it).  The
-TRAINING entries trace on the oracle path instead -- reverse-mode AD
-through the interpret-mode SpMM kernel has no transpose rule (the same
-reason the gradient tests skip under forced kernels), and their contracts
-(donation, scan carry, callback freedom) are dispatch-independent.
+every entry traces under ``REPRO_FORCE_PALLAS=1`` (set host-side around
+the trace; ``repro.hostenv`` snapshots it).  The training entries
+differentiate through the kernel path: the SpMM and context dispatches
+carry custom VJPs whose backward is the oracle's VJP (kernels/ops.py).
 
 Traced jaxprs are cached per entry so the jaxpr pass and the VMEM pass
 share one trace.
@@ -183,6 +181,13 @@ PALLAS_COUNTS = {
     # assignment-refresh kernel.  Serve = the same two per layer x 2.
     "vq_infer_layer": 2,
     "vq_serve_batch": 4,
+    # per layer of a training step: the intra SpMM and the context forward,
+    # the Eq. 7 context backward (the injection's custom VJP runs the
+    # kernel), and the fused assign+stats update; the SpMM backward is
+    # the oracle's XLA VJP.  2 layers.
+    "train_step": 8,
+    # per layer: the full-(sub)graph SpMM forward; its backward is XLA
+    "sampler_step": 2,
 }
 
 
@@ -252,6 +257,7 @@ def _train_entries() -> list[Entry]:
             vq_train_epoch,
             lambda *a: vq_train_epoch(*a, cfg, opt), *eargs),
         lower=lambda: vq_train_epoch.lower(*eargs, cfg, opt),
+        force_pallas=True, pallas_count=PALLAS_COUNTS["train_step"],
         donated_min=1, carry_budget=budget)]
 
     # sampler baseline: S batches of P=16 padded subgraph rows, deg cap 8
@@ -269,6 +275,7 @@ def _train_entries() -> list[Entry]:
             sampler_train_epoch,
             lambda *a: sampler_train_epoch(*a, cfg, opt), *sargs),
         lower=lambda: sampler_train_epoch.lower(*sargs, cfg, opt),
+        force_pallas=True, pallas_count=PALLAS_COUNTS["sampler_step"],
         donated_min=1,
         carry_budget=tree_bytes((s["params"], s["ost"])) + 4096))
 
@@ -285,6 +292,7 @@ def _train_entries() -> list[Entry]:
                                          opt=opt), *eargs),
             lower=lambda: _dp_epoch_jit.lower(*eargs, mesh=mesh, cfg=cfg,
                                               opt=opt),
+            force_pallas=True, pallas_count=PALLAS_COUNTS["train_step"],
             donated_min=1, carry_budget=budget))
         entries.append(Entry(
             name="sharded_epoch",
@@ -295,6 +303,7 @@ def _train_entries() -> list[Entry]:
                                               compress=False), *eargs),
             lower=lambda: _sharded_epoch_jit.lower(
                 *eargs, mesh=mesh, cfg=cfg, opt=opt, compress=False),
+            force_pallas=True, pallas_count=PALLAS_COUNTS["train_step"],
             donated_min=1, carry_budget=budget))
     return entries
 

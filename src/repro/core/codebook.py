@@ -124,7 +124,11 @@ def init_codebook(key: jax.Array, f_feat: int, f_grad: int,
                   cfg: CodebookConfig) -> CodebookState:
     n_branches, fb, gb = branch_layout(f_feat, f_grad, cfg.f_prod)
     f_blk = fb + gb
+    # gradient halves start at zero: before any gradient is observed the
+    # Eq. 7 injection must add nothing, and per-node loss gradients (~1/b)
+    # sit orders of magnitude below a random init's scale
     cw = 0.02 * jax.random.normal(key, (n_branches, cfg.k, f_blk), jnp.float32)
+    cw = cw.at[:, :, fb:].set(0.0)
     return CodebookState(
         codewords_w=cw,
         cluster_size=jnp.ones((n_branches, cfg.k), jnp.float32),

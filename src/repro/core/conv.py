@@ -21,7 +21,6 @@ from repro.core.codebook import CodebookState, CodebookConfig
 from repro.core.message_passing import ConvOperands
 from repro.distributed.quantization import PackedAssignment, QTensor
 from repro.kernels import ops as kops
-from repro.kernels.spmm_ell_hbm import StripeIndex
 
 
 class MinibatchPack(NamedTuple):
@@ -32,9 +31,6 @@ class MinibatchPack(NamedTuple):
     ``rev_*`` are the out-edges (messages FROM batch nodes -- the "blue"
     backward messages of Fig. 2).  Positions are the index inside the batch
     if the other endpoint is also in the batch, else -1.
-    ``stripe_index`` (optional, built by the packer) is the tile->stripes
-    scalar-prefetch metadata for the intra-batch term's HBM SpMM variant,
-    used when b * f exceeds the VMEM envelope (DESIGN.md section 3).
     ``slot_mask`` (optional, [b]) is 0 on the wrap-padded slots of a tail
     batch -- those rows are real (wrapped) nodes whose messages stay valid,
     but the loss must skip them (DESIGN.md section 9).
@@ -46,7 +42,6 @@ class MinibatchPack(NamedTuple):
     rev_ids: jax.Array     # [b, Dr]  out-edge target global ids
     rev_mask: jax.Array    # [b, Dr]
     rev_pos: jax.Array     # [b, Dr]
-    stripe_index: Optional[StripeIndex] = None
     slot_mask: Optional[jax.Array] = None
 
     @property
@@ -250,8 +245,7 @@ def fixed_conv_operands(kind: str, pack: MinibatchPack,
     ops_ = ConvOperands(
         in_pos=pack.nbr_pos, in_vals=in_vals,
         out_ids=pack.nbr_ids, out_vals=out_vals,
-        rev_ids=pack.rev_ids, rev_vals=rev_vals,
-        stripe_index=pack.stripe_index)
+        rev_ids=pack.rev_ids, rev_vals=rev_vals)
     return ops_, self_vals
 
 

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 
 from repro.distributed.quantization import PackedAssignment
 from repro.kernels import ops as kops
-from repro.kernels.spmm_ell_hbm import StripeIndex
 
 
 # ---------------------------------------------------------------------------
@@ -225,19 +224,16 @@ def context_messages_sketch(c_out_sketch: jax.Array,
 # ---------------------------------------------------------------------------
 
 def intra_messages(in_pos: jax.Array, in_vals: jax.Array,
-                   x_b: jax.Array,
-                   stripe_index: Optional[StripeIndex] = None) -> jax.Array:
+                   x_b: jax.Array) -> jax.Array:
     """Exact intra-mini-batch messages  C_in X_B.
 
     in_pos:  [b, D] int32 -- neighbor position inside the batch (-1 padding /
              out-of-batch; those slots must carry in_vals == 0)
     in_vals: [b, D]
     x_b:     [b, f]
-    stripe_index: pack-time tile->stripes metadata for the HBM SpMM variant
-             (inference-scale batches where b * f exceeds VMEM)
     """
     idx = jnp.maximum(in_pos, 0)
-    return kops.spmm_ell(idx, in_vals, x_b, stripe_index)
+    return kops.spmm_ell(idx, in_vals, x_b)
 
 
 # ---------------------------------------------------------------------------
@@ -255,7 +251,6 @@ class ConvOperands(NamedTuple):
     out_vals: jax.Array    # [b, D]   C_out values (0 on padding)
     rev_ids: jax.Array     # [b, Dr]  reverse-edge (batch -> out) target ids
     rev_vals: jax.Array    # [b, Dr]  C^T_out values (0 on padding)
-    stripe_index: Optional[StripeIndex] = None  # intra-term HBM metadata
 
 
 def approx_message_passing(ops_: ConvOperands, x_b: jax.Array,
@@ -277,7 +272,7 @@ def approx_message_passing(ops_: ConvOperands, x_b: jax.Array,
         x_b = inject_context_grad(
             x_b, ops_.rev_vals, ops_.rev_ids,
             jax.lax.stop_gradient(grad_codewords), assignment, w)
-    m = intra_messages(ops_.in_pos, ops_.in_vals, x_b, ops_.stripe_index)
+    m = intra_messages(ops_.in_pos, ops_.in_vals, x_b)
     m = m + context_messages_reconstruct(
         ops_.out_vals, ops_.out_ids, feat_codewords, assignment)
     return m

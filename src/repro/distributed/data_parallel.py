@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed import sharding as shd
@@ -72,7 +72,7 @@ def _dp_epoch_jit(params, vq_states, opt_state, plan, perm, slot_mask,
         in_specs=(P(), P(), P(), P(), epoch_batch_spec(),
                   epoch_batch_spec(), P(), P(), P(), P()),
         out_specs=(P(), P(), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
     return sharded(params, vq_states, opt_state, plan, perm, slot_mask,
                    x, labels, train_mask, degrees)
 
@@ -176,7 +176,7 @@ def _sharded_epoch_jit(params, vq_states, opt_state, plan, perm, slot_mask,
         in_specs=(P(), P(), P(), rows, epoch_batch_spec(),
                   epoch_batch_spec(), rows, rows, rows, P()),
         out_specs=(P(), P(), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
     return sharded(params, vq_states, opt_state, plan, perm, slot_mask,
                    x, labels, train_mask, degrees)
 
@@ -225,7 +225,7 @@ def _sharded_infer_layer_jit(params_l, vq_state, plan, perm, slot_mask,
         body, mesh=mesh,
         in_specs=(P(), P(), rows, scan, scan, rows, P()),
         out_specs=(rows, P()),
-        check_rep=False)
+        check_vma=False)
     return sharded(params_l, vq_state, plan, perm, slot_mask, acts,
                    degrees)
 
@@ -263,7 +263,7 @@ def _sharded_serve_jit(params, vq_states, plan, bids, x, degrees, *,
         body, mesh=mesh,
         in_specs=(P(), P(), rows, P(), rows, P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return sharded(params, vq_states, plan, bids, x, degrees)
 
 

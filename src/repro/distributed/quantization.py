@@ -230,9 +230,8 @@ class PackedAssignment:
     ([n_branches, ceil(n/2)] uint8) -- 0.5 bytes/entry, 8x smaller than
     the int32 table and half the uint8 one, which is what doubles the
     fused-dispatch VMEM crossover again (DESIGN.md section 15).  The node
-    count ``n`` is static aux data (the pytree idiom of
-    ``spmm_ell_hbm.StripeIndex``), so the wrapper flows through jit /
-    scan / shard_map like any array leaf.
+    count ``n`` is static pytree aux data, so the wrapper flows through
+    jit / scan / shard_map like any array leaf.
     """
 
     def __init__(self, packed: jax.Array, n: int):

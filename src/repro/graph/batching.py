@@ -21,7 +21,6 @@ import jax.numpy as jnp
 
 from repro.core.conv import MinibatchPack
 from repro.graph.structure import CSR, Graph
-from repro.kernels.spmm_ell_hbm import StripeIndex, clamp_tiles
 
 
 def _pack_rows(csr: CSR, ids: np.ndarray, deg_cap: int,
@@ -51,82 +50,23 @@ def _pack_rows(csr: CSR, ids: np.ndarray, deg_cap: int,
     return nbr, mask, pos
 
 
-def make_stripe_index(nbr_idx: np.ndarray, n_src: int, *,
-                      mask: np.ndarray | None = None,
-                      bb: int = 128, stripe: int = 512,
-                      max_stripes: int | None = None) -> StripeIndex:
-    """Host-side tile->stripes metadata for the HBM SpMM kernel.
-
-    Built at batch-pack time so the scalar-prefetch operands ride along
-    with the pack instead of being recomputed in-jit every step.  ``mask``
-    marks real (non-padding) neighbor slots; padding slots touch no stripe.
-    Mirrors the kernel's tile clamping (``clamp_tiles``) so the index is
-    valid for ``spmm_ell_hbm_pallas`` on a [len(nbr_idx), n_src-row] call.
-
-    The ids width is shape-derived -- min(n_stripes, bb * deg) -- NOT the
-    batch's observed maximum, so successive packs of the same dataset keep
-    identical shapes and jit'd steps never retrace.  ``max_stripes`` caps
-    it tighter (e.g. a measured dataset locality bound, keeping the
-    scalar-prefetch operand small on huge graphs); a batch exceeding the
-    cap raises rather than silently dropping stripes.
-    """
-    nbr_idx = np.asarray(nbr_idx)
-    b, deg = nbr_idx.shape
-    bb, stripe = clamp_tiles(b, n_src, bb, stripe)
-    bp = (b + bb - 1) // bb * bb
-    nt = bp // bb
-    n_stripes = (n_src + stripe - 1) // stripe
-    sid = np.zeros((bp, deg), np.int64)
-    valid = np.zeros((bp, deg), bool)
-    sid[:b] = np.clip(nbr_idx, 0, None) // stripe
-    valid[:b] = np.ones((b, deg), bool) if mask is None \
-        else np.asarray(mask) != 0
-    sid, valid = sid.reshape(nt, bb * deg), valid.reshape(nt, bb * deg)
-    per_tile = [np.unique(sid[t][valid[t]]) for t in range(nt)]
-    ms = max_stripes if max_stripes is not None \
-        else max(1, min(n_stripes, bb * deg))
-    worst = max((len(u) for u in per_tile), default=0)
-    if worst > ms:
-        raise ValueError(
-            f"a row tile touches {worst} stripes > max_stripes={ms}; "
-            f"raise the cap or the stripe size")
-    ids = np.zeros((nt, ms), np.int32)
-    counts = np.zeros((nt,), np.int32)
-    for t, u in enumerate(per_tile):
-        ids[t, :len(u)] = u
-        counts[t] = len(u)
-    return StripeIndex(jnp.asarray(ids), jnp.asarray(counts),
-                       bb=bb, stripe=stripe, n_src=n_src)
-
-
 def make_pack(g: Graph, batch_ids: np.ndarray, deg_cap: int | None = None,
-              *, stripe_index: bool = False, stripe_bb: int = 128,
-              stripe: int = 512,
-              slot_mask: np.ndarray | None = None) -> MinibatchPack:
-    """Pack a mini-batch; with ``stripe_index=True`` also emit the
-    tile->stripes metadata the HBM SpMM kernel's scalar prefetch needs for
-    the intra-batch term (source rows = batch positions).  ``slot_mask``
-    (optional, [b]) marks padding slots of a wrap-padded tail batch with 0
-    so the loss skips them (:func:`epoch_slices`)."""
+              *, slot_mask: np.ndarray | None = None) -> MinibatchPack:
+    """Pack a mini-batch.  ``slot_mask`` (optional, [b]) marks padding
+    slots of a wrap-padded tail batch with 0 so the loss skips them
+    (:func:`epoch_slices`)."""
     deg_cap = deg_cap or g.max_degree()
     inv = np.full(g.n, -1, np.int32)
-    inv[batch_ids] = np.arange(len(batch_ids), dtype=np.int32)
+    # reversed writes: a duplicated id keeps its FIRST slot (plan_batch)
+    inv[batch_ids[::-1]] = np.arange(len(batch_ids), dtype=np.int32)[::-1]
     nbr, nmask, npos = _pack_rows(g.in_csr, batch_ids, deg_cap, inv)
     rev, rmask, rpos = _pack_rows(g.out_csr, batch_ids, deg_cap, inv)
-    sidx: Optional[StripeIndex] = None
-    if stripe_index:
-        # intra-term gather source is x_b: indices are in-batch positions,
-        # valid only where the neighbor is itself in the batch
-        sidx = make_stripe_index(np.maximum(npos, 0), len(batch_ids),
-                                 mask=(npos >= 0) & (nmask != 0),
-                                 bb=stripe_bb, stripe=stripe)
     return MinibatchPack(
         batch_ids=jnp.asarray(batch_ids.astype(np.int32)),
         nbr_ids=jnp.asarray(nbr), nbr_mask=jnp.asarray(nmask),
         nbr_pos=jnp.asarray(npos),
         rev_ids=jnp.asarray(rev), rev_mask=jnp.asarray(rmask),
-        rev_pos=jnp.asarray(rpos), stripe_index=sidx,
-        slot_mask=None if slot_mask is None
+        rev_pos=jnp.asarray(rpos), slot_mask=None if slot_mask is None
         else jnp.asarray(slot_mask.astype(np.float32)))
 
 
@@ -135,27 +75,20 @@ class FullGraphOperands(NamedTuple):
 
     Used by the full-graph oracle, the sampling baselines (on their sampled
     subgraphs) and the inference path.  NamedTuple -> a jit-able pytree.
-    ``stripe_index`` (optional) carries the tile->stripes metadata that
-    routes the [n, f] feature matrix through the HBM SpMM variant when it
-    exceeds the VMEM envelope (DESIGN.md section 3).
     """
     nbr_ids: jnp.ndarray    # [n, D]
     nbr_mask: jnp.ndarray   # [n, D]
     degrees: jnp.ndarray    # [n]
-    stripe_index: Optional[StripeIndex] = None
 
 
-def full_operands(g: Graph, deg_cap: int | None = None, *,
-                  stripe_index: bool = False, stripe_bb: int = 128,
-                  stripe: int = 512) -> FullGraphOperands:
+def full_operands(g: Graph, deg_cap: int | None = None
+                  ) -> FullGraphOperands:
     deg_cap = deg_cap or g.max_degree()
     ids = np.arange(g.n)
     nbr, mask, _ = _pack_rows(g.in_csr, ids, deg_cap)
-    sidx = make_stripe_index(nbr, g.n, mask=mask, bb=stripe_bb,
-                             stripe=stripe) if stripe_index else None
     return FullGraphOperands(
         nbr_ids=jnp.asarray(nbr), nbr_mask=jnp.asarray(mask),
-        degrees=jnp.asarray(g.degrees()), stripe_index=sidx)
+        degrees=jnp.asarray(g.degrees()))
 
 
 def subgraph_operands(src: np.ndarray, dst: np.ndarray, n_sub: int,
@@ -324,12 +257,15 @@ def build_epoch_plan(g: Graph, deg_cap: int | None = None, *,
 def plan_batch(plan: EpochPlan, batch_ids: jnp.ndarray,
                slot_mask: Optional[jnp.ndarray] = None) -> MinibatchPack:
     """In-jit MinibatchPack for one batch of a permutation (node->slot
-    scatter + row gather; bit-identical to ``make_pack`` on the same ids,
-    minus the host-only stripe-index option)."""
+    scatter + row gather; bit-identical to ``make_pack`` on the same
+    ids).  A duplicated id (serve requests) points at its first slot, as
+    in :func:`_inbatch_positions`: the slot fixes where a message enters
+    the kernels' one-hot sums, so every path must pick the same one."""
     b = batch_ids.shape[0]
     batch_ids = batch_ids.astype(jnp.int32)
-    slot = jnp.full((plan.n,), -1, jnp.int32).at[batch_ids].set(
+    slot = jnp.full((plan.n,), b, jnp.int32).at[batch_ids].min(
         jnp.arange(b, dtype=jnp.int32))
+    slot = jnp.where(slot == b, -1, slot)
     nbr = plan.nbr_ids[batch_ids]
     nmask = plan.nbr_mask[batch_ids]
     rev = plan.rev_ids[batch_ids]
@@ -338,8 +274,7 @@ def plan_batch(plan: EpochPlan, batch_ids: jnp.ndarray,
     rpos = jnp.where(rmask != 0, slot[rev], -1).astype(jnp.int32)
     return MinibatchPack(
         batch_ids=batch_ids, nbr_ids=nbr, nbr_mask=nmask, nbr_pos=npos,
-        rev_ids=rev, rev_mask=rmask, rev_pos=rpos,
-        stripe_index=None, slot_mask=slot_mask)
+        rev_ids=rev, rev_mask=rmask, rev_pos=rpos, slot_mask=slot_mask)
 
 
 def _inbatch_positions(batch_ids: jnp.ndarray, ids: jnp.ndarray,
@@ -348,10 +283,9 @@ def _inbatch_positions(batch_ids: jnp.ndarray, ids: jnp.ndarray,
     argsort+searchsorted over the b batch ids instead of ``plan_batch``'s
     O(n) node->slot scatter.  The sharded executor uses this because a
     transient [n] slot array would reintroduce the per-device O(n) memory
-    the row sharding just removed.  For distinct batch ids the result is
-    identical to the scatter; for duplicate ids (serve path) it picks one
-    authoritative slot, which references the same feature row -- the
-    downstream gathers are value-identical either way."""
+    the row sharding just removed.  Identical to the scatter, duplicate
+    ids included: the stable argsort puts a duplicated id's slots in
+    order, so ``searchsorted`` finds its first slot."""
     b = batch_ids.shape[0]
     order = jnp.argsort(batch_ids)
     sb = batch_ids[order]
@@ -388,8 +322,7 @@ def plan_batch_sharded(plan: EpochPlan, batch_ids: jnp.ndarray,
     rpos = _inbatch_positions(batch_ids, rev, rmask)
     return MinibatchPack(
         batch_ids=batch_ids, nbr_ids=nbr, nbr_mask=nmask, nbr_pos=npos,
-        rev_ids=rev, rev_mask=rmask, rev_pos=rpos,
-        stripe_index=None, slot_mask=slot_mask)
+        rev_ids=rev, rev_mask=rmask, rev_pos=rpos, slot_mask=slot_mask)
 
 
 # ---------------------------------------------------------------------------

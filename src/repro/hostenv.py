@@ -10,7 +10,7 @@ effect on the cached executable but WOULD take effect on the next new
 shape, leaving one epoch running a mix of regimes.
 
 :func:`env_knob` therefore reads ``os.environ`` only while no trace is
-active (``jax.core.trace_state_clean()``): host-side calls -- tests
+active (``jax._src.core.trace_state_clean()``): host-side calls -- tests
 monkeypatching ``REPRO_SPMM_VARIANT``, the trainer choosing an executor,
 an eager kernel call -- always see the live environment, while calls made
 during jit tracing reuse the most recent host-side snapshot.  The one
@@ -28,10 +28,7 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-try:  # public since jax 0.4.x
-    from jax.core import trace_state_clean as _trace_state_clean
-except ImportError:  # pragma: no cover - older/newer layout
-    from jax._src.core import trace_state_clean as _trace_state_clean
+from jax._src.core import trace_state_clean as _trace_state_clean
 
 # name -> raw value (None records "unset"); refreshed on every host-side
 # read, frozen while a trace is active
@@ -64,3 +61,30 @@ def env_knob_set(name: str) -> bool:
 def reset_env_snapshot() -> None:
     """Drop every snapshotted knob (tests; forces fresh host-side reads)."""
     _snapshot.clear()
+
+
+# Fixed in-checkout home of JAX's persistent compilation cache when the
+# environment does not place it (the path is part of the cache key, so it
+# never comes from a temporary name, a pid or the time).  Listed in
+# .gitignore.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing else is configured; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Entry points (``chip_smoke.py``, the
+    ``main()`` of ``repro.launch.serve_gnn`` and of the examples) call
+    this; importing a module never does.
+    """
+    import jax
+
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR

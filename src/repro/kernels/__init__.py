@@ -6,7 +6,7 @@ ref.py.  Kernels: vq_assign (fused distance+argmin), vq_update (fused
 assign + cluster counts/sums + per-row quantization error -- the one-pass
 streaming codebook update, no one-hot intermediate), spmm_ell (ELLPACK
 message passing, VMEM-resident source), spmm_ell_hbm (ELLPACK message
-passing, HBM-resident source with double-buffered stripe DMA),
+passing, HBM-resident source gathered by row DMAs),
 context_ell (one-pass multi-branch VQ-context SpMM -- Eq. 6 context
 forward and streaming Eq. 7 backward, codebook VMEM-resident),
 flash_attention (training attention), vq_attention (codebook + window

@@ -121,22 +121,14 @@ def _time(fn, *args) -> float:
     return best
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # per-kernel tuners (ops.py consumers)
 # ---------------------------------------------------------------------------
 
 def tuned_spmm(n_src: int, f: int, itemsize: int = 4, dtype=None
                ) -> Optional[dict[str, Any]]:
-    """{'variant': 'resident'|'hbm', 'bb': int, 'stripe': int} for a
-    [n_src, f] source matrix of ``itemsize``-byte elements, or None when
-    autotuning is off.  ``stripe`` (the HBM variant's DMA granule) is
-    measured alongside bb under the same cache entry; the resident
-    variant ignores it, and a caller's precomputed ``StripeIndex`` still
-    pins both (tuner config never overrides an explicit tiling).
+    """{'variant': 'resident'|'hbm', 'bb': int} for a [n_src, f] source
+    matrix of ``itemsize``-byte elements, or None when autotuning is off.
 
     ``dtype`` is the storage dtype of the source rows and keys the cache
     entry -- int8 and float8_e4m3fn share itemsize 1 but are distinct
@@ -153,6 +145,7 @@ def tuned_spmm(n_src: int, f: int, itemsize: int = 4, dtype=None
 
     from repro.kernels.spmm_ell import spmm_ell_pallas
     from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
+    from repro.kernels import ops
     b, deg = min(_ROW_CLAMP, 256), 16
     ns = min(int(n_src), _SRC_CLAMP)
     fm = min(int(f), 128)
@@ -161,19 +154,19 @@ def tuned_spmm(n_src: int, f: int, itemsize: int = 4, dtype=None
     idx = jax.random.randint(ki, (b, deg), 0, ns, jnp.int32)
     val = jax.random.uniform(kv, (b, deg), jnp.float32)
     x = jax.random.normal(kx, (ns, fm), jnp.float32)
-    interp = _interpret()
+    interp = ops.interpret_mode()
 
-    timings: dict[tuple[str, int, int], float] = {}
-    for bb in (64, 128, 256):
-        timings[("resident", bb, 512)] = _time(
+    timings: dict[tuple[str, int], float] = {}
+    for bb in (128, 256):     # resident rows ride the lanes: multiples of 128
+        timings[("resident", bb)] = _time(
             lambda i, v, s, _bb=bb: spmm_ell_pallas(
                 i, v, s, bb=_bb, interpret=interp), idx, val, x)
-    for stripe in (256, 512, 1024):
-        timings[("hbm", 128, stripe)] = _time(
-            lambda i, v, s, _st=stripe: spmm_ell_hbm_pallas(
-                i, v, s, None, stripe=_st, interpret=interp), idx, val, x)
-    (variant, bb, stripe), _ = min(timings.items(), key=lambda kv_: kv_[1])
-    cfg = {"variant": variant, "bb": int(bb), "stripe": int(stripe)}
+    for bb in (64, 128, 256):
+        timings[("hbm", bb)] = _time(
+            lambda i, v, s, _bb=bb: spmm_ell_hbm_pallas(
+                i, v, s, bb=_bb, interpret=interp), idx, val, x)
+    (variant, bb), _ = min(timings.items(), key=lambda kv_: kv_[1])
+    cfg = {"variant": variant, "bb": int(bb)}
     record(key, cfg)
     return cfg
 
@@ -202,6 +195,7 @@ def tuned_context(n_nodes: int, n_branches: int, itemsize: float = 4,
 
     from repro.distributed.quantization import PackedAssignment
     from repro.kernels.context_ell import context_ell_pallas
+    from repro.kernels import ops
     from repro.kernels.spmm_ell import spmm_ell_pallas
     b, deg, f_blk = min(_ROW_CLAMP, 256), 16, 8
     k = 16 if packed else 64
@@ -218,7 +212,7 @@ def tuned_context(n_nodes: int, n_branches: int, itemsize: float = 4,
     else:
         fused_a = loop_a = assign.astype(dtype)
     cw = jax.random.normal(kc, (nb, k, f_blk), jnp.float32)
-    interp = _interpret()
+    interp = ops.interpret_mode()
 
     def loop(i, v, a, c):
         # the per-branch fallback, built on the kernel directly (module doc)
@@ -250,13 +244,14 @@ def tuned_vq_update(b: int, k: int, f: int) -> Optional[dict[str, Any]]:
         return hit
 
     from repro.kernels.vq_update import vq_assign_update_pallas
+    from repro.kernels import ops
     bm = min(int(b), _ROW_CLAMP)
     km, fm = min(int(k), 512), min(int(f), 128)
     rng = jax.random.PRNGKey(0)
     kx, kc = jax.random.split(rng)
     x = jax.random.normal(kx, (bm, fm), jnp.float32)
     cw = jax.random.normal(kc, (km, fm), jnp.float32)
-    interp = _interpret()
+    interp = ops.interpret_mode()
 
     timings: dict[tuple[int, int], float] = {}
     for bb in (128, 256):

@@ -8,20 +8,21 @@ messages:
 
 The paper's scaling argument (Sec. 3.3) is that this term only ever touches
 a [k, f_blk] codeword table per branch -- O(k * f) state, independent of
-graph size.  The pre-fusion implementation still paid per-branch costs the
-math does not require: a materialized ``[n_branches, b, D]`` gathered-
-assignment tensor plus one SpMM kernel launch per branch plus a concat.
-This kernel performs the whole computation in ONE ``(b/bb,)`` grid pass:
+graph size.  The per-branch form (``ops._context_ell_loop``) pays one SpMM
+kernel launch per branch plus a concat; this kernel performs the whole
+computation in ONE grid pass over row tiles:
 
-  * all branches' codeword tables live VMEM-resident as a single flat
-    ``[n_branches * k, f_blk]`` matrix (k * f is tiny by construction --
-    the point of VQ);
-  * the assignment table rides along as ``[n, n_branches]`` (transposed so
-    a neighbor id selects one contiguous row holding all its branch ids);
-  * the inner loop over the D neighbor slots fuses assignment gather ->
-    flat codeword gather -> weighted accumulate, emitting the
-    branch-concatenated ``[bb, n_branches * f_blk]`` rows directly -- no
-    per-branch intermediate ever exists.
+  * the per-branch assignment gather ``R^beta[ids]`` runs in XLA ahead of
+    the kernel (SMEM cannot hold an [n, n_branches] table at graph scale,
+    and Mosaic cannot gather VMEM with a vector of ids) and arrives
+    transposed, ``[n_branches * D, b]``, so batch rows ride the lanes;
+  * all branches' codeword tables live VMEM-resident, each transposed to
+    ``[f_pad, k]`` (``f_blk`` rounded up to the 8-row sublane tile) --
+    k * f is tiny by construction, the point of VQ;
+  * per branch, the resident SpMM's one-hot MXU product
+    (``spmm_ell.onehot_ell_sum``) turns the tile's D slots into
+    ``X~^beta^T @ A`` and writes that branch's ``[f_pad, rows]`` slice of
+    the branch-concatenated output; no per-branch intermediate leaves VMEM.
 
 The same kernel is the streaming Eq. 7 backward (DESIGN.md section 10):
 called with the reverse-edge operands and the *gradient* codewords it
@@ -35,20 +36,15 @@ be int8 or float8_e4m3fn with a per-branch/per-channel f32 scale
 (``cw_scale [nb, 1, f_blk]``,
 ``distributed.quantization.quantize_codewords``) and the assignment table
 may be uint8 (k <= 256) or nibble-packed (``PackedAssignment``, k <= 16,
-two ids per byte) -- all stay in their storage dtype inside VMEM (4x /
-8x-vs-int32 envelope win on the assignment table, the dispatch-budget
-lever).  Quantized codeword rows gather in storage dtype and widen
-in-register (``astype(f32)``) -- on non-fp8 backends that upcast IS the
-fallback path, so interpret-mode CPU CI exercises the same kernel.  The
-accumulate runs in f32, and the dequant multiply is a single epilogue row
-``acc * scale_flat [1, nb * f_blk]``: scales are k-independent, so the
-multiply commutes with the over-neighbors sum and with the fused ``w_t``
-MXU epilogue ordering (scale first, then ``@ W^T``).  Packed assignments
-unpack in-kernel with a shift/mask on the gathered byte -- no unpacked
-table ever materializes.
+two ids per byte); the XLA-side gather reads either storage form.  The
+codebook stays in storage dtype in VMEM and is widened a block at a time;
+the accumulate runs in f32, and the dequant multiply is a single epilogue
+column ``acc * scale``: scales are k-independent, so the multiply commutes
+with the over-neighbors sum and with the fused ``w_t`` MXU epilogue
+ordering (scale first, then ``@ W^T``).
 
 Padding contract (shared with spmm_ell): slots with ``vals == 0`` may
-point at any valid node id; rows padded to the ``bb`` tile carry zero vals.
+point at any valid node id; rows padded to the lane tile carry zero vals.
 """
 from __future__ import annotations
 
@@ -58,80 +54,38 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.distributed.quantization import PackedAssignment
+from repro.kernels.spmm_ell import (CHUNK, VMEM_LIMIT_BYTES, lane_tile,
+                                    onehot_ell_sum, rup)
 
 
-def _accumulate(ids_ref, val_ref, assign_ref, cw_ref, *, deg: int, nb: int,
-                k: int, bb: int, packed: bool = False) -> jax.Array:
-    """Shared fused gather+FMA over the D neighbor slots -> [bb, nb*f_blk]."""
-    f_blk = cw_ref.shape[1]
-    offs = jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1) * k  # [1, nb]
+def _context_ell_kernel(aid_ref, val_ref, cw_ref, *refs, deg: int, nb: int,
+                        scaled: bool, fused_wt: bool):
+    # refs is ([sc_ref,] [wt_ref,] o_ref, acc_ref)
+    refs = list(refs)
+    sc_ref = refs.pop(0) if scaled else None
+    wt_ref = refs.pop(0) if fused_wt else None
+    o_ref, acc_ref = refs
+    f_pad = cw_ref.shape[1]
 
-    def body(d, acc):
-        ids = ids_ref[:, d]                                # [bb] int32
-        vals = val_ref[:, d].astype(jnp.float32)           # [bb]
-        if packed:
-            # nibble-packed table [ceil(n/2), nb]: gather the byte holding
-            # the id, then shift/mask out this node's nibble in-register
-            byte = assign_ref[ids >> 1, :].astype(jnp.int32)   # [bb, nb]
-            aid = ((byte >> ((ids & 1) * 4)[:, None]) & 0xF) + offs
-        else:
-            # assignment rides in its storage dtype (int32 or uint8); the
-            # id arithmetic widens in-register only
-            aid = assign_ref[ids, :].astype(jnp.int32) + offs  # [bb, nb]
-        rows = cw_ref[aid.reshape(bb * nb), :]             # [bb*nb, f_blk]
-        # row-major flatten: row (i*nb + beta) is branch beta of batch row i,
-        # so this reshape IS the branch concat -- no moveaxis, no copy
-        rows = rows.reshape(bb, nb * f_blk).astype(jnp.float32)
-        return acc + vals[:, None] * rows
+    def branch(beta, carry):
+        part = onehot_ell_sum(aid_ref, val_ref, cw_ref.at[beta],
+                              beta * deg, deg)                 # [f_pad, bl]
+        acc_ref[pl.ds(pl.multiple_of(beta * f_pad, 8), f_pad), :] = part
+        return carry
 
-    return jax.lax.fori_loop(
-        0, deg, body, jnp.zeros((bb, nb * f_blk), jnp.float32))
-
-
-def _context_ell_kernel(ids_ref, val_ref, assign_ref, cw_ref, o_ref, *,
-                        deg: int, nb: int, k: int, packed: bool):
-    bb = o_ref.shape[0]
-    o_ref[...] = _accumulate(ids_ref, val_ref, assign_ref, cw_ref, deg=deg,
-                             nb=nb, k=k, bb=bb,
-                             packed=packed).astype(o_ref.dtype)
-
-
-def _context_ell_wt_kernel(ids_ref, val_ref, assign_ref, cw_ref, wt_ref,
-                           o_ref, *, deg: int, nb: int, k: int,
-                           packed: bool):
-    bb = o_ref.shape[0]
-    acc = _accumulate(ids_ref, val_ref, assign_ref, cw_ref,
-                      deg=deg, nb=nb, k=k, bb=bb, packed=packed)
-    # fused epilogue: the Eq. 7 ``@ W^T`` as one resident MXU matmul
-    o_ref[...] = jax.lax.dot_general(
-        acc, wt_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
-
-
-def _context_ell_q_kernel(ids_ref, val_ref, assign_ref, cw_ref, sc_ref,
-                          o_ref, *, deg: int, nb: int, k: int,
-                          packed: bool):
-    """int8/fp8 codewords: f32 accumulate + one dequant-row epilogue."""
-    bb = o_ref.shape[0]
-    acc = _accumulate(ids_ref, val_ref, assign_ref, cw_ref,
-                      deg=deg, nb=nb, k=k, bb=bb, packed=packed)
-    o_ref[...] = (acc * sc_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
-
-
-def _context_ell_q_wt_kernel(ids_ref, val_ref, assign_ref, cw_ref, sc_ref,
-                             wt_ref, o_ref, *, deg: int, nb: int, k: int,
-                             packed: bool):
-    bb = o_ref.shape[0]
-    acc = _accumulate(ids_ref, val_ref, assign_ref, cw_ref,
-                      deg=deg, nb=nb, k=k, bb=bb, packed=packed)
-    acc = acc * sc_ref[...].astype(jnp.float32)   # dequant BEFORE the W^T mix
-    o_ref[...] = jax.lax.dot_general(
-        acc, wt_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, nb, branch, 0)
+    tile = acc_ref[...]
+    if sc_ref is not None:
+        tile = tile * sc_ref[...]         # dequant BEFORE the W^T mix
+    if wt_ref is not None:
+        # fused epilogue: the Eq. 7 ``@ W^T`` as one resident MXU matmul
+        tile = jnp.dot(wt_ref[...], tile,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+    o_ref[...] = tile
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))
@@ -139,83 +93,84 @@ def context_ell_pallas(out_ids: jax.Array, out_vals: jax.Array,
                        assignment: jax.Array, codewords: jax.Array, *,
                        cw_scale: Optional[jax.Array] = None,
                        w_t: Optional[jax.Array] = None,
-                       bb: int = 128, interpret: bool = True) -> jax.Array:
+                       bb: int = 128, interpret: bool = False) -> jax.Array:
     """Fused multi-branch codeword SpMM (one kernel for any n_branches).
 
     out_ids:    [b, D] int32  global node ids (padding: val == 0)
     out_vals:   [b, D]        edge values
     assignment: [n_branches, n] int32 or uint8 (k <= 256) codeword ids, or
-                a nibble-packed ``PackedAssignment`` (k <= 16); the table
-                stays in its storage dtype inside VMEM
+                a nibble-packed ``PackedAssignment`` (k <= 16)
     codewords:  [n_branches, k, f_blk]  feature OR gradient codewords
                 (f32, or int8/fp8 when ``cw_scale`` is given)
     cw_scale:   optional [n_branches, 1, f_blk] f32 per-branch/per-channel
-                dequant scales of quantized codewords (module docstring)
+                dequant scales (one epilogue multiply)
     w_t:        optional [n_branches * f_blk, f_out] fused epilogue matmul
 
     Returns [b, n_branches * f_blk] (branch-concatenated), or [b, f_out]
-    with the ``w_t`` epilogue.
+    with the ``w_t`` epilogue.  ``bb`` rows per grid step, rounded up to a
+    multiple of 128 (rows ride the lanes).
     """
     b, deg = out_ids.shape
     nb, k, f_blk = codewords.shape
-    f_cat = nb * f_blk
     if deg == 0:
-        f_out = f_cat if w_t is None else w_t.shape[1]
+        f_out = nb * f_blk if w_t is None else w_t.shape[1]
         return jnp.zeros((b, f_out), jnp.float32)
 
-    bb = min(bb, max(8, b))
-    bp = (b + bb - 1) // bb * bb
-    ids_p = jnp.zeros((bp, deg), jnp.int32).at[:b].set(
-        out_ids.astype(jnp.int32))
-    val_p = jnp.zeros((bp, deg), jnp.float32).at[:b].set(
-        out_vals.astype(jnp.float32))
-    packed = isinstance(assignment, PackedAssignment)
-    if packed:
-        # packed bytes transpose to [ceil(n/2), nb]: one gathered byte row
-        # holds a node pair's ids for every branch
-        assign_t = assignment.packed.T
-    else:
-        # uint8 assignment stays uint8 (the 4x VMEM-envelope win);
-        # everything else rides as int32
-        assign_t = assignment.T if assignment.dtype == jnp.uint8 \
-            else assignment.astype(jnp.int32).T        # [n, nb]
-    cw_flat = codewords.reshape(nb * k, f_blk)
+    bl = lane_tile(b, bb)
+    bp = rup(b, bl)
+    ids_t = out_ids.astype(jnp.int32).T                      # [D, b]
+    aid = assignment.gather(ids_t) \
+        if isinstance(assignment, PackedAssignment) \
+        else assignment[:, ids_t]                            # [nb, D, b]
+    aid = jnp.zeros((nb * deg, bp), jnp.int32).at[:, :b].set(
+        aid.astype(jnp.int32).reshape(nb * deg, b))
+    val_t = jnp.zeros((deg, bp), jnp.float32).at[:, :b].set(
+        out_vals.astype(jnp.float32).T)
 
-    n = assign_t.shape[0]
-    common = dict(deg=deg, nb=nb, k=k, packed=packed)
+    # per-branch transposed codebook [nb, f_pad, k_pad]
+    f_pad, k_pad = rup(f_blk, 8), rup(k, CHUNK)
+    cw = jnp.pad(jnp.swapaxes(codewords, 1, 2),
+                 ((0, 0), (0, f_pad - f_blk), (0, k_pad - k)))
+    f_cat = nb * f_pad
+
+    def spread(a):
+        """[nb * f_blk, ...] -> [f_cat, ...]: branch beta's f_blk rows land
+        at beta * f_pad, zeros elsewhere (the accumulator layout)."""
+        a = a.reshape(nb, f_blk, *a.shape[1:])
+        a = jnp.pad(a, [(0, 0), (0, f_pad - f_blk)]
+                    + [(0, 0)] * (a.ndim - 2))
+        return a.reshape(f_cat, *a.shape[2:])
+
     in_specs = [
-        pl.BlockSpec((bb, deg), lambda i: (i, 0)),
-        pl.BlockSpec((bb, deg), lambda i: (i, 0)),
-        pl.BlockSpec((n, nb), lambda i: (0, 0)),
-        pl.BlockSpec((nb * k, f_blk), lambda i: (0, 0)),
+        pl.BlockSpec((nb * deg, bl), lambda i: (0, i)),
+        pl.BlockSpec((deg, bl), lambda i: (0, i)),
+        pl.BlockSpec(cw.shape, lambda i: (0, 0, 0)),
     ]
-    operands = [ids_p, val_p, assign_t, cw_flat]
+    operands = [aid, val_t, cw]
     if cw_scale is not None:
-        # [nb, 1, f_blk] -> the flat [1, nb * f_blk] epilogue row matching
-        # the accumulator's branch-major column layout
-        in_specs.append(pl.BlockSpec((1, f_cat), lambda i: (0, 0)))
-        operands.append(cw_scale.astype(jnp.float32).reshape(1, f_cat))
-        kern, kern_wt = _context_ell_q_kernel, _context_ell_q_wt_kernel
-    else:
-        kern, kern_wt = _context_ell_kernel, _context_ell_wt_kernel
-    if w_t is None:
-        out = pl.pallas_call(
-            functools.partial(kern, **common),
-            grid=(bp // bb,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((bb, f_cat), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((bp, f_cat), jnp.float32),
-            interpret=interpret,
-        )(*operands)
-    else:
+        in_specs.append(pl.BlockSpec((f_cat, 1), lambda i: (0, 0)))
+        operands.append(spread(cw_scale.astype(jnp.float32).reshape(
+            nb * f_blk, 1)))
+    f_out = f_cat
+    if w_t is not None:
         f_out = w_t.shape[1]
-        out = pl.pallas_call(
-            functools.partial(kern_wt, **common),
-            grid=(bp // bb,),
-            in_specs=in_specs + [
-                pl.BlockSpec((f_cat, f_out), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((bb, f_out), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((bp, f_out), jnp.float32),
-            interpret=interpret,
-        )(*operands, w_t.astype(jnp.float32))
-    return out[:b]
+        in_specs.append(pl.BlockSpec((f_out, f_cat), lambda i: (0, 0)))
+        operands.append(spread(w_t.astype(jnp.float32)).T)
+    out = pl.pallas_call(
+        functools.partial(_context_ell_kernel, deg=deg, nb=nb,
+                          scaled=cw_scale is not None,
+                          fused_wt=w_t is not None),
+        grid=(bp // bl,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((f_out, bl), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((f_out, bp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((f_cat, bl), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(*operands)
+    out = out[:, :b].T
+    if w_t is None:
+        out = out.reshape(b, nb, f_pad)[:, :, :f_blk].reshape(b, nb * f_blk)
+    return out

@@ -34,10 +34,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, bk]
@@ -69,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, bq: int = 256, bk: int = 512,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: [b, h, sq, d], k/v: [b, h, skv, d] -> [b, h, sq, d].
 
     sq must equal skv when causal (standard training layout).

@@ -4,25 +4,32 @@ On a TPU backend the Pallas kernels run compiled; on the CPU host the system
 executes the pure-jnp oracles from ref.py (numerically identical -- the
 kernels are validated against them in interpret mode by tests/test_kernels.py,
 tests/test_context_ell.py, tests/test_spmm_hbm.py, tests/test_vq_update.py
-and the precision sweeps in tests/test_int8.py / tests/test_fp8_int4.py).
-Set REPRO_FORCE_PALLAS=1 to route every call through the interpret-mode
-kernels instead (used by the kernel test sweeps and CI).
+and the precision sweeps in tests/test_int8.py / tests/test_fp8_int4.py,
+and compiled for v5e by tests/test_tpu_compile.py).
+Set REPRO_FORCE_PALLAS=1 to route every call through the kernels on the CPU
+too, in interpret mode (used by the kernel test sweeps and CI).
+``interpret_mode`` is the one place that decides compiled vs interpreted.
 
 Production notes (TPU):
   * ``spmm_ell`` has two variants (DESIGN.md section 3, resident vs HBM):
-    the resident kernel holds the full source matrix in VMEM; for
-    n_src * f beyond the VMEM envelope the HBM variant keeps it in
-    memory_space=ANY and DMAs double-buffered row stripes keyed by a
-    scalar-prefetched tile->stripes index (PrefetchScalarGridSpec).  The
-    size-based dispatch below picks the variant; override with
+    the resident kernel holds the full source matrix in VMEM and gathers
+    with a one-hot MXU product; for n_src * f beyond the VMEM budget the
+    HBM variant keeps it in memory_space=ANY and DMAs one source row per
+    neighbor slot.  The size-based dispatch below picks the variant;
+    override with
     REPRO_SPMM_VARIANT / REPRO_SPMM_VMEM_BUDGET_MB or
-    ``configure_spmm_dispatch``.
+    ``configure_spmm_dispatch``.  The kernel path carries a custom VJP
+    whose backward is the XLA-compiled VJP of ``ref.spmm_ell`` (a
+    ``pallas_call`` has no transpose rule), so training differentiates
+    through it.
   * ``context_ell`` (DESIGN.md section 10) fuses the multi-branch
     VQ-context term -- Eq. 6 forward and the streaming Eq. 7 backward --
     into ONE kernel dispatch regardless of n_branches; dispatch falls back
     to the per-branch loop when the [n_branches, n] assignment table
-    exceeds the VMEM envelope (REPRO_CONTEXT_VARIANT /
-    REPRO_CONTEXT_VMEM_BUDGET_MB or ``configure_context_dispatch``).
+    exceeds the VMEM budget (a rule that predates the XLA-side assignment
+    gather of the current kernel; REPRO_CONTEXT_VARIANT /
+    REPRO_CONTEXT_VMEM_BUDGET_MB or ``configure_context_dispatch``).  It
+    carries the same oracle-VJP custom rule.
   * operand precision tiers (DESIGN.md sections 13/15): codewords may be
     int8 or float8_e4m3fn ``QTensor`` snapshots and assignment tables
     uint8 (k <= 256) or nibble-packed ``PackedAssignment`` (k <= 16);
@@ -45,10 +52,17 @@ from repro.kernels.vq_assign import vq_assign_pallas
 from repro.kernels.vq_update import vq_assign_update_pallas
 from repro.kernels.context_ell import context_ell_pallas
 from repro.kernels.spmm_ell import spmm_ell_pallas
-from repro.kernels.spmm_ell_hbm import StripeIndex, spmm_ell_hbm_pallas
+from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.vq_attention import vq_attention_decode_pallas
 from repro.distributed.quantization import PackedAssignment, QTensor
+
+
+def interpret_mode() -> bool:
+    """True when the Pallas kernels must run in the interpreter: on any
+    backend but a TPU.  The one place this is decided (the autotuner's
+    timing runs ask here too)."""
+    return jax.default_backend() != "tpu"
 
 
 def _use_pallas() -> bool:
@@ -127,8 +141,7 @@ def precision_packs_assignment(precision: Optional[str] = None) -> bool:
 
 def vq_assign(x: jax.Array, codewords: jax.Array) -> jax.Array:
     if _use_pallas():
-        return vq_assign_pallas(
-            x, codewords, interpret=jax.default_backend() != "tpu")
+        return vq_assign_pallas(x, codewords, interpret=interpret_mode())
     return ref.vq_assign(x, codewords)
 
 
@@ -154,7 +167,7 @@ def vq_assign_update(x: jax.Array, codewords: jax.Array, *,
             bb, kb = tuned["bb"], tuned["kb"]
         return vq_assign_update_pallas(
             x, codewords, bb=bb, kb=kb, emit_dtype=emit_dtype,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     idx, qerr, counts, sums = ref.vq_assign_update(x, codewords)
     return idx.astype(emit_dtype), qerr, counts, sums
 
@@ -242,48 +255,78 @@ def spmm_ell_variant(n_src: int, f: int, itemsize: int = 4) -> str:
         else "resident"
 
 
-def spmm_ell(nbr_idx: jax.Array, nbr_val: jax.Array, x: jax.Array,
-             stripe_index: Optional[StripeIndex] = None, *,
+def spmm_ell(nbr_idx: jax.Array, nbr_val: jax.Array, x: jax.Array, *,
              x_scale: Optional[jax.Array] = None) -> jax.Array:
     """ELLPACK SpMM with size-based resident/HBM variant dispatch.
 
-    ``stripe_index`` (built at batch-pack time by
-    ``repro.graph.batching.make_stripe_index``) is only consumed by the HBM
-    variant; the resident kernel and the CPU oracle ignore it.
-
     ``x`` may be a ``QTensor`` of int8 or float8_e4m3fn rows (or pass
-    ``x_scale`` [1, f] explicitly with a quantized ``x``): both kernel
-    variants and the CPU oracle consume the storage dtype natively -- f32
-    accumulate and one dequant epilogue inside the kernel, so the HBM
-    variant's stripes DMA as 1-byte elements too (DESIGN.md sections
-    13/15).  On backends without native fp8 arithmetic the in-kernel
-    ``astype(f32)`` upcast is the fallback path -- same kernel, interpret
-    mode included.
-
-    A precomputed ``stripe_index`` pins the HBM tiling (its static
-    bb/stripe override the tuner's); otherwise the autotuner's measured
-    ``bb``/``stripe`` flow into whichever variant dispatch picks.
+    ``x_scale`` [1, f] explicitly with a quantized ``x``): the resident
+    variant keeps the rows in storage dtype in VMEM, the HBM variant and
+    the CPU oracle widen them up front; all accumulate in f32 with one
+    dequant epilogue (DESIGN.md sections 13/15).  The autotuner's measured
+    ``bb`` flows into whichever variant dispatch picks.
     """
     if isinstance(x, QTensor):
         x, x_scale = x.q, x.scale
     if _use_pallas():
-        interpret = jax.default_backend() != "tpu"
-        n_src, f = x.shape
-        bb, stripe = 128, 512
-        # key the tuner on the storage dtype, not just itemsize: int8 and
-        # fp8 sources share itemsize 1 but are distinct operand regimes
-        tuned = autotune.tuned_spmm(n_src, f, x.dtype.itemsize,
-                                    dtype=x.dtype)
-        if tuned is not None:
-            bb = int(tuned.get("bb", bb))
-            stripe = int(tuned.get("stripe", stripe))
-        if spmm_ell_variant(n_src, f, x.dtype.itemsize) == "hbm":
-            return spmm_ell_hbm_pallas(
-                nbr_idx, nbr_val, x, stripe_index, x_scale=x_scale,
-                bb=bb, stripe=stripe, interpret=interpret)
-        return spmm_ell_pallas(nbr_idx, nbr_val, x, x_scale=x_scale,
-                               bb=bb, interpret=interpret)
+        return _spmm_ell_kernel(nbr_idx, nbr_val, x, x_scale)
     return ref.spmm_ell(nbr_idx, nbr_val, x, x_scale)
+
+
+def _differentiable(a) -> bool:
+    """Float operands of at least 16 bits carry cotangents; ids and
+    1-byte quantized storage do not."""
+    return a is not None and jnp.issubdtype(a.dtype, jnp.floating) \
+        and a.dtype.itemsize >= 2
+
+
+def _oracle_cotangents(oracle, args: tuple, g) -> list:
+    """Cotangents of ``oracle(*args)`` for the differentiable entries of
+    ``args`` (None for the rest): the backward of every kernel-path custom
+    VJP, compiled by XLA."""
+    pos = [i for i, a in enumerate(args)
+           if not isinstance(a, PackedAssignment)
+           and _differentiable(a)]
+
+    def fn(*diff):
+        full = list(args)
+        for i, a in zip(pos, diff):
+            full[i] = a
+        return oracle(*full)
+
+    _, vjp = jax.vjp(fn, *[args[i] for i in pos])
+    cts = [None] * len(args)
+    for i, ct in zip(pos, vjp(g.astype(jnp.float32))):
+        cts[i] = ct
+    return cts
+
+
+@jax.custom_vjp
+def _spmm_ell_kernel(nbr_idx, nbr_val, x, x_scale):
+    n_src, f = x.shape
+    bb = 128
+    # key the tuner on the storage dtype, not just itemsize: int8 and
+    # fp8 sources share itemsize 1 but are distinct operand regimes
+    tuned = autotune.tuned_spmm(n_src, f, x.dtype.itemsize, dtype=x.dtype)
+    if tuned is not None:
+        bb = int(tuned.get("bb", bb))
+    if spmm_ell_variant(n_src, f, x.dtype.itemsize) == "hbm":
+        return spmm_ell_hbm_pallas(nbr_idx, nbr_val, x, x_scale=x_scale,
+                                   bb=bb, interpret=interpret_mode())
+    return spmm_ell_pallas(nbr_idx, nbr_val, x, x_scale=x_scale,
+                           bb=bb, interpret=interpret_mode())
+
+
+def _spmm_ell_fwd(nbr_idx, nbr_val, x, x_scale):
+    return (_spmm_ell_kernel(nbr_idx, nbr_val, x, x_scale),
+            (nbr_idx, nbr_val, x, x_scale))
+
+
+def _spmm_ell_bwd(res, g):
+    return tuple(_oracle_cotangents(ref.spmm_ell, res, g))
+
+
+_spmm_ell_kernel.defvjp(_spmm_ell_fwd, _spmm_ell_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -399,33 +442,52 @@ def context_ell(out_ids: jax.Array, out_vals: jax.Array,
     if isinstance(codewords, QTensor):
         codewords, cw_scale = codewords.q, codewords.scale
     if _use_pallas():
-        interpret = jax.default_backend() != "tpu"
-        if isinstance(assignment, PackedAssignment):
-            nb, n = assignment.shape
-            itemsize: float = 0.5
-            a_dtype = jnp.uint4
-        else:
-            nb, n = assignment.shape
-            itemsize = assignment.dtype.itemsize
-            a_dtype = assignment.dtype
-        bb = 128
-        tuned = autotune.tuned_context(n, nb, itemsize, dtype=a_dtype)
-        if tuned is not None:
-            bb = int(tuned.get("bb", bb))
-        if context_ell_variant(n, nb, itemsize, dtype=a_dtype) == "fused":
-            return context_ell_pallas(out_ids, out_vals, assignment,
-                                      codewords, cw_scale=cw_scale, w_t=w_t,
-                                      bb=bb, interpret=interpret)
-        return _context_ell_loop(out_ids, out_vals, assignment, codewords,
-                                 w_t, cw_scale)
+        return _context_ell_kernel(out_ids, out_vals, assignment, codewords,
+                                   w_t, cw_scale)
     return _context_ell_ref(out_ids, out_vals, assignment, codewords, w_t,
                             cw_scale)
+
+
+@jax.custom_vjp
+def _context_ell_kernel(out_ids, out_vals, assignment, codewords, w_t,
+                        cw_scale):
+    if isinstance(assignment, PackedAssignment):
+        nb, n = assignment.shape
+        itemsize: float = 0.5
+        a_dtype = jnp.uint4
+    else:
+        nb, n = assignment.shape
+        itemsize = assignment.dtype.itemsize
+        a_dtype = assignment.dtype
+    bb = 128
+    tuned = autotune.tuned_context(n, nb, itemsize, dtype=a_dtype)
+    if tuned is not None:
+        bb = int(tuned.get("bb", bb))
+    if context_ell_variant(n, nb, itemsize, dtype=a_dtype) == "fused":
+        return context_ell_pallas(out_ids, out_vals, assignment,
+                                  codewords, cw_scale=cw_scale, w_t=w_t,
+                                  bb=bb, interpret=interpret_mode())
+    return _context_ell_loop(out_ids, out_vals, assignment, codewords,
+                             w_t, cw_scale)
+
+
+def _context_ell_fwd(out_ids, out_vals, assignment, codewords, w_t,
+                     cw_scale):
+    args = (out_ids, out_vals, assignment, codewords, w_t, cw_scale)
+    return _context_ell_kernel(*args), args
+
+
+def _context_ell_bwd(args, g):
+    return tuple(_oracle_cotangents(ref.context_ell, args, g))
+
+
+_context_ell_kernel.defvjp(_context_ell_fwd, _context_ell_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True):
     if _use_pallas() and q.shape[2] % 128 == 0 and q.shape[-1] % 8 == 0:
         return flash_attention_pallas(
-            q, k, v, causal=causal, interpret=jax.default_backend() != "tpu")
+            q, k, v, causal=causal, interpret=interpret_mode())
     return ref.flash_attention(q, k, v, causal=causal)
 
 
@@ -433,7 +495,7 @@ def vq_attention_decode(q, cb_k, cb_v, mass, win_k, win_v, win_mask):
     if _use_pallas():
         return vq_attention_decode_pallas(
             q, cb_k, cb_v, mass, win_k, win_v, win_mask,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
     return jax.vmap(
         lambda qq, ck, cv, m, wk, wv, wm: ref.vq_attention_decode(
             qq, ck, cv, m, wk, wv, wm)

@@ -1,25 +1,23 @@
 """Pallas TPU kernel: ELLPACK SpMM with an HBM-resident source matrix.
 
 Production variant of ``spmm_ell`` for ``n_src * f`` beyond the VMEM
-envelope (DESIGN.md section 3, resident vs HBM): the dense source matrix
+budget (DESIGN.md section 3, resident vs HBM): the dense source matrix
 ``x`` stays in ``memory_space=ANY`` (HBM on a real TPU) and the kernel
-DMAs *stripes* of ``stripe`` contiguous source rows into a double-buffered
-VMEM scratch, so the gather+FMA over stripe ``j`` overlaps the async copy
-of stripe ``j+1``.
+gathers exactly the rows its tile needs, one row DMA per neighbor slot.
 
-Which stripes a row tile needs is data-dependent, so it is scalar-prefetched
-(``PrefetchScalarGridSpec``): a per-tile list of touched stripe ids plus a
-per-tile count, both known before the kernel body runs.  The index is built
-either at batch-pack time on the host (``repro.graph.batching
-.make_stripe_index`` -- the cheap path, it rides along with the pack) or
-in-jit from the neighbor ids as a fallback.
+The tile's neighbor ids ride in SMEM and address the DMAs: slot ``d`` of
+row ``r`` copies ``x[ids[r, d]]`` into row ``r`` of the VMEM buffer
+``buf[d]``.  Every slot is copied, padding included (padding ids point at
+valid rows and carry val == 0), so the buffer never holds uninitialised
+data.  Each slot has its own DMA semaphore, so the weighted accumulate of
+slot ``d`` starts as soon as that slot's ``bb`` rows have landed, while the
+later slots' copies are still in flight.  HBM traffic is ``b * D * f``
+elements -- the gather itself, independent of ``n_src`` and of any index
+locality.
 
-Per-tile work is ``count[t] * deg`` masked gathers from the [stripe, f]
-scratch instead of the resident kernel's ``deg`` gathers from the full
-[n_src, f] block; the win is that VMEM holds ``2 * stripe * f`` source
-elements instead of ``n_src * f``.  Graphs with index locality (sorted node
-ids, clustered batches) touch few stripes per tile and approach the
-resident kernel's arithmetic intensity.
+Row DMAs move whole 32-bit lane rows: an int8/fp8 source is widened to f32
+ahead of the kernel (exact), and a width that is not a multiple of 128 is
+zero-padded; the per-channel scale applies once in the epilogue.
 """
 from __future__ import annotations
 
@@ -30,214 +28,96 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-@jax.tree_util.register_pytree_node_class
-class StripeIndex:
-    """Per-row-tile neighbor-stripe index for the HBM SpMM kernel.
-
-    ``ids[t, :counts[t]]`` are the (ascending) stripe ids touched by row
-    tile ``t``; entries beyond the count are arbitrary valid stripe ids.
-    ``bb`` / ``stripe`` / ``n_src`` are static (pytree aux data) so a
-    precomputed index pins the kernel's tiling and jit validates the
-    (tile count, source-row count) match at trace time.  The *contents*
-    are trusted: an index built from different neighbor ids than the call's
-    silently drops messages -- build it from the same pack.
-    """
-
-    def __init__(self, ids: jax.Array, counts: jax.Array, *,
-                 bb: int, stripe: int, n_src: int):
-        self.ids = ids          # [num_tiles, max_stripes] int32
-        self.counts = counts    # [num_tiles] int32
-        self.bb = int(bb)
-        self.stripe = int(stripe)
-        self.n_src = int(n_src)
-
-    def tree_flatten(self):
-        return (self.ids, self.counts), (self.bb, self.stripe, self.n_src)
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        ids, counts = children
-        bb, stripe, n_src = aux
-        return cls(ids, counts, bb=bb, stripe=stripe, n_src=n_src)
-
-    def __repr__(self):
-        return (f"StripeIndex(tiles={self.ids.shape[0]}, "
-                f"max_stripes={self.ids.shape[1]}, bb={self.bb}, "
-                f"stripe={self.stripe}, n_src={self.n_src})")
+from repro.kernels.spmm_ell import rup
 
 
-def _rup(v: int, m: int) -> int:
-    return (v + m - 1) // m * m
-
-
-def clamp_tiles(b: int, n_src: int, bb: int, stripe: int) -> tuple[int, int]:
-    """Shared tile clamping so host-built indices match the kernel grid."""
-    return min(bb, max(8, b)), min(stripe, _rup(n_src, 8))
-
-
-def stripe_index_jnp(nbr_idx: jax.Array, nbr_val: jax.Array, n_src: int, *,
-                     bb: int, stripe: int) -> StripeIndex:
-    """In-jit stripe-index construction (fallback when the pack did not
-    precompute one).  Slots with ``val == 0`` (padding) touch no stripe.
-
-    The ids width is the static bound min(n_stripes, bb * deg) -- a tile of
-    bb rows with deg slots cannot touch more stripes than it has slots.
-    For very large graphs prefer the host-built pack-time index
-    (``repro.graph.batching.make_stripe_index``): it can be capped to the
-    dataset's measured locality, keeping the scalar-prefetch operand small.
-    """
-    b, deg = nbr_idx.shape
-    bb, stripe = clamp_tiles(b, n_src, bb, stripe)
-    bp = _rup(b, bb)
-    nt = bp // bb
-    n_stripes = _rup(n_src, stripe) // stripe
-
-    idx_p = jnp.zeros((bp, deg), jnp.int32).at[:b].set(
-        nbr_idx.astype(jnp.int32))
-    val_p = jnp.zeros((bp, deg), jnp.float32).at[:b].set(
-        nbr_val.astype(jnp.float32))
-    sid = (idx_p // stripe).reshape(nt, bb * deg)
-    # park padding slots in an overflow column that is sliced away
-    sid = jnp.where((val_p != 0.0).reshape(nt, bb * deg), sid, n_stripes)
-    touched = jnp.zeros((nt, n_stripes + 1), bool).at[
-        jnp.arange(nt)[:, None], sid].set(True)[:, :n_stripes]
-    counts = jnp.sum(touched, axis=1).astype(jnp.int32)
-    # stable argsort of ~touched: touched stripes first, ascending id
-    ids = jnp.argsort(~touched, axis=1, stable=True).astype(jnp.int32)
-    ids = ids[:, :min(n_stripes, bb * deg)]
-    return StripeIndex(ids, counts, bb=bb, stripe=stripe, n_src=n_src)
-
-
-def _spmm_ell_hbm_kernel(sid_ref, cnt_ref, idx_ref, val_ref, x_ref, *refs,
-                         deg: int, stripe: int):
-    # refs is (o_ref, scratch, sems) or, on the int8 path,
-    # (sc_ref, o_ref, scratch, sems): the DMA'd stripes keep x's storage
-    # dtype (int8 rows move as int8 bytes -- the DMA win), the gather-FMA
-    # accumulates the raw int8 values in f32, and the per-channel dequant
-    # is a single epilogue multiply -- the scale commutes with the sum
-    # over neighbors, mirroring the resident ``_spmm_ell_q_kernel``.
-    if len(refs) == 4:
-        sc_ref, o_ref, scratch, sems = refs
-    else:
-        o_ref, scratch, sems = refs
-        sc_ref = None
-    t = pl.program_id(0)
+def _spmm_ell_hbm_kernel(idx_ref, val_ref, x_ref, *refs, deg: int,
+                         scaled: bool):
+    # refs is ([sc_ref,] o_ref, buf, sems)
+    refs = list(refs)
+    sc_ref = refs.pop(0) if scaled else None
+    o_ref, buf, sems = refs
     bb, f = o_ref.shape
-    nst = cnt_ref[t]
 
-    def get_dma(slot, j):
-        s = sid_ref[t, j]
-        return pltpu.make_async_copy(
-            x_ref.at[pl.ds(s * stripe, stripe), :],
-            scratch.at[slot],
-            sems.at[slot])
+    def row_copy(r, d, src_row):
+        return pltpu.make_async_copy(x_ref.at[pl.ds(src_row, 1)],
+                                     buf.at[d, pl.ds(r, 1)], sems.at[d])
 
-    @pl.when(nst > 0)
-    def _warmup():
-        get_dma(0, 0).start()
+    def start_slot(d, c):
+        def start_row(r, c2):
+            row_copy(r, d, idx_ref[r, d]).start()
+            return c2
+        return jax.lax.fori_loop(0, bb, start_row, c)
 
-    def stripe_body(j, acc):
-        slot = jax.lax.rem(j, 2)
+    jax.lax.fori_loop(0, deg, start_slot, 0)
 
-        @pl.when(j + 1 < nst)
-        def _prefetch_next():
-            get_dma(jax.lax.rem(j + 1, 2), j + 1).start()
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (bb, deg), 1)
+    vals = val_ref[...]
 
-        get_dma(slot, j).wait()
-        base = sid_ref[t, j] * stripe
-        xs = scratch[slot].astype(jnp.float32)               # [stripe, f]
+    def accumulate(d, acc):
+        def wait_row(r, c):
+            row_copy(r, d, 0).wait()
+            return c
+        jax.lax.fori_loop(0, bb, wait_row, 0)
+        w = jnp.sum(jnp.where(lanes == d, vals, 0.0), axis=1,
+                    keepdims=True)                             # [bb, 1]
+        return acc + w * buf[d]
 
-        def slot_body(d, acc2):
-            loc = idx_ref[:, d] - base                       # [bb]
-            in_stripe = (loc >= 0) & (loc < stripe)
-            rows = xs[jnp.where(in_stripe, loc, 0), :]       # [bb, f]
-            w = jnp.where(in_stripe, val_ref[:, d].astype(jnp.float32), 0.0)
-            return acc2 + w[:, None] * rows
-
-        return jax.lax.fori_loop(0, deg, slot_body, acc)
-
-    acc = jax.lax.fori_loop(0, nst, stripe_body,
+    acc = jax.lax.fori_loop(0, deg, accumulate,
                             jnp.zeros((bb, f), jnp.float32))
     if sc_ref is not None:
-        acc = acc * sc_ref[...].astype(jnp.float32)
-    o_ref[...] = acc.astype(o_ref.dtype)
+        acc = acc * sc_ref[...]
+    o_ref[...] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("bb", "stripe", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bb", "interpret"))
 def spmm_ell_hbm_pallas(nbr_idx: jax.Array, nbr_val: jax.Array,
-                        x: jax.Array,
-                        stripe_index: StripeIndex | None = None, *,
-                        x_scale: jax.Array | None = None,
-                        bb: int = 128, stripe: int = 512,
-                        interpret: bool = True) -> jax.Array:
+                        x: jax.Array, *, x_scale: jax.Array | None = None,
+                        bb: int = 128, interpret: bool = False) -> jax.Array:
     """nbr_idx/[b, D] int32, nbr_val/[b, D], x/[n_src, f] -> [b, f] f32.
 
     Same contract as ``spmm_ell_pallas`` (padding slots carry val == 0),
-    but ``x`` lives in ``memory_space=ANY`` and only ``2 * stripe`` of its
-    rows are ever resident in VMEM.  ``stripe_index`` (from
-    ``repro.graph.batching.make_stripe_index``) skips the in-jit index
-    build; it must have been built for the same ``(b, n_src)`` tiling.
-    As with the resident kernel, callers keep ``f`` lane-aligned (mult. of
-    128) for the compiled TPU path; interpret mode takes any ``f``.
+    but ``x`` lives in ``memory_space=ANY`` and only the tile's gathered
+    ``[D, bb, f]`` rows are ever resident in VMEM.
 
-    ``x_scale`` ([1, f] or [f] per-channel dequant scales) makes the
-    kernel consume an int8 ``x`` natively: stripes DMA as int8 (4x fewer
-    HBM bytes -- the bandwidth this variant is bound by), the accumulate
+    ``x_scale`` ([1, f] or [f] per-channel dequant scales) marks ``x`` as
+    int8/fp8 rows: they are widened before the row DMAs, the accumulate
     stays f32, and the scales apply once in the epilogue.
     """
     b, deg = nbr_idx.shape
     n_src, f = x.shape
-    if stripe_index is not None:
-        bb, stripe = stripe_index.bb, stripe_index.stripe
-    else:
-        bb, stripe = clamp_tiles(b, n_src, bb, stripe)
-        stripe_index = stripe_index_jnp(nbr_idx, nbr_val, n_src,
-                                        bb=bb, stripe=stripe)
-    bp = _rup(b, bb)
-    nt = bp // bb
-    np_ = _rup(n_src, stripe)
-    if stripe_index.ids.shape[0] != nt:
-        raise ValueError(
-            f"stripe_index built for {stripe_index.ids.shape[0]} tiles, "
-            f"kernel grid has {nt} (b={b}, bb={bb})")
-    if stripe_index.n_src != n_src:
-        raise ValueError(
-            f"stripe_index built for n_src={stripe_index.n_src}, "
-            f"x has {n_src} rows")
-
+    if deg == 0:
+        return jnp.zeros((b, f), jnp.float32)
+    bb = min(bb, rup(b, 8))
+    bp = rup(b, bb)
+    fp = rup(f, 128)
     idx_p = jnp.zeros((bp, deg), jnp.int32).at[:b].set(
         nbr_idx.astype(jnp.int32))
     val_p = jnp.zeros((bp, deg), jnp.float32).at[:b].set(
         nbr_val.astype(jnp.float32))
-    x_p = x if np_ == n_src else \
-        jnp.zeros((np_, f), x.dtype).at[:n_src].set(x)
 
     in_specs = [
-        pl.BlockSpec((bb, deg), lambda i, *_: (i, 0)),
-        pl.BlockSpec((bb, deg), lambda i, *_: (i, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec((bb, deg), lambda i: (i, 0), memory_space=pltpu.SMEM),
+        pl.BlockSpec((bb, deg), lambda i: (i, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
+    x_p = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, fp - f)))
     operands = [idx_p, val_p, x_p]
     if x_scale is not None:
-        in_specs.append(pl.BlockSpec((1, f), lambda i, *_: (0, 0)))
-        operands.append(x_scale.reshape(1, f))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nt,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bb, f), lambda i, *_: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, stripe, f), x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
+        in_specs.append(pl.BlockSpec((1, fp), lambda i: (0, 0)))
+        operands.append(jnp.pad(x_scale.astype(jnp.float32).reshape(1, f),
+                                ((0, 0), (0, fp - f))))
     out = pl.pallas_call(
-        functools.partial(_spmm_ell_hbm_kernel, deg=deg, stripe=stripe),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bp, f), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",)),
+        functools.partial(_spmm_ell_hbm_kernel, deg=deg,
+                          scaled=x_scale is not None),
+        grid=(bp // bb,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bb, fp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bp, fp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((deg, bb, fp), jnp.float32),
+                        pltpu.SemaphoreType.DMA((deg,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(stripe_index.ids, stripe_index.counts, *operands)
-    return out[:b]
+    )(*operands)
+    return out[:b, :f]

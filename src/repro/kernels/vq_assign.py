@@ -34,8 +34,11 @@ def pad_assign_operands(x: jax.Array, codewords: jax.Array,
     multiple of 128 with zeros (leaves distances unchanged).  Padded
     codeword rows get value 1e15 so they never win the argmin.
 
-    Returns (xp, cp, bb, kb, bp, kp, fp) with bb/kb clamped to the actual
-    problem size (floor 8, the f32 sublane width).
+    Returns (xp, cp, cn2, bb, kb, bp, kp, fp) with bb/kb clamped to the
+    actual problem size (floor 8, the f32 sublane width).  ``cn2`` [1, kp]
+    holds the codeword squared norms as a lane-major row: computed in the
+    kernel, the [kb] column reduction would need a sublane-to-lane
+    relayout that Mosaic materializes at ~100x the tile's VMEM.
     """
     b, f = x.shape
     k = codewords.shape[0]
@@ -49,21 +52,33 @@ def pad_assign_operands(x: jax.Array, codewords: jax.Array,
     xp = jnp.zeros((bp, fp), x.dtype).at[:b, :f].set(x)
     cp = jnp.full((kp, fp), 1e15, jnp.float32).at[:k, :f].set(
         codewords.astype(jnp.float32)).at[:k, f:].set(0.0)
-    return xp, cp, bb, kb, bp, kp, fp
+    cn2 = jnp.sum(cp * cp, axis=1)[None, :]
+    return xp, cp, cn2, bb, kb, bp, kp, fp
 
 
-def _vq_assign_kernel(x_ref, c_ref, val_ref, idx_ref, *, kb: int):
+def tile_argmin(x_ref, c_ref, cn_ref, kb: int):
+    """Distances of the [bb, f] x-tile to the [kb, f] codeword tile and
+    their per-row (min, first argmin) over the tile, argmin offset to the
+    global codeword id.  The argmin is the smallest column attaining the
+    min (jnp.argmin's tie rule), taken with a min-reduction over masked
+    column ids."""
     ki = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)                    # [bb, f]
     c = c_ref[...].astype(jnp.float32)                    # [kb, f]
     # MXU: scores[b, k] = x . c^T
     scores = jax.lax.dot_general(
         x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    cn2 = jnp.sum(c * c, axis=1)                          # [kb]
-    dist = cn2[None, :] - 2.0 * scores                    # [bb, kb]
-
+    dist = cn_ref[...] - 2.0 * scores                     # [bb, kb]
     tile_min = jnp.min(dist, axis=1, keepdims=True)       # [bb, 1]
-    tile_arg = (jnp.argmin(dist, axis=1)[:, None] + ki * kb).astype(jnp.int32)
+    cols = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
+    tile_arg = jnp.min(jnp.where(dist == tile_min, cols, kb), axis=1,
+                       keepdims=True) + ki * kb
+    return x, tile_min, tile_arg
+
+
+def _vq_assign_kernel(x_ref, c_ref, cn_ref, val_ref, idx_ref, *, kb: int):
+    ki = pl.program_id(1)
+    _, tile_min, tile_arg = tile_argmin(x_ref, c_ref, cn_ref, kb)
 
     @pl.when(ki == 0)
     def _init():
@@ -97,7 +112,8 @@ def vq_assign_pallas(x: jax.Array, codewords: jax.Array, *,
     f -> multiple of 128 with zeros, which leaves distances unchanged).
     """
     b, _ = x.shape
-    xp, cp, bb, kb, bp, kp, fp = pad_assign_operands(x, codewords, bb, kb)
+    xp, cp, cn2, bb, kb, bp, kp, fp = pad_assign_operands(x, codewords,
+                                                          bb, kb)
 
     grid = (bp // bb, kp // kb)
     val, idx = pl.pallas_call(
@@ -106,6 +122,7 @@ def vq_assign_pallas(x: jax.Array, codewords: jax.Array, *,
         in_specs=[
             pl.BlockSpec((bb, fp), lambda i, j: (i, 0)),
             pl.BlockSpec((kb, fp), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, kb), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
@@ -116,7 +133,7 @@ def vq_assign_pallas(x: jax.Array, codewords: jax.Array, *,
             jax.ShapeDtypeStruct((bp, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(xp, cp)
+    )(xp, cp, cn2)
     if not want_min:
         return idx[:b, 0]
     xn2 = jnp.sum(x.astype(jnp.float32) ** 2, axis=1)

@@ -61,7 +61,7 @@ def _vq_attn_kernel(q_ref, cbk_ref, cbv_ref, mass_ref, wk_ref, wv_ref,
 def vq_attention_decode_pallas(q: jax.Array, cb_k: jax.Array, cb_v: jax.Array,
                                mass: jax.Array, win_k: jax.Array,
                                win_v: jax.Array, win_mask: jax.Array, *,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool = False) -> jax.Array:
     """Batched VQ-Attention decode.
 
     q:        [n, g, d]   n = batch*kv_heads GQA groups, g q-heads per group

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.vq_assign import pad_assign_operands
+from repro.kernels.vq_assign import pad_assign_operands, tile_argmin
 
 # Narrow emit dtypes and the largest k each can index: uint8 (the int8/fp8
 # tiers' table dtype) and uint4 (the nibble-packed +a4 tiers; SIGNED int4
@@ -50,21 +50,12 @@ from repro.kernels.vq_assign import pad_assign_operands
 _EMIT_K_LIMITS = {"uint8": 256, "uint4": 16}
 
 
-def _vq_update_kernel(x_ref, c_ref, idx_ref, qerr_ref, cnt_ref, sum_ref, *,
-                      bb: int, kb: int, b: int):
+def _vq_update_kernel(x_ref, c_ref, cn_ref, idx_ref, qerr_ref, cnt_ref,
+                      sum_ref, *, bb: int, kb: int, b: int):
     i = pl.program_id(0)
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
-    x = x_ref[...].astype(jnp.float32)                    # [bb, fp]
-    c = c_ref[...].astype(jnp.float32)                    # [kb, fp]
-    # MXU: scores[b, k] = x . c^T
-    scores = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    cn2 = jnp.sum(c * c, axis=1)                          # [kb]
-    dist = cn2[None, :] - 2.0 * scores                    # [bb, kb]
-
-    tile_min = jnp.min(dist, axis=1, keepdims=True)       # [bb, 1]
-    tile_arg = (jnp.argmin(dist, axis=1)[:, None] + ki * kb).astype(jnp.int32)
+    x, tile_min, tile_arg = tile_argmin(x_ref, c_ref, cn_ref, kb)
 
     @pl.when(ki == 0)
     def _init_rows():
@@ -87,13 +78,13 @@ def _vq_update_kernel(x_ref, c_ref, idx_ref, qerr_ref, cnt_ref, sum_ref, *,
 
     @pl.when(ki == nk - 1)
     def _accumulate():
-        kp = cnt_ref.shape[0]
+        kp = cnt_ref.shape[1]
         final = idx_ref[...].astype(jnp.int32)            # [bb, 1] post-combine
         rows = i * bb + jax.lax.broadcasted_iota(jnp.int32, (bb, 1), 0)
         valid = rows < b                                  # padded rows: no stats
         cols = jax.lax.broadcasted_iota(jnp.int32, (bb, kp), 1)
         sel = jnp.where(jnp.logical_and(final == cols, valid), 1.0, 0.0)
-        cnt_ref[...] += jnp.sum(sel, axis=0)[:, None]
+        cnt_ref[...] += jnp.sum(sel, axis=0, keepdims=True)   # [1, kp]
         sum_ref[...] += jax.lax.dot_general(
             sel, x, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -146,7 +137,8 @@ def vq_assign_update_pallas(
             f"emit_dtype={emit.name!r} supports k <= {k_limit}, got "
             f"k={k}; use emit_dtype=jnp.int32 (always valid)"
             + (" or jnp.uint8 (k <= 256)" if emit == jnp.uint4 else ""))
-    xp, cp, bb, kb, bp, kp, fp = pad_assign_operands(x, codewords, bb, kb)
+    xp, cp, cn2, bb, kb, bp, kp, fp = pad_assign_operands(x, codewords,
+                                                          bb, kb)
     # sub-byte dtypes ride the uint8 output block; byte-wide emit dtypes go
     # out natively when the grid has a single k-tile
     block_emit = jnp.uint8 if emit == jnp.uint4 else emit
@@ -160,21 +152,22 @@ def vq_assign_update_pallas(
         in_specs=[
             pl.BlockSpec((bb, fp), lambda i, j: (i, 0)),
             pl.BlockSpec((kb, fp), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, kb), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
             # constant index maps: revisited VMEM accumulators (module doc)
-            pl.BlockSpec((kp, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, kp), lambda i, j: (0, 0)),
             pl.BlockSpec((kp, fp), lambda i, j: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bp, 1), idx_dtype),
             jax.ShapeDtypeStruct((bp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, kp), jnp.float32),
             jax.ShapeDtypeStruct((kp, fp), jnp.float32),
         ],
         interpret=interpret,
-    )(xp, cp)
+    )(xp, cp, cn2)
     return (idx[:b, 0].astype(emit), qerr[:b, 0],
-            counts[:k, 0], sums[:k, :f])
+            counts[0, :k], sums[:k, :f])

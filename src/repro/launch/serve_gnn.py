@@ -50,6 +50,7 @@ from repro.graph.batching import (build_epoch_plan, full_operands,
                                   inference_slices)
 from repro.graph.structure import Graph
 from repro.kernels import ops as kops
+from repro import hostenv
 from repro.models.gnn import (GNNConfig, _layer_out_dims, init_gnn,
                               init_vq_states, quantize_vq_states,
                               vq_infer_epoch, vq_serve_batch)
@@ -261,6 +262,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
+    hostenv.enable_compile_cache()
 
     from repro.graph.datasets import synthetic_arxiv
     g = synthetic_arxiv(n=args.n, seed=args.seed)

@@ -71,7 +71,7 @@ class GCN:
     @staticmethod
     def full_apply(p: Params, x, ops_: FullGraphOperands, act) -> jax.Array:
         vals, self_vals = _gcn_edge_vals(ops_)
-        m = kops.spmm_ell(ops_.nbr_ids, vals, x, ops_.stripe_index) \
+        m = kops.spmm_ell(ops_.nbr_ids, vals, x) \
             + self_vals[:, None] * x
         return act(m @ p["w"] + p["b"])
 
@@ -113,7 +113,7 @@ class SAGE:
     @staticmethod
     def full_apply(p: Params, x, ops_: FullGraphOperands, act) -> jax.Array:
         vals = ops_.nbr_mask / jnp.maximum(ops_.degrees, 1.0)[:, None]
-        mean_nbr = kops.spmm_ell(ops_.nbr_ids, vals, x, ops_.stripe_index)
+        mean_nbr = kops.spmm_ell(ops_.nbr_ids, vals, x)
         return act(x @ p["w1"] + mean_nbr @ p["w2"] + p["b"])
 
     @staticmethod
@@ -152,8 +152,7 @@ class GIN:
 
     @staticmethod
     def full_apply(p: Params, x, ops_: FullGraphOperands, act) -> jax.Array:
-        s = kops.spmm_ell(ops_.nbr_ids, ops_.nbr_mask, x,
-                          ops_.stripe_index)
+        s = kops.spmm_ell(ops_.nbr_ids, ops_.nbr_mask, x)
         m = (1.0 + p["eps"]) * x + s
         h = jax.nn.relu(m @ p["w1"] + p["b1"])
         return act(h @ p["w2"] + p["b2"])

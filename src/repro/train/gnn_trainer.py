@@ -264,6 +264,7 @@ def train_vq(g: Graph, cfg: GNNConfig, *, epochs: int, batch_size: int,
 
     hist, t0 = [], time.time()
     vq_errs = None
+    losses_tr: list = []
     for ep in range(epochs):
         if use_epoch:
             ids, smask = (batch_fn(rng) if batch_fn is not None else
@@ -272,16 +273,17 @@ def train_vq(g: Graph, cfg: GNNConfig, *, epochs: int, batch_size: int,
             ids_d = jnp.asarray(ids.astype(np.int32))
             smask_d = jnp.asarray(smask)
             if sstate is not None:
-                params, vq, ost, _, errs = vq_train_epoch_sharded(
+                params, vq, ost, losses, errs = vq_train_epoch_sharded(
                     sstate, params, vq, ost, ids_d, smask_d, cfg, opt)
             elif mesh is not None:
-                params, vq, ost, _, errs = vq_train_epoch_dp(
+                params, vq, ost, losses, errs = vq_train_epoch_dp(
                     mesh, params, vq, ost, plan, ids_d, smask_d, x,
                     labels, tm, ops.degrees, cfg, opt)
             else:
-                params, vq, ost, _, errs = vq_train_epoch(
+                params, vq, ost, losses, errs = vq_train_epoch(
                     params, vq, ost, plan, ids_d, smask_d, x, labels, tm,
                     ops.degrees, cfg, opt)
+            losses_tr.append(np.asarray(losses))
             if errs.shape[0]:
                 vq_errs = errs[-1]
         elif cfg.task == "node":
@@ -325,7 +327,11 @@ def train_vq(g: Graph, cfg: GNNConfig, *, epochs: int, batch_size: int,
                     ops.degrees, cfg, opt, pos_pairs=jnp.asarray(pos),
                     neg_pairs=jnp.asarray(neg))
         if (ep + 1) % eval_every == 0 or ep == epochs - 1:
-            m = _evaluate(params, g, cfg, x, ops)
+            # evaluation runs unsharded beside x: a jit over mesh-
+            # replicated params would ask XLA to partition the full-graph
+            # forward's Pallas kernels, which Mosaic refuses on a TPU
+            m = _evaluate(params if mesh is None else
+                          jax.device_put(params, x.sharding), g, cfg, x, ops)
             # whitened-space VQ relative error of the last batch, emitted by
             # the fused update kernel (no extra distance computation); stays
             # unset when the epoch had no batch (empty node pool)
@@ -341,7 +347,7 @@ def train_vq(g: Graph, cfg: GNNConfig, *, epochs: int, batch_size: int,
     fi0, fo0 = _layer_out_dims(cfg)[0]
     f_grad = BACKBONES[cfg.backbone].f_grad(fi0, fo0, heads=cfg.heads)
     return {"history": hist, "final": hist[-1], "params": params,
-            "vq_states": vq,
+            "vq_states": vq, "losses": losses_tr,
             "mem_bytes": vq_batch_bytes(
                 batch_size, deg, cfg.hidden, cfg.n_layers, cfg.codebook.k,
                 f_prod=cfg.layer_codebook_cfg().f_prod, f_grad=f_grad,

@@ -120,6 +120,21 @@ def test_repro002_one_hot_in_hot_module():
     assert _sub_findings(src, "src/repro/nn/ffn.py") == []
 
 
+def test_repro002_one_hot_inside_kernel_body_allowed():
+    """A one-hot block built inside a Pallas kernel body lives in VMEM
+    for one tile; only the HBM-materialized indicator is banned."""
+    src = """
+        import jax
+        def _kernel(ids_ref, src_ref, o_ref):
+            a = jax.nn.one_hot(ids_ref[...], 256)
+            o_ref[...] = a @ src_ref[...]
+        def wrapper(ids, k):
+            return jax.nn.one_hot(ids, k)
+    """
+    fs = _sub_findings(src, "src/repro/kernels/spmm_ell.py")
+    assert [(f.rule, f.line) for f in fs] == [("REPRO002", 7)]
+
+
 def test_repro002_einsum_scoping():
     src = """
         import jax.numpy as jnp

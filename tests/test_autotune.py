@@ -153,18 +153,20 @@ def test_env_budget_silences_tuner(tuner_cache, monkeypatch):
     assert ops.spmm_ell_variant(512, 16) == "resident"
 
 
-def test_tuned_spmm_includes_stripe(tuner_cache):
-    """The spmm tuner races HBM stripe sizes under the same cache entry
-    and always records one (the resident variant carries the default)."""
+def test_tuned_spmm_races_both_variants(tuner_cache):
+    """The spmm tuner races both variants' row tiles under one cache entry
+    and reloads the same winner from the file."""
     cfg = autotune.tuned_spmm(500, 16)
-    assert cfg["stripe"] in (256, 512, 1024)
+    assert (cfg["variant"], cfg["bb"]) in {
+        ("resident", 128), ("resident", 256),
+        ("hbm", 64), ("hbm", 128), ("hbm", 256)}
     autotune.clear(memory_only=True)
-    assert autotune.tuned_spmm(500, 16)["stripe"] == cfg["stripe"]
+    assert autotune.tuned_spmm(500, 16) == cfg
 
 
-def test_tuned_stripe_flows_into_hbm_call(tuner_cache, monkeypatch):
-    """A tuned stripe reaches the HBM kernel through ops.spmm_ell, and a
-    pre-stripe cache entry (no 'stripe' key) still dispatches fine."""
+def test_tuned_hbm_entry_flows_into_hbm_call(tuner_cache, monkeypatch):
+    """A tuned HBM entry reaches the HBM kernel through ops.spmm_ell, and
+    an entry carrying an unknown key still dispatches fine."""
     monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
     keyr = jax.random.PRNGKey(1)
     k1, k2, k3 = jax.random.split(keyr, 3)
@@ -177,7 +179,7 @@ def test_tuned_stripe_flows_into_hbm_call(tuner_cache, monkeypatch):
     autotune.record(key, {"variant": "hbm", "bb": 64, "stripe": 256})
     assert_allclose(np.asarray(ops.spmm_ell(ids, val, x)), want,
                     rtol=1e-5, atol=1e-5)
-    autotune.record(key, {"variant": "hbm", "bb": 64})  # legacy entry
+    autotune.record(key, {"variant": "hbm", "bb": 64})
     autotune.clear(memory_only=True)                    # reload from file
     assert_allclose(np.asarray(ops.spmm_ell(ids, val, x)), want,
                     rtol=1e-5, atol=1e-5)

@@ -6,15 +6,11 @@ the lazy-residual contract of inject_context_grad, and gradient parity of
 approx_message_passing's cotangent against dense autodiff through the full
 convolution matrix on a tiny graph.
 
-Gradient tests skip under REPRO_FORCE_PALLAS=1: reverse-mode AD cannot
-trace through the intra-term SpMM pallas_call (no transpose rule).  The
-streaming Eq. 7 backward itself never differentiates through a kernel --
-the custom-VJP backward *invokes* the context kernel forward -- and is
-covered under FORCE_PALLAS by the w_t-epilogue parity sweep here plus the
-dispatch tests.
+Gradient tests also run under REPRO_FORCE_PALLAS=1: the kernel-path SpMM
+and context dispatches carry custom VJPs whose backward is the oracle's
+XLA-compiled VJP (kernels/ops.py), and the streaming Eq. 7 backward
+*invokes* the context kernel forward.
 """
-import os
-
 import numpy as np
 import pytest
 import jax
@@ -27,12 +23,6 @@ from repro.core.message_passing import (ConvOperands, approx_message_passing,
                                         intra_messages, reconstruct)
 from repro.kernels import ops, ref
 from repro.kernels.context_ell import context_ell_pallas
-
-_FORCED_PALLAS = os.environ.get("REPRO_FORCE_PALLAS", "0") == "1"
-needs_autodiff = pytest.mark.skipif(
-    _FORCED_PALLAS, reason="no reverse-mode AD through the intra-term "
-    "pallas_call; Eq. 7's own kernel is parity-covered under FORCE_PALLAS")
-
 
 def _case(b, deg, n, nb, k, f_blk, seed=None, cw_dtype=jnp.float32):
     key = jax.random.PRNGKey(seed if seed is not None
@@ -220,7 +210,6 @@ def _tiny_operands(seed=0, b=6, deg=4, dr=3, n=15, nb=2, k=8,
     return ops_, x_b, fcw, gcw, assign, w, cot
 
 
-@needs_autodiff
 def test_inject_residuals_lazy():
     """inject_context_grad stores NO [b, Dr, f_grad] reconstruction: the
     vjp residuals are the O(b*Dr) edge operands + the O(k*f) codebook."""
@@ -237,7 +226,6 @@ def test_inject_residuals_lazy():
     assert gcw.shape in shapes
 
 
-@needs_autodiff
 @pytest.mark.parametrize("with_w", [False, True])
 def test_eq7_gradient_parity_dense(with_w):
     """approx_message_passing's cotangent (streaming fused backward) ==
@@ -277,7 +265,6 @@ def test_eq7_gradient_parity_dense(with_w):
                     rtol=1e-4, atol=1e-4)
 
 
-@needs_autodiff
 @pytest.mark.parametrize("with_w", [False, True])
 def test_eq7_streaming_matches_materialized(with_w):
     """The lazy streaming backward == the pre-PR materialized injection."""
@@ -289,7 +276,7 @@ def test_eq7_streaming_matches_materialized(with_w):
         grad_hat = jax.lax.stop_gradient(
             reconstruct(gcw, assign, ops_.rev_ids))
         xi = inject_context_grad_materialized(x, ops_.rev_vals, grad_hat, w)
-        m = intra_messages(ops_.in_pos, ops_.in_vals, xi, ops_.stripe_index)
+        m = intra_messages(ops_.in_pos, ops_.in_vals, xi)
         return m + context_messages_reconstruct(
             ops_.out_vals, ops_.out_ids, fcw, assign)
 
@@ -300,7 +287,6 @@ def test_eq7_streaming_matches_materialized(with_w):
                     rtol=1e-5, atol=1e-5)
 
 
-@needs_autodiff
 def test_eq7_inject_off_is_plain_autodiff():
     """inject=False: the cotangent is exactly the dense C_in^T term."""
     ops_, x_b, fcw, gcw, assign, w, cot = _tiny_operands()

@@ -131,8 +131,6 @@ with mesh:
                  out_shardings=(state_sh, shd.replicated(mesh)))
     compiled = fn.lower(state, tok).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):   # jax < 0.4.x returned one dict per device
-        ca = ca[0]
     print("COMPILED_OK", ca["flops"] > 0)
 """
     env = dict(os.environ, PYTHONPATH=SRC)

@@ -99,6 +99,35 @@ def test_plan_batch_matches_make_pack(g, setup):
                               np.asarray(getattr(jit_pack, name))), name
 
 
+def test_plan_batch_duplicate_ids_take_first_slot(g, setup):
+    """Serve requests repeat ids: the host packer, the in-jit scatter and
+    the sharded executor's sort-based positions all send a duplicated
+    node's messages to its FIRST slot (the slot decides where a message
+    enters the kernels' one-hot sums, so sharded serving stays
+    bit-exact)."""
+    from repro.graph.batching import _inbatch_positions
+    rng = np.random.default_rng(5)
+    ids = rng.permutation(g.n)[:48]
+    ids = np.concatenate([ids, ids[rng.integers(0, 48, 16)]])
+    rng.shuffle(ids)
+    host = make_pack(g, ids)
+    jit_pack = jax.jit(plan_batch)(setup["plan"],
+                                   jnp.asarray(ids.astype(np.int32)))
+    for name in PACK_FIELDS:
+        assert np.array_equal(np.asarray(getattr(host, name)),
+                              np.asarray(getattr(jit_pack, name))), name
+    first = {}
+    for s, i in enumerate(ids):
+        first.setdefault(int(i), s)
+    nbr, mask = np.asarray(jit_pack.nbr_ids), np.asarray(jit_pack.nbr_mask)
+    want = np.where(mask != 0, np.vectorize(
+        lambda i: first.get(int(i), -1))(nbr), -1)
+    assert np.array_equal(np.asarray(jit_pack.nbr_pos), want)
+    sorted_pos = _inbatch_positions(jnp.asarray(ids.astype(np.int32)),
+                                    jit_pack.nbr_ids, jit_pack.nbr_mask)
+    assert np.array_equal(np.asarray(sorted_pos), want)
+
+
 # ---------------------------------------------------------------------------
 # tail-batch padding (the old stream silently dropped up to b-1 nodes)
 # ---------------------------------------------------------------------------
@@ -184,6 +213,29 @@ def test_trainer_rejects_mesh_without_epoch_executor(g, setup, monkeypatch):
     with pytest.raises(ValueError, match="epoch executor"):
         train_vq(g, setup["cfg"], epochs=1, batch_size=128,
                  mesh=graph_dp_mesh(1))
+
+
+def test_trainer_evaluates_mesh_training_on_one_device(g, setup,
+                                                       monkeypatch):
+    """After a data-parallel epoch the params are replicated over the
+    mesh; the full-graph evaluation must see them on x's one device (on
+    a TPU, a jit over mesh-placed params would have XLA partition the
+    Pallas kernels, which Mosaic refuses)."""
+    from jax.sharding import SingleDeviceSharding
+    from repro.distributed.data_parallel import graph_dp_mesh
+    from repro.train import gnn_trainer
+    seen = []
+    evaluate = gnn_trainer._evaluate
+
+    def spy(params, *args):
+        seen.extend(leaf.sharding for leaf in jax.tree_util.tree_leaves(
+            params))
+        return evaluate(params, *args)
+
+    monkeypatch.setattr(gnn_trainer, "_evaluate", spy)
+    gnn_trainer.train_vq(g, setup["cfg"], epochs=1, batch_size=128,
+                         mesh=graph_dp_mesh(1))
+    assert seen and all(isinstance(s, SingleDeviceSharding) for s in seen)
 
 
 def test_trainer_env_gate_paths_agree(g, setup, monkeypatch):

@@ -358,7 +358,7 @@ def test_quantize_vq_states_tiers_and_guards():
 
 def test_gather_from_shards_fp8_bit_exact():
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.collectives import gather_from_shards
 
@@ -406,10 +406,6 @@ def test_tier_inference_agreement(tier, monkeypatch):
 
 @pytest.mark.parametrize("tier", ["fp8", "fp8+a4"])
 def test_tier_training_smoke(tier):
-    import os
-    if os.environ.get("REPRO_FORCE_PALLAS", "0") == "1":
-        pytest.skip("training grads cannot trace through the intra-term "
-                    "SpMM pallas_call (test_int8.py convention)")
     from repro.graph.datasets import synthetic_arxiv
     from repro.models.gnn import GNNConfig
     from repro.train.gnn_trainer import train_vq

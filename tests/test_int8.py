@@ -7,7 +7,6 @@ kernel, the ops.py dispatch consuming QTensor/uint8 operands data-driven
 core/conv.py + models/gnn.py, and fp32-vs-int8 end-to-end agreement for
 inference and a short training run.
 """
-import os
 
 import numpy as np
 import pytest
@@ -291,11 +290,6 @@ def test_quantize_vq_states_needs_small_k():
         quantize_vq_states(vq, cfg)
 
 
-@pytest.mark.skipif(
-    os.environ.get("REPRO_FORCE_PALLAS", "0") == "1",
-    reason="training grads cannot trace through the intra-term SpMM "
-    "pallas_call (test_context_ell.py convention); the int8 forward "
-    "operands are parity-covered under FORCE_PALLAS above")
 def test_int8_training_smoke():
     from repro.graph.datasets import synthetic_arxiv
     from repro.models.gnn import GNNConfig

@@ -68,3 +68,18 @@ def test_train_scenario_smoke():
         r = train_scenario(g, cfg, method, epochs=1, batch_size=64,
                            eval_every=1)
         assert "val" in r["final"], method
+
+
+@pytest.mark.parametrize("n", [4000, 4003, 169343])
+def test_paper_batch_size_fills_every_batch(n):
+    """An epoch at the paper's ~n/4 batch is four batches, none of them a
+    sliver of n % 4 real nodes (a full-size optimizer step on a few
+    nodes' gradient)."""
+    import numpy as np
+    from types import SimpleNamespace
+    from repro.configs.vq_gnn_paper import paper_batch_size
+    from repro.graph.batching import epoch_slices
+    b = paper_batch_size(SimpleNamespace(n=n))
+    _, mask = epoch_slices(np.arange(n), b)
+    assert mask.shape[0] == 4
+    assert mask.sum(axis=1).min() >= b - 3

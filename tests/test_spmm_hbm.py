@@ -1,6 +1,6 @@
 """HBM-resident SpMM-ELL variant: parity vs the jnp oracle and the
-VMEM-resident kernel (interpret mode), stripe-index construction, and the
-resident/HBM dispatch heuristic in kernels/ops.py.
+VMEM-resident kernel (interpret mode), the row-DMA gather's edge cases, and
+the resident/HBM dispatch heuristic in kernels/ops.py.
 
 The size sweep deliberately includes ``n_src * f`` shapes above the resident
 VMEM envelope used by the dispatch tests (the envelope is configurable, and
@@ -13,11 +13,9 @@ import jax
 import jax.numpy as jnp
 from numpy.testing import assert_allclose
 
-from repro.graph.batching import make_stripe_index
 from repro.kernels import ops, ref
 from repro.kernels.spmm_ell import spmm_ell_pallas
-from repro.kernels.spmm_ell_hbm import (StripeIndex, spmm_ell_hbm_pallas,
-                                        stripe_index_jnp)
+from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
 
 
 def _case(b, deg, n, f, dtype=jnp.float32, seed=None):
@@ -35,10 +33,10 @@ def _case(b, deg, n, f, dtype=jnp.float32, seed=None):
 
 @pytest.mark.parametrize("b,deg,n,f", [
     (1, 1, 1, 1),            # degenerate minimum
-    (8, 4, 16, 8),           # everything below one tile/stripe
-    (33, 7, 50, 12),         # b and n both non-multiples of bb/stripe
-    (128, 32, 300, 64),      # multi-tile, multi-stripe
-    (200, 9, 3000, 96),      # many stripes per tile
+    (8, 4, 16, 8),           # everything below one tile
+    (33, 7, 50, 12),         # b a non-multiple of bb, f of 128
+    (128, 32, 300, 64),      # multi-tile, wide rows
+    (200, 9, 3000, 96),      # a large source
     (257, 5, 20000, 64),     # above the 4 MiB resident envelope (5 MiB f32)
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -53,20 +51,18 @@ def test_spmm_ell_hbm_sweep(b, deg, n, f, dtype):
     assert_allclose(np.asarray(got), np.asarray(resident), **tol)
 
 
-@pytest.mark.parametrize("bb,stripe", [(8, 8), (16, 64), (128, 512),
-                                       (32, 24)])  # incl. non-pow2 stripe
-def test_spmm_ell_hbm_tile_sizes(bb, stripe):
-    """Non-multiple tile sizes: b % bb != 0 and n % stripe != 0."""
+@pytest.mark.parametrize("bb", [8, 16, 128, 40])
+def test_spmm_ell_hbm_tile_sizes(bb):
+    """Non-multiple tile sizes: b % bb != 0."""
     idx, val, x = _case(53, 6, 210, 16)
-    got = spmm_ell_hbm_pallas(idx, val, x, bb=bb, stripe=stripe,
-                              interpret=True)
+    got = spmm_ell_hbm_pallas(idx, val, x, bb=bb, interpret=True)
     want = ref.spmm_ell(idx, val, x)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
 def test_spmm_ell_hbm_padding_zero_vals():
     """Padding slots carry val == 0; their index may point anywhere valid --
-    they must not contribute, nor force a stripe DMA by themselves."""
+    their row is copied like any other but must not contribute."""
     idx = jnp.array([[5, 0], [2, 1]], jnp.int32)
     val = jnp.array([[1.0, 0.0], [0.5, 0.0]])   # second slot is padding
     x = jnp.arange(12, dtype=jnp.float32).reshape(6, 2)
@@ -79,7 +75,7 @@ def test_spmm_ell_hbm_all_padding_rows():
     """Rows whose every slot is padding (val == 0 everywhere) come out 0."""
     idx, val, x = _case(40, 4, 100, 8)
     val = val.at[7].set(0.0).at[23].set(0.0)
-    got = spmm_ell_hbm_pallas(idx, val, x, bb=16, stripe=32, interpret=True)
+    got = spmm_ell_hbm_pallas(idx, val, x, bb=16, interpret=True)
     want = ref.spmm_ell(idx, val, x)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
     assert np.all(np.asarray(got)[7] == 0) and np.all(np.asarray(got)[23] == 0)
@@ -99,11 +95,11 @@ def _quantize_per_channel(x):
 @pytest.mark.parametrize("b,deg,n,f", [
     (8, 4, 16, 8),
     (33, 7, 50, 12),          # non-multiple tiles
-    (128, 16, 3000, 32),      # many stripes per tile
+    (128, 16, 3000, 32),      # multi-tile
 ])
 def test_spmm_ell_hbm_int8_scale_parity(b, deg, n, f):
-    """int8 stripes DMA natively; the epilogue scale must reproduce the
-    dequantize-up-front result (scale commutes with the neighbor sum)."""
+    """int8 rows with the epilogue scale reproduce the dequantize-up-front
+    result (the scale commutes with the neighbor sum)."""
     idx, val, x = _case(b, deg, n, f)
     q, scale = _quantize_per_channel(x)
     got = spmm_ell_hbm_pallas(idx, val, q, x_scale=scale, interpret=True)
@@ -116,19 +112,19 @@ def test_spmm_ell_hbm_int8_matches_resident_q_kernel():
     resident quantized kernel's on the same operands."""
     idx, val, x = _case(60, 6, 400, 16)
     q, scale = _quantize_per_channel(x)
-    hbm = spmm_ell_hbm_pallas(idx, val, q, x_scale=scale, bb=32, stripe=64,
+    hbm = spmm_ell_hbm_pallas(idx, val, q, x_scale=scale, bb=32,
                               interpret=True)
     resident = spmm_ell_pallas(idx, val, q, x_scale=scale, interpret=True)
     assert_allclose(np.asarray(hbm), np.asarray(resident),
                     rtol=1e-6, atol=1e-6)
 
 
-def test_spmm_ell_hbm_int8_precomputed_index():
-    idx, val, x = _case(75, 8, 400, 32)
+def test_spmm_ell_hbm_int8_ragged_width():
+    """A width that is not a multiple of 128 is lane-padded for the row
+    DMAs and sliced back."""
+    idx, val, x = _case(75, 8, 400, 40)
     q, scale = _quantize_per_channel(x)
-    si = make_stripe_index(np.asarray(idx), x.shape[0], bb=32, stripe=64)
-    got = spmm_ell_hbm_pallas(idx, val, q, si, x_scale=scale,
-                              interpret=True)
+    got = spmm_ell_hbm_pallas(idx, val, q, x_scale=scale, interpret=True)
     want = ref.spmm_ell(idx, val, q.astype(jnp.float32) * scale)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -146,68 +142,40 @@ def test_ops_dispatch_routes_hbm_int8(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# stripe index: host builder vs in-jit fallback
+# the row-DMA gather: one copy per slot, every slot copied
 # ---------------------------------------------------------------------------
 
-def test_stripe_index_host_matches_jnp():
-    idx, val, x = _case(90, 5, 700, 8)
-    mask = (val != 0).astype(np.float32)
-    host = make_stripe_index(np.asarray(idx), x.shape[0],
-                             mask=np.asarray(mask), bb=32, stripe=128)
-    injit = stripe_index_jnp(idx, val, x.shape[0], bb=32, stripe=128)
-    assert host.bb == injit.bb and host.stripe == injit.stripe
-    assert np.array_equal(np.asarray(host.counts), np.asarray(injit.counts))
-    for t in range(host.ids.shape[0]):
-        c = int(host.counts[t])
-        assert np.array_equal(np.asarray(host.ids[t, :c]),
-                              np.asarray(injit.ids[t, :c]))
-
-
-def test_spmm_ell_hbm_precomputed_stripe_index():
-    """Pack-time host index and the in-jit fallback give identical output."""
-    idx, val, x = _case(75, 8, 400, 32)
-    si = make_stripe_index(np.asarray(idx), x.shape[0], bb=32, stripe=64)
-    got = spmm_ell_hbm_pallas(idx, val, x, si, interpret=True)
-    auto = spmm_ell_hbm_pallas(idx, val, x, bb=32, stripe=64, interpret=True)
+@pytest.mark.parametrize("case", [
+    "repeated_ids",     # one source row feeds many slots of one tile
+    "last_row",         # every slot reads the source's last row
+    "pad_to_last_row",  # padding slots point at the last row, val 0
+    "single_source",    # n_src == 1
+    "deg_one",          # D == 1: one DMA wave per tile
+    "tile_tail",        # b one past a tile: the tail tile is mostly padding
+    "wide_sparse",      # wide rows, mostly padding slots
+])
+def test_spmm_ell_hbm_gather_cases(case):
+    idx, val, x = _case(45, 6, 90, 24, seed=7)
+    if case == "repeated_ids":
+        idx = jnp.full_like(idx, 17)
+    elif case == "last_row":
+        idx = jnp.full_like(idx, 89)
+    elif case == "pad_to_last_row":
+        pad = jnp.arange(6)[None, :] >= 3
+        idx = jnp.where(pad, 89, idx)
+        val = jnp.where(pad, 0.0, val)
+    elif case == "single_source":
+        idx, x = jnp.zeros_like(idx), x[:1]
+    elif case == "deg_one":
+        idx, val = idx[:, :1], val[:, :1]
+    elif case == "tile_tail":
+        idx, val, x = _case(17, 5, 90, 24, seed=8)
+    else:
+        idx, val, x = _case(40, 12, 500, 256, seed=9)
+        val = jnp.where(jnp.arange(12)[None, :] % 4 == 0, val, 0.0)
+    got = spmm_ell_hbm_pallas(idx, val, x, bb=16, interpret=True)
     want = ref.spmm_ell(idx, val, x)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
-    assert_allclose(np.asarray(got), np.asarray(auto), rtol=0, atol=0)
-
-
-def test_stripe_index_mismatched_tiling_raises():
-    idx, val, x = _case(64, 4, 256, 8)
-    bad = make_stripe_index(np.asarray(idx)[:32], x.shape[0],
-                            bb=8, stripe=64)   # built for 4 tiles, not 8
-    with pytest.raises(ValueError, match="tiles"):
-        spmm_ell_hbm_pallas(idx, val, x, bad, interpret=True)
-
-
-def test_stripe_index_mismatched_n_src_raises():
-    idx, val, x = _case(64, 4, 256, 8)
-    bad = make_stripe_index(np.asarray(idx) % 128, 128, bb=8, stripe=64)
-    with pytest.raises(ValueError, match="n_src"):
-        spmm_ell_hbm_pallas(idx, val, x, bad, interpret=True)
-
-
-def test_stripe_index_static_shapes_across_batches():
-    """Successive packs of the same dataset shapes must produce identical
-    StripeIndex shapes (else jit'd train steps retrace every batch)."""
-    rng = np.random.default_rng(0)
-    shapes = set()
-    for _ in range(5):
-        idx = rng.integers(0, 777, (60, 6))
-        si = make_stripe_index(idx, 777, bb=16, stripe=64)
-        shapes.add((si.ids.shape, si.counts.shape, si.bb, si.stripe))
-    assert len(shapes) == 1
-
-
-def test_stripe_index_max_stripes_cap():
-    rng = np.random.default_rng(1)
-    idx = rng.integers(0, 1000, (32, 8))
-    si = make_stripe_index(idx, 1000, bb=8, stripe=64, max_stripes=8 * 8)
-    assert si.ids.shape[1] == 64
-    with pytest.raises(ValueError, match="max_stripes"):
-        make_stripe_index(idx, 1000, bb=8, stripe=8, max_stripes=2)
 
 
 # ---------------------------------------------------------------------------
@@ -268,9 +236,9 @@ def test_ops_dispatch_routes_hbm(monkeypatch):
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
-def test_full_graph_apply_with_stripe_index(monkeypatch):
+def test_full_graph_apply_through_hbm(monkeypatch):
     """GCN full-graph oracle is unchanged when routed through the HBM
-    variant with a pack-time stripe index."""
+    variant."""
     from repro.graph.batching import full_operands
     from repro.graph.structure import build_graph
     from repro.nn.gnn_layers import GCN
@@ -291,8 +259,6 @@ def test_full_graph_apply_with_stripe_index(monkeypatch):
     # now force every spmm through the HBM Pallas kernel (interpret mode)
     monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
     monkeypatch.setenv("REPRO_SPMM_VARIANT", "hbm")
-    ops_hbm = full_operands(g, stripe_index=True, stripe_bb=32, stripe=32)
-    assert isinstance(ops_hbm.stripe_index, StripeIndex)
-    y_hbm = GCN.full_apply(p, x, ops_hbm, jax.nn.relu)
+    y_hbm = GCN.full_apply(p, x, full_operands(g), jax.nn.relu)
     assert_allclose(np.asarray(y_hbm), np.asarray(y_plain),
                     rtol=1e-5, atol=1e-5)

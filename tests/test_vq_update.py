@@ -5,7 +5,6 @@ edge shapes, the optional min-distance output of vq_assign, and the
 codebook.update equivalence old-path (one-hot einsum) vs fused-path --
 including the dead-codeword revival branch.
 """
-import os
 
 import jax
 import jax.numpy as jnp
@@ -213,11 +212,6 @@ def test_update_fused_pallas_path_matches_cpu_path(monkeypatch):
     _states_allclose(pls_state, cpu_state)
 
 
-@pytest.mark.skipif(
-    os.environ.get("REPRO_FORCE_PALLAS", "0") == "1",
-    reason="end-to-end trainer test: reverse-mode AD has no rule for the "
-    "interpret-mode SpMM pallas_call; kernel parity is covered above and "
-    "this test runs in tier-1")
 def test_train_vq_small_graph_pads_single_batch(monkeypatch):
     """batch_size > n used to yield NO mini-batch (the tail-drop bug, and a
     jnp.mean(None) crash risk in the vq_err monitor).  epoch_slices now
