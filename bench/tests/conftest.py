@@ -1,0 +1,13 @@
+"""Puts the repository root (for ``bench``) and ``src`` (for the program)
+on the path of the benchmark's own tests:
+
+    python -m pytest bench/tests -q
+"""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
