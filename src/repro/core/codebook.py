@@ -233,6 +233,7 @@ def assign(state: CodebookState, feats: jax.Array, grads: jax.Array,
     return jax.vmap(kops.vq_assign)(v, state.codewords_w)
 
 
+@jax.named_scope("vq_update")
 def assign_features_only(state: CodebookState, feats: jax.Array, f_feat: int,
                          cfg: CodebookConfig) -> jax.Array:
     """Assignment using only the feature half (inference / inductive setting).
@@ -254,6 +255,7 @@ def assign_features_only(state: CodebookState, feats: jax.Array, f_feat: int,
 # VQ-Update (Algorithm 2)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("vq_update")
 def update(state: CodebookState, feats: jax.Array, grads: jax.Array,
            cfg: CodebookConfig, *,
            axis_name: Optional[str] = None

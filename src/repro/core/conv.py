@@ -99,6 +99,7 @@ def branch_histogram(ids: jax.Array, k: int,
         w, flat, num_segments=nb * k).reshape(nb, k)
 
 
+@jax.named_scope("vq_update")
 def refresh_assignment(state: LayerVQState, batch_ids: jax.Array,
                        new_assign: jax.Array) -> LayerVQState:
     """Scatter the refreshed batch assignments into the global table
@@ -238,6 +239,7 @@ def fixed_edge_values(kind: str, pack: MinibatchPack,
     return in_vals, out_vals, rev_vals, self_vals
 
 
+@jax.named_scope("edge_norm")
 def fixed_conv_operands(kind: str, pack: MinibatchPack,
                         degrees: jax.Array) -> tuple[ConvOperands, jax.Array]:
     in_vals, out_vals, rev_vals, self_vals = fixed_edge_values(

@@ -268,11 +268,14 @@ def approx_message_passing(ops_: ConvOperands, x_b: jax.Array,
     ``[b, Dr, f_grad]`` tensor, and the backward streams Eq. 7 through the
     same fused context kernel the forward uses.
     """
+    # named scopes label the parts in the HLO metadata (xprof), nothing else
     if inject:
-        x_b = inject_context_grad(
-            x_b, ops_.rev_vals, ops_.rev_ids,
-            jax.lax.stop_gradient(grad_codewords), assignment, w)
-    m = intra_messages(ops_.in_pos, ops_.in_vals, x_b)
-    m = m + context_messages_reconstruct(
-        ops_.out_vals, ops_.out_ids, feat_codewords, assignment)
-    return m
+        with jax.named_scope("context"):
+            x_b = inject_context_grad(
+                x_b, ops_.rev_vals, ops_.rev_ids,
+                jax.lax.stop_gradient(grad_codewords), assignment, w)
+    with jax.named_scope("in_batch"):
+        m = intra_messages(ops_.in_pos, ops_.in_vals, x_b)
+    with jax.named_scope("context"):
+        return m + context_messages_reconstruct(
+            ops_.out_vals, ops_.out_ids, feat_codewords, assignment)

@@ -45,6 +45,9 @@ from jax.sharding import Mesh, NamedSharding
 
 from repro.core.codebook import CodebookConfig
 from repro.distributed import sharding as shd
+from repro.distributed.data_parallel import (ShardedGraphState,
+                                             vq_infer_epoch_sharded,
+                                             vq_serve_batch_sharded)
 from repro.distributed.quantization import tree_bytes
 from repro.graph.batching import (build_epoch_plan, full_operands,
                                   inference_slices)
@@ -86,7 +89,6 @@ class GNNServer:
         self.f_out = _layer_out_dims(cfg)[-1][1]
         self.sstate = None
         if shard_graph:
-            from repro.distributed.data_parallel import ShardedGraphState
             self.sstate = ShardedGraphState(mesh, self.plan, self.x,
                                             self.ops.degrees)
             # the replicated copies exist only transiently at build time
@@ -115,8 +117,6 @@ class GNNServer:
         t0 = time.time()
         ids, sm = inference_slices(self.g.n, self.batch)
         if self.sstate is not None:
-            from repro.distributed.data_parallel import \
-                vq_infer_epoch_sharded
             _, self.vq = vq_infer_epoch_sharded(
                 self.sstate, self.params, self.vq,
                 jnp.asarray(ids.astype(np.int32)), jnp.asarray(sm),
@@ -145,18 +145,22 @@ class GNNServer:
             raise ValueError(
                 f"serve step needs exactly {self.batch} id slots, got "
                 f"{len(bids)} (use serve() for arbitrary request sizes)")
-        ids_d = jnp.asarray(bids.astype(np.int32))
-        if self.sstate is not None:
-            from repro.distributed.data_parallel import \
-                vq_serve_batch_sharded
-            y = vq_serve_batch_sharded(self.sstate, self.params, self.vq,
-                                       ids_d, self.cfg)
+        # three host spans that tile the step, on the profiler's clock (one
+        # inactive TraceMe each when no trace runs): the id put, the
+        # enqueue of the jitted step, and the wait for and copy of its rows
+        with jax.profiler.TraceAnnotation("program.serve.put"):
+            ids_d = jnp.asarray(bids.astype(np.int32))
+            if self.ids_sharding is not None:
+                ids_d = jax.device_put(ids_d, self.ids_sharding)
+        with jax.profiler.TraceAnnotation("program.serve.dispatch"):
+            if self.sstate is not None:
+                y = vq_serve_batch_sharded(self.sstate, self.params,
+                                           self.vq, ids_d, self.cfg)
+            else:
+                y = vq_serve_batch(self.params, self.vq, self.plan, ids_d,
+                                   self.x, self.ops.degrees, self.cfg)
+        with jax.profiler.TraceAnnotation("program.serve.fetch"):
             return np.asarray(y)
-        if self.ids_sharding is not None:
-            ids_d = jax.device_put(ids_d, self.ids_sharding)
-        y = vq_serve_batch(self.params, self.vq, self.plan, ids_d, self.x,
-                           self.ops.degrees, self.cfg)
-        return np.asarray(y)
 
     def serve(self, node_ids: np.ndarray) -> np.ndarray:
         """Serve one request of arbitrary size (pads the tail step by

@@ -320,7 +320,8 @@ def _vq_step_body(params, vq_states, opt_state, pack: MinibatchPack,
     if axis_name is not None:
         loss = jax.lax.psum(loss, axis_name)
         gparams = psum_tree(gparams, axis_name)
-    new_params, new_opt = opt.update(gparams, opt_state, params)
+    with jax.named_scope("optimizer"):
+        new_params, new_opt = opt.update(gparams, opt_state, params)
 
     # ---- Alg. 1 line 15-16: VQ update + assignment synchronization ----
     # cbm.update is fused (one distance pass per branch, codebook.py module
@@ -669,7 +670,8 @@ def _full_step_body(params, opt_state, x, ops_: FullGraphOperands,
         return link_loss(out, pos_pairs, neg_pairs, pair_mask)
 
     loss, grads = jax.value_and_grad(loss_fn)(params)
-    new_params, new_opt = opt.update(grads, opt_state, params)
+    with jax.named_scope("optimizer"):
+        new_params, new_opt = opt.update(grads, opt_state, params)
     return new_params, new_opt, loss
 
 
