@@ -27,10 +27,11 @@ Two entry points (the ``benchmarks/run.py`` convention):
       * ``context/a4_*`` -- the nibble-packed assignment tier (DESIGN.md
         section 15): fused-kernel parity on a packed table + fp8
         codewords, exact packed-table bytes (<= 0.5x uint8, <= 0.125x
-        int32), the fused-dispatch crossover extension (>= 2x the uint8
-        tier's node count, probed from ``context_ell_variant`` itself),
-        and the loop-vs-fused regime timing at a budget between the two
-        thresholds.
+        int32), and the fused/loop crossover: the table is gathered in
+        XLA and never enters VMEM, so the smallest VMEM budget at which a
+        term dispatches fused (bisected on the traced dispatch) must be
+        the same for int32, uint8 and packed tables at any node count
+        (``crossover_spread`` == 0).
       * interpret-mode kernel parity vs the oracle (maxerr), the
         bench_kernels convention.
   run() -> legacy (name, us, derived) tuples for the CSV printer.
@@ -49,17 +50,16 @@ from repro.core.message_passing import (ConvOperands, approx_message_passing,
                                         intra_messages, reconstruct)
 from repro.distributed.quantization import (PackedAssignment,
                                             quantize_codewords, tree_bytes)
+from repro.analysis.trace_count import CONTEXT_TRACE_COUNT
 from repro.kernels import ops, ref
 from repro.kernels.context_ell import context_ell_pallas
 
 _FWD_GATE = {"fused_over_loop": 1.0 / 1.5}   # fused must be >= 1.5x
 _RES_GATE = {"residual_ratio": 0.5}          # streaming residual <= 0.5x
-_INT8_GATE = {"int8_over_fp32": 1.0 / 1.3}   # int8 path must be >= 1.3x
 _MEM_GATE = {"int8_operand_ratio": 0.5}      # int8 operand bytes <= 0.5x
-_A4_GATE = {"a4_over_uint8": 1.0 / 1.3}      # packed path must be >= 1.3x
 _A4_MEM_GATE = {"a4_over_uint8_bytes": 0.5,  # packed table <= 0.5x uint8
                 "a4_over_int32_bytes": 0.125}    # ... <= 0.125x int32
-_A4_CROSS_GATE = {"uint8_over_a4_crossover": 0.5}    # crossover n >= 2x
+_A4_CROSS_GATE = {"crossover_spread": 0.0}   # one crossover for all tables
 
 
 def _context_case(b, deg, n, nb, k, f_blk, seed=0):
@@ -200,36 +200,13 @@ def run_structured() -> list[dict]:
            {"maxerr": float(jnp.abs(got - want).max())},
            tolerance={"maxerr": 1e-3})
 
-    # --- the ISSUE 7 serving-shape gate: int8 operands vs the fp32 path
-    # at the VMEM-envelope crossover.  With a 1 MiB dispatch budget the
-    # fp32 [4, 100k] int32 assignment table (1.6 MiB) exceeds the fused
-    # kernel's envelope -> the dispatch layer takes the eager per-branch
-    # loop; the uint8 table (0.4 MiB) still fits -> ONE fused dispatch.
-    # That dispatch-regime difference IS the int8 claim (the table is the
-    # envelope lever), and it is exactly what ``context_ell_variant``
-    # decides on a real TPU -- the bench times each regime's op-dispatch
-    # cost (the existing fused_vs_loop convention: dispatch-level, eager
-    # loop vs single fused call; within one jit the forms converge on CPU)
+    # --- int8 operand bytes against the fp32 tables at a serving shape ---
     b, deg, n, nb, k, f_blk = 4096, 16, 100_000, 4, 256, 8
     ids, val, assign, cw = _context_case(b, deg, n, nb, k, f_blk)
     qcw = quantize_codewords(cw)
     ua = assign.astype(jnp.uint8)
-    ops.configure_context_dispatch(reset=True, vmem_budget_mb=1.0)
-    v32 = ops.context_ell_variant(n, nb, assign.dtype.itemsize)
-    v8 = ops.context_ell_variant(n, nb, ua.dtype.itemsize)
-    assert v32 == "loop" and v8 == "fused", (v32, v8)
-    us_fp32 = _time(_legacy_loop, ids, val, assign, cw)
-    us_int8 = _time(ops.context_ell, ids, val, ua, qcw)
-    ops.configure_context_dispatch(reset=True)
     fp32_bytes = assign.size * 4 + cw.size * 4
     int8_bytes = ua.size + qcw.q.size + qcw.scale.size * 4
-    _entry(rows, f"context/int8_vs_fp32_dispatch/nb{nb}_k{k}_b{b}", us_int8,
-           {"us_int8": us_int8, "us_fp32": us_fp32,
-            "speedup": us_fp32 / max(us_int8, 1e-9),
-            "int8_over_fp32": us_int8 / max(us_fp32, 1e-9),
-            "fp32_variant_at_1mb": 1.0 if v32 == "loop" else 0.0,
-            "int8_variant_at_1mb": 0.0 if v8 == "fused" else 1.0},
-           tolerance=_INT8_GATE)
     _entry(rows, f"context/int8_operand_bytes/nb{nb}_k{k}_n100k", 0.0,
            {"fp32_mb": fp32_bytes / 2**20, "int8_mb": int8_bytes / 2**20,
             "int8_operand_ratio": int8_bytes / fp32_bytes},
@@ -237,8 +214,8 @@ def run_structured() -> list[dict]:
 
     # --- nibble-packed int4 assignment tables + fp8 codewords (the +a4 /
     # fp8 tiers, DESIGN.md section 15).  Parity first, the int8 convention:
-    # the fused kernel on a PACKED uint4 table (shift/mask unpack inside
-    # the kernel) + fp8 codewords must reproduce the oracle on the
+    # the fused kernel on a PACKED uint4 table (unpacked by its XLA-side
+    # gather) + fp8 codewords must reproduce the oracle on the
     # dequantized tables exactly ---
     ids, val, assign, cw = _context_case(512, 8, 5000, 4, 16, 8)
     qcw8 = quantize_codewords(cw, dtype=jnp.float8_e4m3fn)
@@ -271,51 +248,45 @@ def run_structured() -> list[dict]:
             "a4_over_int32_bytes": a4_bytes / i32_bytes},
            tolerance=_A4_MEM_GATE)
 
-    # --- the tentpole dispatch claim: at a fixed VMEM budget the packed
-    # table's fused-dispatch crossover sits at >= 2x the uint8 tier's
-    # node count (found by probing ``context_ell_variant`` itself, so the
-    # gate can never drift from the shipped heuristic).  At a budget
-    # between the two thresholds ([4, 200k]: uint8 0.76 MiB > 0.5 MiB,
-    # packed 0.38 MiB < 0.5 MiB) the uint8 table falls back to the
-    # per-branch loop while the packed table keeps the ONE fused dispatch;
-    # the timing compares those regimes at the op-dispatch level (the
-    # int8_vs_fp32 convention: eager ``_context_ell_loop`` vs one
-    # ``ops.context_ell`` call), both on the SAME int8 codewords so the
-    # row isolates the assignment-packing lever ---
-    def _crossover(itemsize, dt):
-        lo, hi = 1, 1
-        while ops.context_ell_variant(hi, nb, itemsize, dtype=dt) == "fused":
-            lo, hi = hi, hi * 2
-        while hi - lo > 1:
-            mid = (lo + hi) // 2
-            if ops.context_ell_variant(mid, nb, itemsize, dtype=dt) == "fused":
-                lo = mid
-            else:
-                hi = mid
-        return lo
+    # --- the crossover gate: the dispatch charges what the fused kernel
+    # holds (codebook tables, id and value blocks), never the [nb, n]
+    # assignment table.  The smallest budget at which a term of 4 x [16, 8]
+    # codewords over 16 slots dispatches fused, bisected on the traced
+    # dispatch itself (so the gate cannot drift from the shipped rule),
+    # must not depend on the table's storage or its node count ---
+    sds = jax.ShapeDtypeStruct
+    cnb, ck, cf, cdeg = 4, 16, 8, 16
 
-    ops.configure_context_dispatch(reset=True, vmem_budget_mb=0.5)
-    cross_u8 = _crossover(1, jnp.uint8)
-    cross_a4 = _crossover(0.5, jnp.uint4)
-    v8 = ops.context_ell_variant(n, nb, 1, dtype=jnp.uint8)
-    v4 = ops.context_ell_variant(n, nb, 0.5, dtype=jnp.uint4)
-    assert v8 == "loop" and v4 == "fused", (v8, v4)
-    us_u8 = _time(lambda a, v_, s, q, sc: ops._context_ell_loop(
-        a, v_, s, q, None, sc), ids, val, ua, qcw.q, qcw.scale)
-    us_a4 = _time(ops.context_ell, ids, val, pa, qcw)
-    ops.configure_context_dispatch(reset=True)
-    _entry(rows, f"context/a4_vs_uint8_dispatch/nb{nb}_k{k}_b{b}", us_a4,
-           {"us_a4": us_a4, "us_uint8": us_u8,
-            "speedup": us_u8 / max(us_a4, 1e-9),
-            "a4_over_uint8": us_a4 / max(us_u8, 1e-9),
-            "uint8_variant_at_0p5mb": 1.0 if v8 == "loop" else 0.0,
-            "a4_variant_at_0p5mb": 0.0 if v4 == "fused" else 1.0},
-           tolerance=_A4_GATE)
-    _entry(rows, f"context/a4_crossover/nb{nb}_budget0p5mb", 0.0,
-           {"crossover_n_uint8": float(cross_u8),
-            "crossover_n_a4": float(cross_a4),
-            "extension": cross_a4 / max(cross_u8, 1),
-            "uint8_over_a4_crossover": cross_u8 / max(cross_a4, 1)},
+    def _fused_at(table, budget_mb):
+        ops.configure_context_dispatch(reset=True, vmem_budget_mb=budget_mb)
+        before = CONTEXT_TRACE_COUNT.snapshot()
+        # a fresh function per probe: a trace of the same one is cached
+        jax.make_jaxpr(lambda *a: ops._context_ell_kernel(*a))(
+            sds((256, cdeg), jnp.int32), sds((256, cdeg), jnp.float32),
+            table, sds((cnb, ck, cf), jnp.float32), None, None)
+        return CONTEXT_TRACE_COUNT.delta(before)["context.fused"] > 0
+
+    def _crossover_mb(table):
+        lo, hi = 2.0 ** -10, 64.0        # loop at lo, fused at hi (MiB)
+        for _ in range(24):
+            mid = (lo * hi) ** 0.5
+            lo, hi = (lo, mid) if _fused_at(table, mid) else (mid, hi)
+        return hi
+
+    cross = {}
+    try:
+        for cn in (1_000, 10_000_000):
+            for tag, table in (
+                    ("int32", sds((cnb, cn), jnp.int32)),
+                    ("uint8", sds((cnb, cn), jnp.uint8)),
+                    ("a4", PackedAssignment(sds((cnb, cn // 2), jnp.uint8),
+                                            cn))):
+                cross[f"crossover_mb_{tag}_n{cn}"] = _crossover_mb(table)
+    finally:
+        ops.configure_context_dispatch(reset=True)
+    _entry(rows, f"context/a4_crossover/nb{cnb}_k{ck}", 0.0,
+           {**cross, "crossover_spread":
+            max(cross.values()) / min(cross.values()) - 1.0},
            tolerance=_A4_CROSS_GATE)
 
     # --- streaming vs materialized Eq. 7 backward: wall time of the full

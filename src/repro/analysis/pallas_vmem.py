@@ -20,8 +20,11 @@ their array bytes).
             heuristic agrees with the computed footprints -- below the
             SpMM crossover the resident kernel's working set must fit the
             envelope, above it the whole-matrix-in-VMEM kernel must NOT
-            be chosen (ditto fused-vs-loop for the context kernel, where
-            "one fused dispatch" is the below-crossover signature).
+            be chosen.  The context probes grow the codebook (k), the
+            quantity the fused lookup kernel holds in VMEM: below its
+            crossover "one fused dispatch" within the envelope, whose
+            traced footprint the rule's charge (``context_ell.vmem_bytes``)
+            covers; above it no fused dispatch.
 
 The crossover probes re-derive their shapes from the LIVE budgets
 (``_vmem_budget_mb``), so a deployment that overrides
@@ -36,6 +39,7 @@ from repro.analysis import Finding
 from repro.analysis import registry
 from repro.analysis.jaxpr_checks import kernel_name, pallas_calls
 from repro.distributed.quantization import dtype_nbits
+from repro.kernels.context_ell import vmem_bytes as context_vmem_bytes
 
 
 def _block_dims(bm) -> tuple:
@@ -177,34 +181,41 @@ def _crossover_findings() -> list[Finding]:
             f"dispatcher still VMEM-blocks the whole source matrix "
             f"({[kernel_name(e) for e in resident_x]})"))
 
-    def ctx_probe(n, nb):
-        k, fb = 8, 4
+    nb, fb = 4, 4
+
+    def ctx_probe(k):
         args = (sds((b, deg), jnp.int32), sds((b, deg), jnp.float32),
-                sds((nb, n), jnp.int32), sds((nb, k, fb), jnp.float32))
+                sds((nb, 1000), jnp.int32), sds((nb, k, fb), jnp.float32))
         with registry.forced_pallas():
             return jax.make_jaxpr(lambda *a: kops.context_ell(*a))(*args)
 
     cbudget = int(kops._vmem_budget_mb(
         kops._context_overrides,
         "REPRO_CONTEXT_VMEM_BUDGET_MB") * 2 ** 20)
-    nb = 4
-    n_below = int(cbudget * 0.9) // (nb * 4)
-    n_above = int(cbudget * 1.2) // (nb * 4)
-    below = pallas_calls(ctx_probe(n_below, nb))
+    # the rule's charge: the double-buffered lookup tables, 2 * nb * fb
+    # f32 per codeword, on top of the k-independent id, value and output
+    # blocks
+    per_k = 2 * nb * fb * 4
+    fixed = context_vmem_bytes(nb, 128, fb, deg) - 128 * per_k
+    k_below = max(1, (int(cbudget * 0.9) - fixed) // per_k)
+    k_above = (int(cbudget * 1.2) - fixed) // per_k + 1
+    below = pallas_calls(ctx_probe(k_below))
+    charged = context_vmem_bytes(nb, k_below, fb, deg)
     if (len(below) != 1 or "context" not in kernel_name(below[0])
-            or dispatch_footprint(below[0]) > envelope):
+            or dispatch_footprint(below[0]) > min(envelope, charged)):
         findings.append(Finding(
             "REPRO203", "<crossover:context_ell>", 0,
-            f"below the context crossover ([{nb}, {n_below}] int32) "
-            f"expected ONE fused dispatch within the envelope, traced "
+            f"below the context crossover ([{nb}, {k_below}, {fb}] "
+            f"codewords) expected ONE fused dispatch within the envelope "
+            f"and within the rule's {charged}-byte charge, traced "
             f"{[(kernel_name(e), dispatch_footprint(e)) for e in below]}"
         ))
-    above = pallas_calls(ctx_probe(n_above, nb))
+    above = pallas_calls(ctx_probe(k_above))
     if any("context" in kernel_name(e) for e in above):
         findings.append(Finding(
             "REPRO203", "<crossover:context_ell>", 0,
-            f"above the context crossover ([{nb}, {n_above}] int32) the "
-            f"fused kernel is still dispatched"))
+            f"above the context crossover ([{nb}, {k_above}, {fb}] "
+            f"codewords) the fused kernel is still dispatched"))
     return findings
 
 

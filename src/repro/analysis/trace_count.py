@@ -9,7 +9,9 @@ historical ``INFER_TRACE_COUNT["layer"]`` indexing working everywhere.
 
 Shared by the inference-executor entry points (``models/gnn.py``), their
 tests, and the ``repro.analysis`` jaxpr pass (which asserts the deltas
-while tracing the registered entry points on tiny specs).
+while tracing the registered entry points on tiny specs).  The kernel
+dispatch (``kernels/ops.py``) counts the context terms it traces per
+variant the same way.
 """
 from __future__ import annotations
 
@@ -34,3 +36,7 @@ class TraceCounter(dict):
 # per-layer scan body (replicated + row-sharded), "serve" once per trace
 # of the one-compile serving step.
 INFER_TRACE_COUNT = TraceCounter(layer=0, serve=0)
+
+# The context dispatch's counters: "context.fused" or "context.loop" bumps
+# once per context term traced on the kernel path (kernels/ops.py).
+CONTEXT_TRACE_COUNT = TraceCounter({"context.fused": 0, "context.loop": 0})

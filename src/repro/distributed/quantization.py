@@ -228,8 +228,8 @@ class PackedAssignment:
 
     ``packed`` holds two node ids per byte along the node axis
     ([n_branches, ceil(n/2)] uint8) -- 0.5 bytes/entry, 8x smaller than
-    the int32 table and half the uint8 one, which is what doubles the
-    fused-dispatch VMEM crossover again (DESIGN.md section 15).  The node
+    the int32 table and half the uint8 one in HBM (DESIGN.md section
+    15); the context kernel's XLA-side gather unpacks it.  The node
     count ``n`` is static pytree aux data, so the wrapper flows through
     jit / scan / shard_map like any array leaf.
     """

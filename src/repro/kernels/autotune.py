@@ -171,62 +171,58 @@ def tuned_spmm(n_src: int, f: int, itemsize: int = 4, dtype=None
     return cfg
 
 
-def tuned_context(n_nodes: int, n_branches: int, itemsize: float = 4,
-                  dtype=None) -> Optional[dict[str, Any]]:
-    """{'variant': 'fused'|'loop', 'bb': int} for an
-    [n_branches, n_nodes] assignment table, or None when autotuning is off.
+def tuned_context(n_branches: int, k: int, f_blk: int, deg: int,
+                  dtype=jnp.float32) -> Optional[dict[str, Any]]:
+    """{'variant': 'fused'|'loop', 'bb': int} for a context term of
+    ``n_branches`` ``[k, f_blk]`` codeword tables read through ``deg``
+    slots per row -- the shape the dispatch rule charges -- or None when
+    autotuning is off.
 
-    ``dtype`` keys the cache entry by the table's storage dtype; pass
-    ``jnp.uint4`` for nibble-packed tables (``PackedAssignment``, itemsize
-    0.5) -- the measurement then races the packed fused kernel against the
-    loop fallback on the unpacked uint8 table, matching what dispatch
-    would actually run in each regime."""
+    ``dtype`` keys the cache entry by the codebook's storage dtype (f32,
+    int8 or float8_e4m3fn); a narrower codebook races with its per-channel
+    scales, as dispatch would run it.  The assignment table is not part of
+    the key: XLA gathers it ahead of either variant."""
     if not enabled():
         return None
-    if dtype is None:
-        dtype = (jnp.uint4 if itemsize == 0.5
-                 else jnp.uint8 if itemsize == 1 else jnp.int32)
     dtype = jnp.dtype(dtype)
-    packed = dtype == jnp.dtype(jnp.uint4)
-    key = cache_key("context", (n_nodes, n_branches), dtype)
+    key = cache_key("context", (n_branches, k, f_blk, deg), dtype)
     hit = lookup(key)
     if hit is not None:
         return hit
 
-    from repro.distributed.quantization import PackedAssignment
+    from repro.distributed.quantization import quantize_codewords
     from repro.kernels.context_ell import context_ell_pallas
     from repro.kernels import ops
     from repro.kernels.spmm_ell import spmm_ell_pallas
-    b, deg, f_blk = min(_ROW_CLAMP, 256), 16, 8
-    k = 16 if packed else 64
-    n = min(int(n_nodes), _SRC_CLAMP)
-    nb = int(n_branches)
+    b, n = min(_ROW_CLAMP, 256), 1000
+    nb, k, f_blk, deg = int(n_branches), int(k), int(f_blk), int(deg)
     rng = jax.random.PRNGKey(0)
     ki, kv, ka, kc = jax.random.split(rng, 4)
     ids = jax.random.randint(ki, (b, deg), 0, n, jnp.int32)
     val = jax.random.uniform(kv, (b, deg), jnp.float32)
     assign = jax.random.randint(ka, (nb, n), 0, k, jnp.int32)
-    if packed:
-        fused_a: Any = PackedAssignment.pack(assign)
-        loop_a = assign.astype(jnp.uint8)
-    else:
-        fused_a = loop_a = assign.astype(dtype)
     cw = jax.random.normal(kc, (nb, k, f_blk), jnp.float32)
+    scale = None
+    if dtype != jnp.dtype(jnp.float32):
+        qt = quantize_codewords(cw, dtype=dtype)
+        cw, scale = qt.q, qt.scale
     interp = ops.interpret_mode()
 
     def loop(i, v, a, c):
         # the per-branch fallback, built on the kernel directly (module doc)
-        bi = a.astype(jnp.int32)[:, i]
+        bi = a[:, i]
         return jnp.concatenate(
-            [spmm_ell_pallas(bi[j], v, c[j], interpret=interp)
-             for j in range(c.shape[0])], axis=-1)
+            [spmm_ell_pallas(bi[j], v, c[j], interpret=interp,
+                             x_scale=None if scale is None else scale[j])
+             for j in range(nb)], axis=-1)
 
     timings: dict[tuple[str, int], float] = {}
     for bb in (64, 128, 256):
         timings[("fused", bb)] = _time(
             lambda i, v, a, c, _bb=bb: context_ell_pallas(
-                i, v, a, c, bb=_bb, interpret=interp), ids, val, fused_a, cw)
-    timings[("loop", 128)] = _time(loop, ids, val, loop_a, cw)
+                i, v, a, c, cw_scale=scale, bb=_bb, interpret=interp),
+            ids, val, assign, cw)
+    timings[("loop", 128)] = _time(loop, ids, val, assign, cw)
     (variant, bb), _ = min(timings.items(), key=lambda kv_: kv_[1])
     cfg = {"variant": variant, "bb": int(bb)}
     record(key, cfg)

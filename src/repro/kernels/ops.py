@@ -24,12 +24,15 @@ Production notes (TPU):
     through it.
   * ``context_ell`` (DESIGN.md section 10) fuses the multi-branch
     VQ-context term -- Eq. 6 forward and the streaming Eq. 7 backward --
-    into ONE kernel dispatch regardless of n_branches; dispatch falls back
-    to the per-branch loop when the [n_branches, n] assignment table
-    exceeds the VMEM budget (a rule that predates the XLA-side assignment
-    gather of the current kernel; REPRO_CONTEXT_VARIANT /
+    into ONE kernel dispatch regardless of n_branches, a codeword lookup
+    in registers (a one-hot for wide branches); dispatch falls back to the
+    per-branch loop only when what that kernel holds in VMEM (the
+    codebook's tables plus the double-buffered id and value blocks,
+    ``context_ell.vmem_bytes``) exceeds the VMEM budget.  The ``[n_branches, n]`` assignment table is
+    gathered in XLA and never charged (REPRO_CONTEXT_VARIANT /
     REPRO_CONTEXT_VMEM_BUDGET_MB or ``configure_context_dispatch``).  It
-    carries the same oracle-VJP custom rule.
+    carries the same oracle-VJP custom rule; ``CONTEXT_TRACE_COUNT``
+    counts the terms traced per variant.
   * operand precision tiers (DESIGN.md sections 13/15): codewords may be
     int8 or float8_e4m3fn ``QTensor`` snapshots and assignment tables
     uint8 (k <= 256) or nibble-packed ``PackedAssignment`` (k <= 16);
@@ -47,11 +50,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import hostenv
+from repro.analysis.trace_count import CONTEXT_TRACE_COUNT
 from repro.kernels import autotune, ref
 from repro.kernels.vq_assign import vq_assign_pallas
 from repro.kernels.vq_update import vq_assign_update_pallas
-from repro.kernels.context_ell import context_ell_pallas
-from repro.kernels.spmm_ell import spmm_ell_pallas
+from repro.kernels.context_ell import context_ell_pallas, vmem_bytes
+from repro.kernels.spmm_ell import LANES, lane_tile, spmm_ell_pallas
 from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.vq_attention import vq_attention_decode_pallas
@@ -342,11 +346,10 @@ def configure_context_dispatch(variant: Optional[str] = None,
                                reset: bool = False) -> None:
     """Override context_ell dispatch: variant in {'auto', 'fused', 'loop'}.
 
-    'fused' forces the one-pass multi-branch kernel (assignment table
-    VMEM-resident); 'loop' forces the per-branch SpMM fallback (assignment
-    gathered outside the kernel -- the pre-fusion path, kept for assignment
-    tables beyond the VMEM envelope and for benchmarking).  ``reset=True``
-    clears all programmatic overrides first.
+    'fused' forces the one-pass multi-branch lookup kernel; 'loop' forces
+    the per-branch SpMM fallback (the pre-fusion path, kept for codebooks
+    whose lookup tables exceed the VMEM budget and for benchmarking).
+    ``reset=True`` clears all programmatic overrides first.
     """
     if reset:
         _context_overrides.clear()
@@ -358,17 +361,19 @@ def configure_context_dispatch(variant: Optional[str] = None,
         _context_overrides["vmem_budget_mb"] = float(vmem_budget_mb)
 
 
-def context_ell_variant(n_nodes: int, n_branches: int,
-                        itemsize: float = 4, dtype=None) -> str:
-    """'fused' or 'loop' for an [n_branches, n_nodes] assignment table.
+def context_ell_variant(n_branches: int, k: int, f_blk: int, deg: int, *,
+                        bl: int = LANES, f_out: Optional[int] = None,
+                        scaled: bool = False, cw_dtype=jnp.float32) -> str:
+    """'fused' or 'loop' for a term of ``n_branches`` ``[k, f_blk]``
+    codeword tables in ``cw_dtype`` read through ``deg`` slots per row.
 
-    The fused kernel keeps the whole assignment table VMEM-resident; past
-    the VMEM envelope the per-branch loop (whose gathers run outside the
-    kernel against the tiny [k, f_blk] tables) takes over.  ``itemsize``
-    is bytes per assignment entry and may be fractional: nibble-packed
-    tables (``PackedAssignment``) occupy 0.5 bytes/entry, which is exactly
-    how the +a4 tiers double the fused-dispatch crossover again.  ``dtype``
-    keys the autotuner entry (defaults to an itemsize-derived dtype).
+    The rule charges what the fused kernel holds in VMEM
+    (``context_ell.vmem_bytes``: the codebook's tables, tile-padded, the
+    double-buffered id, value and output blocks of ``bl`` rows, the
+    accumulator, the ``f_out``-wide ``w_t`` epilogue's matrix, and the
+    f32 copy of a codebook stored narrower), not the ``[n_branches, n]``
+    assignment table, which XLA gathers before the kernel.  A tuned
+    entry (``autotune.tuned_context``) is keyed on the same shape.
     """
     forced = _context_overrides.get(
         "variant", hostenv.env_knob("REPRO_CONTEXT_VARIANT", "auto"))
@@ -378,28 +383,29 @@ def context_ell_variant(n_nodes: int, n_branches: int,
     if forced in ("fused", "loop"):
         return str(forced)
     if not _budget_forced(_context_overrides, "REPRO_CONTEXT_VMEM_BUDGET_MB"):
-        tuned = autotune.tuned_context(n_nodes, n_branches, itemsize, dtype)
+        tuned = autotune.tuned_context(n_branches, k, f_blk, deg, cw_dtype)
         if tuned is not None:
             return str(tuned["variant"])
     budget_mb = _vmem_budget_mb(_context_overrides,
                                 "REPRO_CONTEXT_VMEM_BUDGET_MB")
-    return "loop" if n_nodes * n_branches * itemsize \
-        > budget_mb * 2 ** 20 else "fused"
+    held = vmem_bytes(n_branches, k, f_blk, deg, bl, f_out=f_out,
+                      scaled=scaled,
+                      cw_itemsize=jnp.dtype(cw_dtype).itemsize)
+    return "loop" if held > budget_mb * 2 ** 20 else "fused"
 
 
 def _context_ell_loop(out_ids, out_vals, assignment, codewords, w_t,
                       cw_scale=None):
     """Per-branch fallback: assignment gather + one SpMM per branch.
 
-    Used when the [n_branches, n] assignment table exceeds the fused
-    kernel's VMEM envelope -- each branch's gather source is its tiny
-    [k, f_blk] codeword table, so the per-branch SpMM always dispatches
-    to the resident variant regardless of graph size.  int8/fp8 codewords
-    ride into each branch's SpMM with their [1, f_blk] scale row
-    (per-branch dequant before the concat == the fused kernel's flat
-    epilogue).  Nibble-packed tables unpack here (outside the kernels):
-    in the loop regime the table is HBM-resident anyway, so packing only
-    buys storage, not the dispatch crossover.
+    Used when the fused kernel's lookup tables exceed the VMEM budget, or
+    when forced -- each branch's gather source is its own [k, f_blk]
+    codeword table, and the per-branch SpMM picks its resident or HBM
+    variant by that table's size.  int8/fp8 codewords ride into each
+    branch's SpMM with their [1, f_blk] scale row (per-branch dequant
+    before the concat == the fused kernel's flat epilogue).  Nibble-packed
+    tables unpack here (outside the kernels), as the fused kernel's XLA
+    gather reads them packed: packing buys storage, not a crossover.
     """
     if isinstance(assignment, PackedAssignment):
         assignment = assignment.unpack()
@@ -434,9 +440,9 @@ def context_ell(out_ids: jax.Array, out_vals: jax.Array,
     ``codewords`` as a ``QTensor`` ([nb, k, f_blk] int8 or float8_e4m3fn +
     [nb, 1, f_blk] f32 scales) and an ``assignment`` that is uint8
     (k <= 256) or a nibble-packed ``PackedAssignment`` (k <= 16) -- the
-    operands stay in storage dtype through every variant, with one f32
-    dequant epilogue; packed tables count 0.5 bytes/entry against the
-    dispatch VMEM budget (the crossover-doubling lever).
+    tables stay in storage dtype in HBM; the fused kernel widens the
+    tiny codebook to f32 lookup tables and applies one f32 dequant
+    epilogue.
     """
     cw_scale = None
     if isinstance(codewords, QTensor):
@@ -451,19 +457,18 @@ def context_ell(out_ids: jax.Array, out_vals: jax.Array,
 @jax.custom_vjp
 def _context_ell_kernel(out_ids, out_vals, assignment, codewords, w_t,
                         cw_scale):
-    if isinstance(assignment, PackedAssignment):
-        nb, n = assignment.shape
-        itemsize: float = 0.5
-        a_dtype = jnp.uint4
-    else:
-        nb, n = assignment.shape
-        itemsize = assignment.dtype.itemsize
-        a_dtype = assignment.dtype
+    b, deg = out_ids.shape
+    nb, k, f_blk = codewords.shape
     bb = 128
-    tuned = autotune.tuned_context(n, nb, itemsize, dtype=a_dtype)
+    tuned = autotune.tuned_context(nb, k, f_blk, deg, codewords.dtype)
     if tuned is not None:
         bb = int(tuned.get("bb", bb))
-    if context_ell_variant(n, nb, itemsize, dtype=a_dtype) == "fused":
+    variant = context_ell_variant(
+        nb, k, f_blk, deg, bl=lane_tile(b, bb),
+        f_out=None if w_t is None else w_t.shape[1],
+        scaled=cw_scale is not None, cw_dtype=codewords.dtype)
+    CONTEXT_TRACE_COUNT.bump(f"context.{variant}")
+    if variant == "fused":
         return context_ell_pallas(out_ids, out_vals, assignment,
                                   codewords, cw_scale=cw_scale, w_t=w_t,
                                   bb=bb, interpret=interpret_mode())

@@ -20,8 +20,11 @@ resident budget keeps small (the sources here are mini-batches and
 ``[k, f_blk]`` codeword tables).
 
 ``onehot_ell_sum`` is shared with the fused context kernel
-(context_ell.py).  Quantized sources stay in storage dtype in VMEM and are
-widened one block at a time.
+(context_ell.py), which uses it only for wide codeword branches: a narrow
+``[k, f_blk]`` codebook is read by lane gathers in registers instead.
+Here the source is a mini-batch of 128-wide rows, where a lookup would
+cost ``f * n_src / 128`` permutes per slot group.  Quantized sources stay
+in storage dtype in VMEM and are widened one block at a time.
 """
 from __future__ import annotations
 

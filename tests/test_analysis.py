@@ -11,6 +11,7 @@ import textwrap
 
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.experimental import pallas as pl
 
 from repro.analysis import Finding, ast_checks, jaxpr_checks, \
@@ -357,6 +358,39 @@ def test_repro203_forced_variant_mismatch():
     assert _rules(findings) == {"REPRO203"}
     spots = {f.path for f in findings}
     assert spots == {"<crossover:spmm_ell>", "<crossover:context_ell>"}
+
+
+@pytest.mark.parametrize("nb,k,f_blk,deg,f_out,scaled", [
+    (32, 1024, 4, 32, None, False),    # the paper's forward term
+    (32, 1024, 4, 32, 128, False),     # its Eq. 7 term with W^T
+    (8, 300, 5, 11, 128, True),        # int8 gradient codewords, ragged
+    (1, 1, 1, 1, None, False),         # one word, one slot
+])
+def test_repro203_context_charge_covers_traced_footprint(nb, k, f_blk, deg,
+                                                         f_out, scaled):
+    """What the context dispatch charges (``context_ell.vmem_bytes``)
+    covers the footprint pass 2 computes from the traced BlockSpecs: each
+    block once there, double-buffered and tile-padded in the charge."""
+    from repro.analysis.jaxpr_checks import pallas_calls
+    from repro.kernels.context_ell import context_ell_pallas, vmem_bytes
+
+    b = 130
+    kw = {}
+    if scaled:
+        kw["cw_scale"] = SDS((nb, 1, f_blk), jnp.float32)
+    if f_out is not None:
+        kw["w_t"] = SDS((nb * f_blk, f_out), jnp.float32)
+    names = list(kw)
+    cj = jax.make_jaxpr(lambda i, v, a, c, *rest: context_ell_pallas(
+        i, v, a, c, interpret=True, **dict(zip(names, rest))))(
+            SDS((b, deg), jnp.int32), SDS((b, deg), jnp.float32),
+            SDS((nb, 1000), jnp.int32),
+            SDS((nb, k, f_blk), jnp.int8 if scaled else jnp.float32),
+            *kw.values())
+    (eqn,) = pallas_calls(cj)
+    traced = pallas_vmem.dispatch_footprint(eqn)
+    charged = vmem_bytes(nb, k, f_blk, deg, f_out=f_out, scaled=scaled)
+    assert traced <= charged < 2.5 * traced
 
 
 # ---------------------------------------------------------------------------

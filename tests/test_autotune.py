@@ -74,7 +74,7 @@ def test_disabled_returns_none(monkeypatch):
     monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
     assert not autotune.enabled()
     assert autotune.tuned_spmm(1000, 16) is None
-    assert autotune.tuned_context(1000, 4) is None
+    assert autotune.tuned_context(4, 64, 8, 16) is None
     assert autotune.tuned_vq_update(256, 64, 8) is None
 
 
@@ -97,12 +97,12 @@ def test_tuned_spmm_measures_and_caches(tuner_cache):
 
 
 def test_tuned_context_and_vq_update(tuner_cache):
-    ctx = autotune.tuned_context(2000, 4)
+    ctx = autotune.tuned_context(4, 64, 8, 16)
     assert ctx["variant"] in ("fused", "loop")
     vq = autotune.tuned_vq_update(256, 64, 8)
     assert vq["bb"] in (128, 256) and vq["kb"] in (256, 512)
-    # uint8 and int32 assignment tables tune independently
-    ctx8 = autotune.tuned_context(2000, 4, itemsize=1)
+    # f32 and int8 codebooks tune independently
+    ctx8 = autotune.tuned_context(4, 64, 8, 16, dtype=jnp.int8)
     assert ctx8["variant"] in ("fused", "loop")
     keys = set(json.loads(tuner_cache.read_text()))
     assert len([k for k in keys if k.startswith("context|")]) == 2
@@ -134,13 +134,16 @@ def test_dispatch_prefers_tuned_variant(tuner_cache, monkeypatch):
 
 
 def test_context_dispatch_budget_silences_tuner(tuner_cache):
-    key = autotune.cache_key("context", (4096, 4), jnp.int32)
+    # keyed on the shape the rule charges: nb, k, f_blk, D
+    shape = (4, 64, 8, 16)
+    key = autotune.cache_key("context", shape, jnp.float32)
     autotune.record(key, {"variant": "loop", "bb": 64})
     ops.configure_context_dispatch(reset=True)
     try:
-        assert ops.context_ell_variant(4096, 4) == "loop"
+        # 0.16 MiB held, so the heuristic says fused
+        assert ops.context_ell_variant(*shape) == "loop"
         ops.configure_context_dispatch(vmem_budget_mb=64.0)
-        assert ops.context_ell_variant(4096, 4) == "fused"
+        assert ops.context_ell_variant(*shape) == "fused"
     finally:
         ops.configure_context_dispatch(reset=True)
 

@@ -22,6 +22,7 @@ from repro.core.message_passing import (ConvOperands, approx_message_passing,
                                         inject_context_grad_materialized,
                                         intra_messages, reconstruct)
 from repro.kernels import ops, ref
+from repro.kernels import context_ell as context_ell_mod
 from repro.kernels.context_ell import context_ell_pallas
 
 def _case(b, deg, n, nb, k, f_blk, seed=None, cw_dtype=jnp.float32):
@@ -53,7 +54,12 @@ def _legacy_loop(out_ids, out_vals, assignment, codewords):
     (33, 7, 50, 4, 16, 8),     # b a non-multiple of bb, nb=4
     (128, 32, 300, 2, 64, 16), # multi-tile
     (5, 0, 10, 4, 8, 8),       # D=0 column padding (no out-of-batch slots)
-    (257, 5, 999, 1, 256, 8),  # single branch, paper-scale k
+    (257, 5, 999, 1, 256, 8),  # single branch, 2 codeword groups of 128
+    (130, 32, 500, 32, 1024, 4),   # the paper's 32 x 4 branches, k, D
+    (64, 9, 150, 8, 1024, 16),     # the head's 8 x 16, D % 8 != 0
+    (40, 11, 300, 8, 300, 5),      # 8 x 5 gradient codewords, k % 128 != 0
+    (40, 9, 100, 1, 1024, 128),    # one full-width branch: the one-hot
+    (24, 5, 60, 2, 40, 301),       # two 301-wide branches: the one-hot
 ])
 @pytest.mark.parametrize("cw_dtype", [jnp.float32, jnp.bfloat16])
 def test_context_ell_sweep(b, deg, n, nb, k, f_blk, cw_dtype):
@@ -74,6 +80,8 @@ def test_context_ell_sweep(b, deg, n, nb, k, f_blk, cw_dtype):
     (33, 7, 50, 4, 16, 8, 12),
     (64, 5, 200, 2, 32, 8, 8),
     (6, 0, 10, 2, 8, 4, 5),    # D=0 with epilogue
+    (130, 32, 500, 32, 1024, 4, 128),  # the paper's Eq. 7 term, layers 1-2
+    (40, 11, 300, 8, 300, 5, 128),     # the head's gradient codewords
 ])
 def test_context_ell_wt_epilogue(b, deg, n, nb, k, f_blk, f_out):
     """The fused ``@ W^T`` epilogue (the streaming Eq. 7 backward form)."""
@@ -85,10 +93,18 @@ def test_context_ell_wt_epilogue(b, deg, n, nb, k, f_blk, f_out):
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
 
 
-def test_context_ell_all_out_of_batch_rows():
-    """Rows whose every slot is a real out-of-batch edge (no zero padding)."""
-    ids, val, assign, cw = _case(40, 6, 100, 4, 16, 8)
+@pytest.mark.parametrize("slots", ["distinct", "same_node",
+                                   "same_codeword"])
+def test_context_ell_all_out_of_batch_rows(slots):
+    """Rows whose every slot is a real out-of-batch edge (no zero padding);
+    several slots of a row may read one codeword (one node repeated, or
+    every node on the last codeword of a 200-word book)."""
+    ids, val, assign, cw = _case(40, 6, 100, 4, 200, 8)
     val = jnp.abs(val) + 0.5                     # all slots carry real edges
+    if slots == "same_node":
+        ids = jnp.broadcast_to(ids[:, :1], ids.shape)
+    elif slots == "same_codeword":
+        assign = jnp.full_like(assign, 199)
     got = context_ell_pallas(ids, val, assign, cw, interpret=True)
     want = ref.context_ell(ids, val, assign, cw)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
@@ -112,38 +128,88 @@ def test_context_ell_tile_sizes(bb):
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("lookup", [True, False])
+@pytest.mark.parametrize("b,deg,n,nb,k,f_blk", [
+    (33, 7, 50, 4, 16, 8),
+    (40, 11, 300, 8, 300, 5),
+    (24, 5, 60, 2, 140, 48),
+])
+@pytest.mark.parametrize("quantized", [False, True])
+def test_context_ell_inner_forms(b, deg, n, nb, k, f_blk, lookup, quantized):
+    """Both inner forms of the kernel, lane lookup and one-hot, whichever
+    the shape would choose: f32 codewords, and int8 codewords with their
+    scales and the ``w_t`` epilogue."""
+    from repro.distributed.quantization import quantize_codewords
+    ids, val, assign, cw = _case(b, deg, n, nb, k, f_blk)
+    scale = w_t = None
+    if quantized:
+        qt = quantize_codewords(cw)
+        cw, scale = qt.q, qt.scale
+        w_t = jax.random.normal(jax.random.PRNGKey(3), (nb * f_blk, 24))
+    got = context_ell_mod._context_ell(ids, val, assign, cw, cw_scale=scale,
+                                       w_t=w_t, bb=128, interpret=True,
+                                       lookup=lookup)
+    deq = cw if scale is None else cw.astype(jnp.float32) * scale
+    want = ref.context_ell(ids, val, assign, deq, w_t)
+    assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+def test_context_inner_form_follows_width():
+    """The lookup's cost grows with the branch's columns, the one-hot's
+    does not: the paper's 4-, 5- and 16-wide branches take the lookup,
+    full-width (128) and Reddit's 301-wide layer-0 branches the one-hot."""
+    uses = context_ell_mod.uses_lookup
+    assert uses(1024, 4) and uses(1024, 5) and uses(1024, 16)
+    assert uses(16, 8) and uses(1, 1)
+    assert not uses(1024, 128) and not uses(1024, 301)
+    assert not uses(41, 602)
+
+
 # ---------------------------------------------------------------------------
 # ops.py dispatch: heuristic, env/configure overrides, reset
 # ---------------------------------------------------------------------------
 
 def test_context_variant_heuristic(monkeypatch):
+    """The rule charges what the fused kernel holds in VMEM -- the
+    codebook's tables and the double-buffered id, value and output blocks
+    -- and not the [nb, n] assignment table, which XLA gathers ahead of
+    the kernel (the rule takes no node count)."""
     monkeypatch.delenv("REPRO_CONTEXT_VARIANT", raising=False)
     monkeypatch.setenv("REPRO_CONTEXT_VMEM_BUDGET_MB", "4")
-    assert ops.context_ell_variant(100_000, 4) == "fused"   # 1.6 MiB table
-    assert ops.context_ell_variant(2_000_000, 4) == "loop"  # 32 MiB table
+    # the paper's widths: 2.4 MiB held by the 32 x 4 terms, less by the
+    # head's 8 x 16
+    assert ops.context_ell_variant(32, 1024, 4, 32) == "fused"
+    assert ops.context_ell_variant(8, 1024, 16, 32) == "fused"
+    # 16k codewords per branch: 16 MiB of double-buffered lookup tables
+    assert ops.context_ell_variant(32, 16384, 4, 32) == "loop"
+    # 1024-row tiles: 8 MiB of double-buffered id blocks
+    assert ops.context_ell_variant(32, 1024, 4, 32, bl=1024) == "loop"
+    # two 301-wide branches: 5.7 MiB, mostly double-buffered one-hot tables
+    assert ops.context_ell_variant(2, 1024, 301, 32) == "loop"
     monkeypatch.setenv("REPRO_CONTEXT_VARIANT", "loop")
-    assert ops.context_ell_variant(8, 1) == "loop"
+    assert ops.context_ell_variant(1, 8, 4, 4) == "loop"
     monkeypatch.setenv("REPRO_CONTEXT_VARIANT", "fused")
-    assert ops.context_ell_variant(2_000_000, 4) == "fused"
+    assert ops.context_ell_variant(32, 16384, 4, 32) == "fused"
     monkeypatch.setenv("REPRO_CONTEXT_VARIANT", "nope")
     with pytest.raises(ValueError):
-        ops.context_ell_variant(8, 1)
+        ops.context_ell_variant(1, 8, 4, 4)
 
 
 def test_context_configure_and_reset(monkeypatch):
     monkeypatch.delenv("REPRO_CONTEXT_VARIANT", raising=False)
     monkeypatch.delenv("REPRO_CONTEXT_VMEM_BUDGET_MB", raising=False)
+    shape = (4, 16, 8, 8)             # nb, k, f_blk, D: 0.12 MiB held
     try:
         ops.configure_context_dispatch(variant="loop")
-        assert ops.context_ell_variant(8, 1) == "loop"
-        ops.configure_context_dispatch(variant="auto", vmem_budget_mb=0.001)
-        assert ops.context_ell_variant(10_000, 4) == "loop"
+        assert ops.context_ell_variant(1, 8, 4, 4) == "loop"
+        ops.configure_context_dispatch(variant="auto", vmem_budget_mb=0.1)
+        assert ops.context_ell_variant(*shape) == "loop"
         with pytest.raises(ValueError):
             ops.configure_context_dispatch(variant="nope")
         # reset clears every programmatic override -> back to defaults
         ops.configure_context_dispatch(reset=True)
         assert not ops._context_overrides
-        assert ops.context_ell_variant(10_000, 4) == "fused"
+        assert ops.context_ell_variant(*shape) == "fused"
         # reset composes with setting new values in the same call
         ops.configure_context_dispatch(variant="loop", reset=True)
         assert ops._context_overrides == {"variant": "loop"}

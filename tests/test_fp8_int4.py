@@ -25,6 +25,7 @@ from repro.distributed.quantization import (PackedAssignment, dtype_nbits,
                                             unpack_nibbles)
 from repro.kernels import autotune, ops, ref
 from repro.kernels.context_ell import context_ell_pallas
+from repro.kernels.context_ell import vmem_bytes as context_vmem_bytes
 from repro.kernels.spmm_ell import spmm_ell_pallas
 from repro.kernels.vq_update import vq_assign_update_pallas
 
@@ -173,13 +174,18 @@ def test_vq_update_emit_rejects_unsupported_dtype_naming_it():
 # kernel parity: fp8 codewords, packed assignment tables (interpret mode)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("b,deg,n,nb,k,f_blk", [
+    (128, 8, 999, 4, 16, 8),       # odd n: padded tail
+    (40, 13, 300, 8, 16, 5),       # 8 x 5, D % 8 != 0
+    (20, 3, 50, 1, 1, 4),          # a one-word codebook
+])
 @pytest.mark.parametrize("with_wt", [False, True])
-def test_context_ell_fp8_packed_parity(with_wt):
-    ids, val, assign, cw = _case(128, 8, 999, 4, 16, 8)   # odd n: padded tail
+def test_context_ell_fp8_packed_parity(b, deg, n, nb, k, f_blk, with_wt):
+    ids, val, assign, cw = _case(b, deg, n, nb, k, f_blk)
     qt = quantize_codewords(cw, dtype=FP8)
     deq = qt.q.astype(jnp.float32) * qt.scale
     pa = PackedAssignment.pack(assign)
-    w_t = jax.random.normal(jax.random.PRNGKey(9), (4 * 8, 24)) \
+    w_t = jax.random.normal(jax.random.PRNGKey(9), (nb * f_blk, 24)) \
         if with_wt else None
     got = context_ell_pallas(ids, val, pa, qt.q, cw_scale=qt.scale,
                              w_t=w_t, interpret=True)
@@ -252,42 +258,64 @@ def test_kernel_precision_env(monkeypatch):
         ops.kernel_precision()
 
 
+def _traced_context_variants(assignment, k, f_blk, deg, b=256):
+    """The variants ``ops`` dispatches one context term to, read from the
+    trace-time counter while tracing the kernel path (through a fresh
+    function each time: a trace of the same function is cached)."""
+    from repro.analysis.trace_count import CONTEXT_TRACE_COUNT
+    sds = jax.ShapeDtypeStruct
+    nb = assignment.shape[0]
+    before = CONTEXT_TRACE_COUNT.snapshot()
+    jax.make_jaxpr(lambda *a: ops._context_ell_kernel(*a))(
+        sds((b, deg), jnp.int32), sds((b, deg), jnp.float32), assignment,
+        sds((nb, k, f_blk), jnp.float32), None, None)
+    delta = CONTEXT_TRACE_COUNT.delta(before)
+    return sorted(key.split(".")[1] for key, n in delta.items() if n)
+
+
 def test_context_dispatch_packed_halves_table_budget():
-    # fractional itemsize: the packed table crosses to 'loop' at 2x the
-    # node count of the uint8 table under the same budget
-    ops.configure_context_dispatch(reset=True, vmem_budget_mb=0.5)
+    # the packed table halves the uint8 table's HBM bytes, and no longer
+    # any VMEM budget: the dispatch charges what the fused kernel holds
+    # (codebook tables, id and value blocks), not the assignment table
+    # XLA gathers ahead of it, so int32, uint8 and nibble-packed tables
+    # cross at the same budget, at any node count
+    nb, k, f_blk, deg = 4, 16, 8, 16
+    held_mb = context_vmem_bytes(nb, k, f_blk, deg) / 2 ** 20
+    sds = jax.ShapeDtypeStruct
     try:
-        n8 = 0.5 * 2 ** 20 / 4          # uint8 threshold at nb=4
-        assert ops.context_ell_variant(int(n8), 4, 1,
-                                       dtype=jnp.uint8) == "fused"
-        assert ops.context_ell_variant(int(n8) + 1, 4, 1,
-                                       dtype=jnp.uint8) == "loop"
-        assert ops.context_ell_variant(int(2 * n8), 4, 0.5,
-                                       dtype=jnp.uint4) == "fused"
-        assert ops.context_ell_variant(int(2 * n8) + 1, 4, 0.5,
-                                       dtype=jnp.uint4) == "loop"
+        for n in (1_000, 10_000_000):
+            tables = (sds((nb, n), jnp.int32), sds((nb, n), jnp.uint8),
+                      PackedAssignment(sds((nb, n // 2), jnp.uint8), n))
+            assert tables[2].packed.size * 2 == tables[1].size
+            for budget_mb, want in ((held_mb * 1.01, "fused"),
+                                    (held_mb * 0.99, "loop")):
+                ops.configure_context_dispatch(reset=True,
+                                               vmem_budget_mb=budget_mb)
+                for table in tables:
+                    assert _traced_context_variants(
+                        table, k, f_blk, deg) == [want], (n, table, want)
     finally:
         ops.configure_context_dispatch(reset=True)
 
 
 def test_autotune_keys_no_tier_collisions(tmp_path, monkeypatch):
-    # int8 vs fp8 spmm sources and uint8 vs uint4 context tables share an
-    # itemsize (or half of one) but are distinct operand regimes: their
-    # cache entries must never collide (REPRO_AUTOTUNE=1 + fp8+a4 vs int8)
+    # int8 vs fp8 spmm sources and int8 vs fp8 context codebooks share an
+    # itemsize but are distinct operand regimes: their cache entries must
+    # never collide (REPRO_AUTOTUNE=1 + fp8 vs int8)
     monkeypatch.setenv("REPRO_AUTOTUNE", "1")
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "at.json"))
     autotune.clear()
     try:
         keys = {autotune.cache_key("spmm", (1000, 16, 1), jnp.int8),
                 autotune.cache_key("spmm", (1000, 16, 1), FP8),
-                autotune.cache_key("context", (1000, 4), jnp.uint8),
-                autotune.cache_key("context", (1000, 4), jnp.uint4)}
+                autotune.cache_key("context", (2, 16, 8, 8), jnp.int8),
+                autotune.cache_key("context", (2, 16, 8, 8), FP8)}
         assert len(keys) == 4
-        cfg8 = autotune.tuned_context(1000, 2, 1, dtype=jnp.uint8)
-        cfg4 = autotune.tuned_context(1000, 2, 0.5, dtype=jnp.uint4)
+        cfg8 = autotune.tuned_context(2, 16, 8, 8, dtype=jnp.int8)
+        cfg4 = autotune.tuned_context(2, 16, 8, 8, dtype=FP8)
         assert cfg8 is not None and cfg4 is not None
-        k8 = autotune.cache_key("context", (1000, 2), jnp.uint8)
-        k4 = autotune.cache_key("context", (1000, 2), jnp.uint4)
+        k8 = autotune.cache_key("context", (2, 16, 8, 8), jnp.int8)
+        k4 = autotune.cache_key("context", (2, 16, 8, 8), FP8)
         assert autotune.lookup(k8) == cfg8
         assert autotune.lookup(k4) == cfg4
     finally:

@@ -73,6 +73,7 @@ def test_quantize_codewords_drift_band():
     (8, 4, 16, 2, 4, 8),
     (33, 7, 50, 4, 16, 8),
     (257, 5, 999, 1, 256, 8),      # k=256 at the uint8 boundary
+    (40, 11, 300, 8, 200, 5),      # 8 x 5, k % 128 != 0, D % 8 != 0
 ])
 @pytest.mark.parametrize("with_wt", [False, True])
 def test_context_ell_int8_parity(b, deg, n, nb, k, f_blk, with_wt):
@@ -159,13 +160,27 @@ def test_ops_spmm_ell_qtensor_cpu_path():
 
 
 def test_uint8_table_shifts_dispatch_crossover():
-    """The 4x VMEM-envelope win: at a budget where the int32 table forces
-    the loop variant, the uint8 table (itemsize=1) stays fused."""
+    """The assignment table no longer enters VMEM (XLA gathers it ahead
+    of the fused kernel), so its storage moves no crossover: traced
+    through the dispatch, at a budget the int32 [4, 100k] table (1.6 MB)
+    once exceeded, int32 and uint8 tables both take the fused kernel, and
+    a codebook too large for the budget takes the loop whatever the
+    table's dtype."""
+    from repro.analysis.trace_count import CONTEXT_TRACE_COUNT
+    sds = jax.ShapeDtypeStruct
     ops.configure_context_dispatch(reset=True, vmem_budget_mb=1.0)
     try:
-        n, nb = 100_000, 4           # int32 table: 1.6 MB > 1 MB budget
-        assert ops.context_ell_variant(n, nb, itemsize=4) == "loop"
-        assert ops.context_ell_variant(n, nb, itemsize=1) == "fused"
+        n, nb, f_blk, deg = 100_000, 4, 8, 16
+        for dt in (jnp.int32, jnp.uint8):
+            for k, want in ((256, "context.fused"), (8192, "context.loop")):
+                before = CONTEXT_TRACE_COUNT.snapshot()
+                jax.make_jaxpr(lambda *a: ops._context_ell_kernel(*a))(
+                    sds((256, deg), jnp.int32), sds((256, deg), jnp.float32),
+                    sds((nb, n), dt), sds((nb, k, f_blk), jnp.float32),
+                    None, None)
+                delta = CONTEXT_TRACE_COUNT.delta(before)
+                assert {key for key, c in delta.items() if c} == {want}, \
+                    (dt, k, delta)
     finally:
         ops.configure_context_dispatch(reset=True)
 

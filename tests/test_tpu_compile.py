@@ -103,15 +103,21 @@ def test_spmm_ell_quantized_sources_compile(sds):
         sds((1, 128))))
 
 
+@pytest.mark.parametrize("b", [B, 4096])     # training/inference, serving
 @pytest.mark.parametrize("nb,f_blk,f_out", [
     (NB, F_BLK, None),        # Eq. 6 forward, layers 0-1
     (NB, F_BLK, 128),         # Eq. 7 backward with the fused W^T epilogue
+    (8, 16, None),            # layer 2's feature codewords (f_in 128)
     (8, 5, 128),              # layer 2's gradient codewords (f_grad 40)
-    (1, 128, None),           # full-width codebook (transformer)
+    (1, 128, None),           # full-width codebook (transformer): one-hot
+    (2, 301, None),           # Reddit's layer 0 (f 602): one-hot
 ])
-def test_context_ell_compiles(sds, nb, f_blk, f_out):
+def test_context_ell_compiles(sds, nb, f_blk, f_out, b):
+    """The codeword lookup (lane gathers of 128-codeword table rows) at
+    the paper's k, D and batch sizes, and the one-hot the kernel keeps for
+    wide branches."""
     from repro.kernels.context_ell import context_ell_pallas
-    args = [sds((B, D), jnp.int32), sds((B, D)), sds((nb, N), jnp.int32),
+    args = [sds((b, D), jnp.int32), sds((b, D)), sds((nb, N), jnp.int32),
             sds((nb, K, f_blk))]
     if f_out is None:
         fn = lambda i, v, a, c: context_ell_pallas(i, v, a, c)  # noqa: E731
@@ -188,3 +194,88 @@ def test_training_step_grad_compiles(sds, kernels_on):
     # per layer: intra SpMM + context forward (+ Eq. 7 backward), and the
     # fused assign+stats update
     assert_kernels(text, 3 * 3)
+    # five context terms, each one fused kernel: three forward, and the
+    # Eq. 7 backward of the two layers past the first
+    assert _context_kernels(text) == 5
+
+
+def _context_kernels(text: str) -> int:
+    return sum("tpu_custom_call" in line and "context_ell_pallas" in line
+               for line in text.splitlines())
+
+
+def _paper_model(sds, n: int):
+    """The paper's GCN (hidden 128, k 1024, f_prod 4, 40 classes) as
+    shapes: config, params, VQ states and an epoch plan over ``n`` nodes."""
+    from repro.core.codebook import CodebookConfig
+    from repro.graph.batching import EpochPlan
+    from repro.models.gnn import GNNConfig, init_gnn, init_vq_states
+
+    cfg = GNNConfig(backbone="gcn", f_in=128, hidden=128, n_out=40,
+                    n_layers=3, codebook=CodebookConfig(k=K, f_prod=4))
+    key = jax.random.PRNGKey(0)
+
+    def place(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(lambda k: init_gnn(k, cfg), key))
+    vq = place(jax.eval_shape(lambda k: init_vq_states(k, cfg, n), key))
+    plan = EpochPlan(nbr_ids=sds((n, D), jnp.int32), nbr_mask=sds((n, D)),
+                     rev_ids=sds((n, D), jnp.int32), rev_mask=sds((n, D)))
+    return cfg, params, vq, plan
+
+
+def test_context_terms_take_the_fused_kernel(sds, kernels_on):
+    """At the paper's n, widths and batches the dispatch sends every
+    context term to the fused kernel and none to the per-branch loop: the
+    ``[32, n]`` assignment table (21.7 MB) is gathered in XLA and is not
+    charged against the VMEM budget.  A training step traces three forward
+    terms and three Eq. 7 terms (layer 0's is dead: its cotangent is the
+    input features', and the compiled step drops it); an inference sweep
+    and a serve step trace one term per layer."""
+    from repro.analysis.trace_count import CONTEXT_TRACE_COUNT
+    from repro.core.conv import MinibatchPack
+    from repro.models.gnn import (_vq_infer_layer_body, _vq_step_body,
+                                  vq_serve_batch)
+    from repro.train.optimizer import rmsprop
+
+    cfg, params, vq, plan = _paper_model(sds, N)
+    opt = rmsprop(3e-3)
+    ost = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(opt.init, params))
+    i32 = jnp.int32
+    pack = MinibatchPack(
+        batch_ids=sds((B,), i32), nbr_ids=sds((B, D), i32),
+        nbr_mask=sds((B, D)), nbr_pos=sds((B, D), i32),
+        rev_ids=sds((B, D), i32), rev_mask=sds((B, D)),
+        rev_pos=sds((B, D), i32))
+
+    def traced(fn, *args) -> dict:
+        before = CONTEXT_TRACE_COUNT.snapshot()
+        jax.jit(fn).trace(*args)
+        return CONTEXT_TRACE_COUNT.delta(before)
+
+    step = traced(
+        lambda p, v, o, pk, x, y, deg, m: _vq_step_body(
+            p, v, o, pk, x, y, deg, cfg, opt, loss_mask=m),
+        params, vq, ost, pack, sds((B, 128)), sds((B,), i32), sds((N,)),
+        sds((B,)))
+    assert step == {"context.fused": 6, "context.loop": 0}
+
+    sweeps = {"context.fused": 0, "context.loop": 0}
+    for layer, (fi, _) in enumerate(cfg.layer_dims()):
+        got = traced(
+            lambda p, v, pl_, perm, sm, acts, deg, _l=layer:
+            _vq_infer_layer_body(p, v, pl_, perm, sm, acts, deg, cfg=cfg,
+                                 layer=_l),
+            params[layer], vq[layer], plan, sds((4, B), i32),
+            sds((4, B)), sds((N, fi)), sds((N,)))
+        for key in sweeps:
+            sweeps[key] += got[key]
+    assert sweeps == {"context.fused": 3, "context.loop": 0}
+
+    serve = traced(
+        lambda p, v, pl_, ids, x, deg: vq_serve_batch.__wrapped__(
+            p, v, pl_, ids, x, deg, cfg),
+        params, vq, plan, sds((4096,), i32), sds((N, 128)), sds((N,)))
+    assert serve == {"context.fused": 3, "context.loop": 0}
