@@ -15,7 +15,8 @@ from numpy.testing import assert_allclose
 
 from repro.kernels import ops, ref
 from repro.kernels.spmm_ell import spmm_ell_pallas
-from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
+from repro.kernels.spmm_ell_hbm import (compact_slots, live_slots,
+                                        spmm_ell_hbm_pallas)
 
 
 def _case(b, deg, n, f, dtype=jnp.float32, seed=None):
@@ -62,7 +63,7 @@ def test_spmm_ell_hbm_tile_sizes(bb):
 
 def test_spmm_ell_hbm_padding_zero_vals():
     """Padding slots carry val == 0; their index may point anywhere valid --
-    their row is copied like any other but must not contribute."""
+    their row is never copied and must not contribute."""
     idx = jnp.array([[5, 0], [2, 1]], jnp.int32)
     val = jnp.array([[1.0, 0.0], [0.5, 0.0]])   # second slot is padding
     x = jnp.arange(12, dtype=jnp.float32).reshape(6, 2)
@@ -142,7 +143,7 @@ def test_ops_dispatch_routes_hbm_int8(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the row-DMA gather: one copy per slot, every slot copied
+# the row-DMA gather: one copy per live slot, dead slots skipped
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("case", [
@@ -176,6 +177,94 @@ def test_spmm_ell_hbm_gather_cases(case):
     got = spmm_ell_hbm_pallas(idx, val, x, bb=16, interpret=True)
     want = ref.spmm_ell(idx, val, x)
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def _sequential(idx, val, x, scale=None):
+    """Accumulate over every slot in order, dead ones included: ``out +=
+    val[:, d] * x[idx[:, d]]`` for d = 0..D-1, in float32.  Jitted, so the
+    backend rounds its multiply-adds as it rounds the kernel's."""
+    idx, val = jnp.asarray(idx), jnp.asarray(val, jnp.float32)
+    x = jnp.asarray(x).astype(jnp.float32)
+
+    def add_slot(d, out):
+        return out + val[:, d][:, None] * x[idx[:, d]]
+    out = jax.jit(lambda: jax.lax.fori_loop(
+        0, idx.shape[1], add_slot,
+        jnp.zeros((idx.shape[0], x.shape[1]), jnp.float32)))()
+    if scale is not None:
+        out = out * jnp.asarray(scale, jnp.float32).reshape(1, -1)
+    return np.asarray(out)
+
+
+def _live_mask(case, b, deg, rng):
+    if case == "dead":
+        return np.zeros((b, deg), bool)
+    if case == "scattered":       # the in-batch term: ~11% live anywhere
+        return rng.random((b, deg)) < 0.109
+    if case == "left_packed":     # full-graph padding: a live prefix
+        return np.arange(deg)[None, :] < rng.integers(0, 29, (b, 1))
+    if case == "dead_tile":       # rows 16..31 dead, rows 0..15 full
+        return np.repeat(np.arange(b)[:, None] < 16, deg, axis=1)
+    return np.ones((b, deg), bool)
+
+
+@pytest.mark.parametrize("case,b,int8", [
+    ("dead", 48, False),          # live share 0
+    ("scattered", 96, False),     # about 11%
+    ("left_packed", 96, False),   # about 44%
+    ("full", 48, False),          # 100%
+    ("dead_tile", 32, False),     # a tile of dead rows beside full rows
+    ("scattered", 53, False),     # b not a multiple of bb
+    ("scattered", 67, True),      # int8 source with scales
+])
+def test_spmm_ell_hbm_live_slots_bit_exact(case, b, int8):
+    """Skipping dead slots changes no bit: the kernel equals an accumulate
+    over every slot in order, at every live share."""
+    deg, n, f = 32, 150, 128
+    rng = np.random.default_rng(b * 7 + deg)
+    live = _live_mask(case, b, deg, rng)
+    idx = rng.integers(0, n, (b, deg)).astype(np.int32)
+    val = np.where(live, rng.normal(size=(b, deg)), 0.0).astype(np.float32)
+    x = rng.normal(size=(n, f)).astype(np.float32)
+    scale = None
+    if int8:
+        q, scale = _quantize_per_channel(jnp.asarray(x))
+        x = np.asarray(q)
+    got = spmm_ell_hbm_pallas(jnp.asarray(idx), jnp.asarray(val),
+                              jnp.asarray(x), x_scale=scale, bb=16,
+                              interpret=True)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _sequential(idx, val, x, scale))
+
+
+def test_live_slots_counts_nonzero_values():
+    val = jnp.array([[0.0, 1.0, 0.0, -2.0],
+                     [0.0, 0.0, 0.0, 0.0],
+                     [3.0, 0.5, 4.0, 1.0],
+                     [-0.0, 0.0, 0.0, 7.0]])
+    np.testing.assert_array_equal(np.asarray(live_slots(val)), [2, 0, 4, 1])
+    assert live_slots(val).dtype == jnp.int32
+
+
+def test_compact_slots_keeps_live_order():
+    """Live slots move to the front in their original order; the rest
+    carry id 0 and value 0."""
+    idx = jnp.array([[10, 11, 12, 13, 14],
+                     [20, 21, 22, 23, 24],
+                     [30, 31, 32, 33, 34]], jnp.int32)
+    val = jnp.array([[0.0, 1.0, 0.0, 2.0, 3.0],
+                     [4.0, 5.0, 6.0, 7.0, 8.0],
+                     [0.0, 0.0, 0.0, 0.0, 9.0]])
+    got_idx, got_val, cnt = compact_slots(idx, val)
+    np.testing.assert_array_equal(np.asarray(got_idx),
+                                  [[11, 13, 14, 0, 0],
+                                   [20, 21, 22, 23, 24],
+                                   [34, 0, 0, 0, 0]])
+    np.testing.assert_array_equal(np.asarray(got_val),
+                                  [[1.0, 2.0, 3.0, 0.0, 0.0],
+                                   [4.0, 5.0, 6.0, 7.0, 8.0],
+                                   [9.0, 0.0, 0.0, 0.0, 0.0]])
+    np.testing.assert_array_equal(np.asarray(cnt), [3, 5, 1])
 
 
 # ---------------------------------------------------------------------------

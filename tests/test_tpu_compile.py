@@ -16,6 +16,8 @@ so the tests steer the dispatch themselves (``ops.interpret_mode`` and
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -79,13 +81,22 @@ def test_spmm_ell_resident_compiles(sds, b, n_src):
         sds((b, D)), sds((n_src, f))))
 
 
-@pytest.mark.parametrize("b,n_src", [(B, B),          # intra-batch source
-                                     (N, N)])         # full-graph eval
-def test_spmm_ell_hbm_compiles(sds, b, n_src):
+@pytest.mark.parametrize("b,n_src,deg", [
+    pytest.param(B, B, D, id="42336-42336"),        # intra-batch source
+    pytest.param(N, N, D, id="169343-169343"),      # full-graph eval
+    pytest.param(B, B, 48, id="42336-42336-48"),    # a wider ELL cap
+])
+def test_spmm_ell_hbm_compiles(sds, b, n_src, deg):
+    """The live-slot counts and ids in SMEM, and the slot compaction ahead
+    of the kernel, which must not be a second instruction under the
+    kernel's name (the benchmark counts one launch per SpMM)."""
     from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
-    assert_kernels(compile_text(
+    text = compile_text(
         lambda i, v, x: spmm_ell_hbm_pallas(i, v, x),
-        sds((b, D), jnp.int32), sds((b, D)), sds((n_src, 128))))
+        sds((b, deg), jnp.int32), sds((b, deg)), sds((n_src, 128)))
+    assert_kernels(text)
+    names = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", text, re.M)
+    assert len([x for x in names if "spmm_ell_hbm_pallas" in x]) == 1
 
 
 def test_spmm_ell_quantized_sources_compile(sds):
