@@ -1,1 +1,1 @@
-"""Benchmarks: one module per paper table/figure + roofline/kernels."""
+"""Benchmarks: one module per paper table/figure + kernels."""

@@ -1,6 +1,5 @@
 """Pallas kernel micro-benchmarks: interpret-mode correctness deltas vs the
-jnp oracles + host-side call timings (TPU wall-times are N/A on this host;
-the roofline projections live in bench_roofline).
+jnp oracles + host-side call timings (TPU wall-times are N/A on this host).
 
 Two entry points:
 
@@ -26,11 +25,9 @@ import jax.numpy as jnp
 from repro.distributed.quantization import (pack_nibbles, quantize_tensor,
                                             unpack_nibbles)
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.spmm_ell import spmm_ell_pallas
 from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
 from repro.kernels.vq_assign import vq_assign_pallas
-from repro.kernels.vq_attention import vq_attention_decode_pallas
 from repro.kernels.vq_update import vq_assign_update_pallas
 
 
@@ -212,32 +209,6 @@ def run_structured() -> list[dict]:
             "idx_mismatches": float((i4.astype(jnp.int32) != i32).sum())},
            tolerance={"idx_mismatches": 0.0})
 
-    # --- flash attention ---
-    q, k, v = (jax.random.normal(kk, (1, 4, 512, 64))
-               for kk in jax.random.split(key, 3))
-    got = flash_attention_pallas(q, k, v, causal=True, interpret=True)
-    want = ref.flash_attention(q, k, v, causal=True)
-    _entry(rows, "kernel/flash_attention/1x4x512x64", 0.0,
-           {"maxerr": float(jnp.abs(got - want).max())},
-           tolerance={"maxerr": 1e-3})
-
-    # --- vq attention decode ---
-    n, g, d, kcb, w = 8, 4, 64, 256, 64
-    ks = jax.random.split(key, 6)
-    qd = jax.random.normal(ks[0], (n, g, d))
-    cbk = jax.random.normal(ks[1], (n, kcb, d))
-    cbv = jax.random.normal(ks[2], (n, kcb, d))
-    mass = jnp.abs(jax.random.normal(ks[3], (n, kcb))) + 0.1
-    wk = jax.random.normal(ks[4], (n, w, d))
-    wv = jax.random.normal(ks[5], (n, w, d))
-    wm = jnp.ones((n, w))
-    got = vq_attention_decode_pallas(qd, cbk, cbv, mass, wk, wv, wm,
-                                     interpret=True)
-    want = jax.vmap(lambda *a: ref.vq_attention_decode(*a))(
-        qd, cbk, cbv, mass, wk, wv, wm)
-    _entry(rows, "kernel/vq_attention/8x4x64_k256_w64", 0.0,
-           {"maxerr": float(jnp.abs(got - want).max())},
-           tolerance={"maxerr": 1e-3})
     return rows
 
 

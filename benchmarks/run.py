@@ -33,7 +33,6 @@ code first, regenerate, then commit the baselines on top.
               oracle, + the CI-gated sampler-executor throughput row;
               the CI ``scenario-matrix`` job runs it with --check and
               uploads BENCH_ablation.json)
-  (ours)   -> bench_roofline (from the multi-pod dry-run artifacts)
   (ours)   -> bench_kernels (Pallas kernels, interpret mode, vs oracles)
   (ours)   -> bench_context (fused VQ-context fwd/bwd vs per-branch loop)
   (ours)   -> bench_epoch (epoch executor: host loop vs scan vs shard_map)
@@ -53,7 +52,7 @@ import subprocess
 import sys
 import time
 
-SUITES = ["complexity", "memory", "kernels", "context", "epoch", "roofline",
+SUITES = ["complexity", "memory", "kernels", "context", "epoch",
           "inference", "convergence", "ablation", "performance"]
 
 

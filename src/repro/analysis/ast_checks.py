@@ -35,7 +35,7 @@
   REPRO005  import-time process mutation: assigning/updating
             ``os.environ`` (or ``os.putenv``) at module top level.
             Mutations under ``if __name__ == "__main__":`` are the CLI
-            pattern and exempt (``launch/dryrun.py``).
+            pattern and exempt.
 """
 from __future__ import annotations
 

@@ -10,10 +10,7 @@ The matrix ``benchmarks/bench_ablation.py`` sweeps -- and the CI
                  (``train.gnn_trainer.train_scenario`` dispatch)
   tasks          node classification / link prediction
 
-This module is deliberately SEPARATE from ``configs.registry``: that file
-enumerates the LM/speech/vision architecture seeds of the generic launch
-harness (llama/whisper/moe, quarantined from the GNN path) and must never
-leak into the matrix -- ``tests/test_scenarios.py`` pins both sets.
+``tests/test_scenarios.py`` pins each axis.
 """
 from repro.train.gnn_trainer import SCALE_METHODS
 
@@ -46,14 +43,8 @@ def matrix_cells(tasks=("node",)):
 
 
 def assert_gnn_only(names) -> None:
-    """Guard used by the matrix path: raise if any LM/speech/vision arch id
-    from ``configs.registry`` shows up where a GNN backbone is expected."""
-    from repro.configs.registry import ARCHS
-    leaked = sorted(set(names) & set(ARCHS))
-    if leaked:
-        raise ValueError(
-            f"non-GNN arch ids {leaked} leaked into the scenario matrix; "
-            f"matrix cells enumerate MATRIX_BACKBONES only")
+    """Guard used by the matrix path: raise if a name that is not one of
+    ``MATRIX_BACKBONES`` shows up where a GNN backbone is expected."""
     unknown = sorted(set(names) - set(MATRIX_BACKBONES))
     if unknown:
         raise ValueError(

@@ -1,23 +1,13 @@
-"""Weight-only int8 quantization for serving (beyond-paper lever).
+"""Quantized operands of the VQ tiers (DESIGN.md sections 13/15).
 
-Decode is memory-bound on the weight stream (EXPERIMENTS.md deep-dive 3);
-per-output-channel int8 storage halves the bytes/step vs bf16.  On TPU the
-int8->bf16 convert fuses into the MXU feed; numerically the per-channel
-scale keeps matmul outputs within ~0.5% of bf16 (test_quantization.py).
-
-Applied at the params-pytree level: every >=2D weight leaf becomes
-(int8 values, f32 per-channel scales); 1D scales/norms stay bf16.
-``dequantize_tree`` restores a dense pytree for the unmodified model code
--- under jit, XLA keeps the int8 buffers as the stored representation and
-materializes bf16 tiles on the fly.
-
-This module also owns the VQ operand tiers (DESIGN.md sections 13/15):
-``quantize_codewords`` (int8 and float8_e4m3fn codeword snapshots with
-per-branch/per-channel f32 scales + the drift band) and the nibble-packed
-assignment machinery (``pack_nibbles`` / ``unpack_nibbles`` /
-``PackedAssignment``) behind the ``+a4`` tiers for k <= 16 product
-branches, plus ``dtype_nbits`` -- the one sub-byte-aware size table shared
-by the HLO dump parser and the state-bytes accounting.
+``quantize_tensor`` is the per-output-channel symmetric int8/fp8 quantizer
+(``QTensor``: values plus f32 scales); ``quantize_codewords`` quantizes
+codeword snapshots the same way with a scale per branch and channel, and a
+drift band that keeps the grid fixed while the codebook barely moves.  The nibble-packed assignment
+machinery (``pack_nibbles`` / ``unpack_nibbles`` / ``PackedAssignment``)
+backs the ``+a4`` tiers for k <= 16 product branches.  ``dtype_nbits`` is
+the one sub-byte-aware size table, shared by the state-bytes accounting
+(``tree_bytes``) and the static checks.
 """
 from __future__ import annotations
 
@@ -32,15 +22,6 @@ class QTensor(NamedTuple):
     scale: jax.Array    # f32 [..., 1, out] per-output-channel scales
 
 
-# HLO short dtype names (as printed in compiled-module shapes) -> bit widths.
-# Shared with launch/dryrun.py, which parses HLO buffer-assignment dumps.
-_HLO_NBITS = {
-    "pred": 8, "s4": 4, "u4": 4, "s8": 8, "u8": 8, "s16": 16, "u16": 16,
-    "s32": 32, "u32": 32, "s64": 64, "u64": 64,
-    "f8e4m3fn": 8, "f8e5m2": 8, "bf16": 16, "f16": 16, "f32": 32, "f64": 64,
-    "c64": 64, "c128": 128,
-}
-
 # numpy reports itemsize=1 for the ml_dtypes sub-byte ints (one id per host
 # byte); on device they pack two per byte, and the size accounting here is
 # about device residency.
@@ -48,15 +29,7 @@ _SUB_BYTE_NBITS = {"int4": 4, "uint4": 4}
 
 
 def dtype_nbits(dt) -> int:
-    """Bits per element of a dtype, sub-byte aware.
-
-    Accepts anything ``jnp.dtype`` does (jnp/np dtypes, instances, names)
-    plus the HLO short names ("f8e4m3fn", "s32", ...) that appear in
-    compiled-module dumps.  Raises KeyError/TypeError on unknown inputs so
-    callers that scan heterogeneous dumps can skip unparseable entries.
-    """
-    if isinstance(dt, str) and dt in _HLO_NBITS:
-        return _HLO_NBITS[dt]
+    """Bits per element of anything ``jnp.dtype`` accepts, sub-byte aware."""
     d = jnp.dtype(dt)
     return _SUB_BYTE_NBITS.get(d.name, d.itemsize * 8)
 
@@ -78,10 +51,6 @@ def quantize_tensor(w: jax.Array, dtype=jnp.int8) -> QTensor:
     else:
         q = jnp.clip(scaled, -qmax, qmax).astype(dtype)
     return QTensor(q, scale)
-
-
-def dequantize_tensor(t: QTensor, dtype=jnp.bfloat16) -> jax.Array:
-    return (t.q.astype(jnp.float32) * t.scale).astype(dtype)
 
 
 # Drift band of the codeword-table requantization (kernels int8 path,
@@ -268,23 +237,6 @@ class PackedAssignment:
 
     def __repr__(self):
         return f"PackedAssignment(shape={self.shape}, packed={self.packed!r})"
-
-
-def _is_weight(leaf) -> bool:
-    return hasattr(leaf, "ndim") and leaf.ndim >= 2 and \
-        leaf.dtype in (jnp.float32, jnp.bfloat16)
-
-
-def quantize_tree(params: Any) -> Any:
-    """int8-quantize every >=2D float leaf of a params pytree."""
-    return jax.tree_util.tree_map(
-        lambda w: quantize_tensor(w) if _is_weight(w) else w, params)
-
-
-def dequantize_tree(qparams: Any, dtype=jnp.bfloat16) -> Any:
-    return jax.tree_util.tree_map(
-        lambda t: dequantize_tensor(t, dtype) if isinstance(t, QTensor)
-        else t, qparams, is_leaf=lambda x: isinstance(x, QTensor))
 
 
 def tree_bytes(params: Any) -> int:

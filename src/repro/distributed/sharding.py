@@ -1,20 +1,11 @@
-"""Per-architecture sharding strategies (DESIGN.md section 5).
+"""Mesh and sharding helpers of the GNN executors.
 
-Strategies (chosen by `strategy_for(cfg)` from head/ff divisibility):
-  tp_fsdp     -- Megatron tensor parallelism on the `model` axis (q heads /
-                 d_ff / vocab / experts) + FSDP/ZeRO-3 of params & optimizer
-                 states over the data axes ("pod","data").  Named-rule based:
-                 column-parallel wq/wk/wv/w1/w3, row-parallel wo/w2 (so the
-                 pair needs one psum, not a resharding all-gather).
-  fsdp        -- no TP (head counts indivisible by 16): params sharded over
-                 the flattened mesh on their largest divisible dim; sequence
-                 parallelism on `model` for activations.
-  replicate   -- tiny models (whisper-tiny): pure DP, weights replicated.
-
-All rules check divisibility against the actual mesh -- a dim that does not
-divide stays unsharded (never crashes the compile).  Everything is written
-against axis NAMES so single-pod (data,model) and multi-pod
-(pod,data,model) bind the same rules.
+``graph_dp_mesh`` builds the one-axis "data" mesh that both the epoch
+executor's data parallelism (DESIGN.md section 9) and the row-sharded graph
+state (DESIGN.md section 14) run on.  The PartitionSpec helpers place the
+stacked ``[S, b]`` epoch arrays, serving micro-batches and node tables on it,
+and the row-ownership maps (``node_to_shard``, ``global_to_local``, ...)
+translate node ids between the global table and its contiguous shards.
 """
 from __future__ import annotations
 
@@ -23,264 +14,6 @@ from typing import Any
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.configs.base import ArchConfig
-from repro.launch.mesh import axes_size, dp_axes
-
-# stacked-layer containers: leading dims are scan axes, never sharded
-_STACKED1 = ("blocks", "pairs", "enc_blocks", "cross_blocks")
-_STACKED2 = ("mamba",)
-
-
-def strategy_for(cfg: ArchConfig, mesh: Mesh) -> str:
-    tp = mesh.shape["model"]
-    if cfg.param_count() < 200e6:
-        return "replicate"
-    if cfg.family == "moe" and cfg.n_experts % tp == 0:
-        # Perf iteration 2 (EXPERIMENTS.md): Megatron-TP on a d_model=2048
-        # attention is collective-bound; experts-on-model + DP attention
-        # cuts per-layer all-reduces 4x -> 1x
-        return "moe_ep_dp"
-    if cfg.n_heads % tp == 0 and (cfg.d_ff == 0 or cfg.d_ff % tp == 0):
-        return "tp_fsdp"
-    return "fsdp"
-
-
-def _divides(dim: int, size: int) -> bool:
-    return size > 0 and dim % size == 0
-
-
-def _spec_for_leaf(pathstr: str, shape: tuple[int, ...], strategy: str,
-                   mesh: Mesh, cfg: ArchConfig) -> P:
-    dp = dp_axes(mesh)
-    dp_n = axes_size(mesh, dp)
-    tp_n = mesh.shape["model"]
-    all_ax = dp + ("model",)
-    all_n = dp_n * tp_n
-
-    # number of leading stacked dims to skip
-    skip = 0
-    if any(f"['{k}']" in pathstr for k in _STACKED1):
-        skip = 1
-    if any(f"['{k}']" in pathstr for k in _STACKED2):
-        skip = 2
-    dims = list(shape[skip:])
-    lead = [None] * skip
-
-    def out(spec_tail):
-        return P(*lead, *spec_tail)
-
-    if len(dims) == 0:
-        return out([])
-
-    if strategy == "replicate":
-        return out([None] * len(dims))
-
-    # vocab-parallel embedding/head in EVERY sharded strategy (the CE loss
-    # is matmul-only so the vocab axis never needs gathering; Perf iter. 1).
-    # The d_model axis stays UNSHARDED: putting dp on it makes the lookup
-    # output d@dp, which conflicts with batch@dp activations and GSPMD
-    # resolves by replicating the batch (+20 GiB/chip on the 405B cell --
-    # Perf iteration 5b).
-    if "['embed']" in pathstr and len(dims) == 2:
-        spec = [None, None]
-        if _divides(dims[0], tp_n):
-            spec[0] = "model"
-        elif _divides(dims[0], dp_n):
-            spec[0] = dp          # odd vocabs: shard vocab over dp instead
-        return out(spec)
-    if "['head']" in pathstr and len(dims) == 2:
-        spec = [None, None]
-        if _divides(dims[1], tp_n):
-            spec[1] = "model"
-        elif _divides(dims[1], dp_n):
-            spec[1] = dp
-        return out(spec)
-
-    if strategy == "moe_ep_dp":
-        # experts over `model` (EP); everything else ZeRO-sharded over dp,
-        # replicated over `model` (attention runs pure-DP)
-        spec = [None] * len(dims)
-        if (".w1" in pathstr or ".w3" in pathstr or ".w2" in pathstr) \
-                and len(dims) == 3:
-            if _divides(dims[0], tp_n):
-                spec[0] = "model"
-            rest = 1 if ".w2" not in pathstr else 2
-            if _divides(dims[rest], dp_n):
-                spec[rest] = dp
-            return out(spec)
-        if ".router" in pathstr and len(dims) == 2:
-            if _divides(dims[1], tp_n):
-                spec[1] = "model"
-            return out(spec)
-        order = sorted(range(len(dims)), key=lambda i: -dims[i])
-        for i in order:
-            if _divides(dims[i], dp_n):
-                spec[i] = dp
-                break
-        return out(spec)
-
-    if strategy == "fsdp":
-        # shard the largest dim divisible by the whole mesh; else by dp
-        spec = [None] * len(dims)
-        order = sorted(range(len(dims)), key=lambda i: -dims[i])
-        for i in order:
-            if _divides(dims[i], all_n):
-                spec[i] = all_ax
-                return out(spec)
-        for i in order:
-            if _divides(dims[i], dp_n):
-                spec[i] = dp
-                return out(spec)
-        return out(spec)
-
-    # ----- tp_fsdp: named Megatron rules + generic fallback -----
-    def col(d_in_idx: int, d_out_idx: int):
-        """column-parallel: out dim over model, in dim over dp (ZeRO-3)."""
-        spec = [None] * len(dims)
-        if _divides(dims[d_out_idx], tp_n):
-            spec[d_out_idx] = "model"
-        if _divides(dims[d_in_idx], dp_n):
-            spec[d_in_idx] = dp
-        return out(spec)
-
-    def row(d_in_idx: int, d_out_idx: int):
-        """row-parallel: in dim over model, out dim over dp."""
-        spec = [None] * len(dims)
-        if _divides(dims[d_in_idx], tp_n):
-            spec[d_in_idx] = "model"
-        if _divides(dims[d_out_idx], dp_n):
-            spec[d_out_idx] = dp
-        return out(spec)
-
-    if ".wq" in pathstr or ".wv" in pathstr or ".wk" in pathstr:
-        if "cross" in pathstr or len(dims) == 2:
-            return col(0, 1)
-    if ".wo" in pathstr and len(dims) == 2:
-        return row(0, 1)
-    if ".w1" in pathstr or ".w3" in pathstr:
-        if len(dims) == 2:
-            return col(0, 1)
-        if len(dims) == 3:     # MoE experts [E, d, eff]: EP over model
-            spec = [None, None, None]
-            if _divides(dims[0], tp_n):
-                spec[0] = "model"
-            if _divides(dims[1], dp_n):
-                spec[1] = dp
-            return out(spec)
-    if ".w2" in pathstr:
-        if len(dims) == 2:
-            return row(0, 1)
-        if len(dims) == 3:     # [E, eff, d]
-            spec = [None, None, None]
-            if _divides(dims[0], tp_n):
-                spec[0] = "model"
-            if _divides(dims[2], dp_n):
-                spec[2] = dp
-            return out(spec)
-    if ".router" in pathstr and len(dims) == 2:
-        return col(0, 1)
-    if "['embed']" in pathstr:
-        spec = [None, None]
-        if _divides(dims[0], tp_n):
-            spec[0] = "model"        # vocab-parallel embedding
-        if _divides(dims[1], dp_n):
-            spec[1] = dp
-        return out(spec)
-    if "['head']" in pathstr:
-        return col(0, 1)
-
-    # generic fallback (mamba in_proj/out_proj, xlstm projections, ...):
-    # last dim over model, largest other dim over dp
-    spec = [None] * len(dims)
-    if len(dims) >= 2:
-        if _divides(dims[-1], tp_n):
-            spec[-1] = "model"
-        rest = sorted(range(len(dims) - 1), key=lambda i: -dims[i])
-        for i in rest:
-            if _divides(dims[i], dp_n):
-                spec[i] = dp
-                break
-    return out(spec)
-
-
-def param_shardings(params: Any, cfg: ArchConfig, mesh: Mesh,
-                    strategy: str | None = None) -> Any:
-    strategy = strategy or strategy_for(cfg, mesh)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    specs = []
-    for path, leaf in flat:
-        pathstr = "".join(str(p) for p in path)
-        specs.append(NamedSharding(mesh, _spec_for_leaf(
-            pathstr, tuple(leaf.shape), strategy, mesh, cfg)))
-    return jax.tree_util.tree_unflatten(treedef, specs)
-
-
-# ---------------------------------------------------------------------------
-# activations / batch / cache shardings
-# ---------------------------------------------------------------------------
-
-def token_sharding(batch: int, mesh: Mesh, cfg: ArchConfig,
-                   strategy: str = "tp_fsdp") -> NamedSharding:
-    """Batch goes over the data axes; when the strategy does not use the
-    `model` axis for tensor parallelism (replicate/fsdp), the batch spreads
-    over it too (model axis would otherwise idle)."""
-    dp = dp_axes(mesh)
-    if strategy in ("replicate", "fsdp"):
-        allax = dp + ("model",)
-        if _divides(batch, axes_size(mesh, allax)):
-            return NamedSharding(mesh, P(allax, None))
-    b_spec = dp if _divides(batch, axes_size(mesh, dp)) else None
-    return NamedSharding(mesh, P(b_spec, None))
-
-
-def _seq_axes_for(seq: int, batch: int, mesh: Mesh):
-    """For decode caches: shard sequence over as much mesh as the batch
-    leaves unused (long_500k batch=1 -> sequence over the whole mesh)."""
-    dp = dp_axes(mesh)
-    if _divides(batch, axes_size(mesh, dp)):
-        return dp, ("model",) if _divides(seq, mesh.shape["model"]) else None
-    # batch unshardable: put everything on the sequence
-    allax = dp + ("model",)
-    if _divides(seq, axes_size(mesh, allax)):
-        return None, allax
-    return None, ("model",) if _divides(seq, mesh.shape["model"]) else None
-
-
-def cache_shardings(cache: Any, cfg: ArchConfig, mesh: Mesh, batch: int,
-                    seq_len: int) -> Any:
-    """Shardings for the serve-step cache pytree (built by eval_shape)."""
-    dp = dp_axes(mesh)
-    b_ax, s_ax = _seq_axes_for(seq_len, batch, mesh)
-
-    def spec(path, leaf) -> NamedSharding:
-        pathstr = "".join(str(p) for p in path)
-        shape = leaf.shape
-        pspec: list = [None] * len(shape)
-        # identify batch dim: first dim of size `batch` after the layer dim
-        for i, d in enumerate(shape):
-            if i == 0:
-                continue           # stacked layer dim
-            if d == batch and b_ax is not None:
-                pspec[i] = b_ax
-                break
-        if (pathstr.endswith(".k") or pathstr.endswith(".v")
-                or "win_" in pathstr or "cross_" in pathstr
-                or "sum_" in pathstr):
-            # KV-like tensors: shard their sequence/window/codebook dim
-            for i, d in enumerate(shape):
-                if i == 0 or pspec[i] is not None:
-                    continue
-                if d in (seq_len, cfg.vq_k, cfg.n_patches, cfg.enc_seq) \
-                        and s_ax is not None and _divides(
-                            d, axes_size(mesh, s_ax)):
-                    pspec[i] = s_ax
-                    break
-        return NamedSharding(mesh, P(*pspec))
-
-    flat, treedef = jax.tree_util.tree_flatten_with_path(cache)
-    return jax.tree_util.tree_unflatten(
-        treedef, [spec(p, l) for p, l in flat])
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

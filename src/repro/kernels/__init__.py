@@ -8,7 +8,5 @@ streaming codebook update, no one-hot intermediate), spmm_ell (ELLPACK
 message passing, VMEM-resident source), spmm_ell_hbm (ELLPACK message
 passing, HBM-resident source gathered by row DMAs),
 context_ell (one-pass multi-branch VQ-context SpMM -- Eq. 6 context
-forward and streaming Eq. 7 backward, codebook VMEM-resident),
-flash_attention (training attention), vq_attention (codebook + window
-decode attention).
+forward and streaming Eq. 7 backward, codebook VMEM-resident).
 """

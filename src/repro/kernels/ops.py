@@ -38,9 +38,6 @@ Production notes (TPU):
     uint8 (k <= 256) or nibble-packed ``PackedAssignment`` (k <= 16);
     every wrapper dispatches on the operand's type/dtype, never on the
     environment, so the tier choice happens once at state construction.
-  * ``flash_attention``: 32k+ sequences use a (bh, nq, nk) grid with carried
-    scratch instead of the resident-KV loop (the HBM SpMM kernel's
-    double-buffering idiom is the template; still TODO).
 """
 from __future__ import annotations
 
@@ -57,8 +54,6 @@ from repro.kernels.vq_update import vq_assign_update_pallas
 from repro.kernels.context_ell import context_ell_pallas, vmem_bytes
 from repro.kernels.spmm_ell import LANES, lane_tile, spmm_ell_pallas
 from repro.kernels.spmm_ell_hbm import spmm_ell_hbm_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.vq_attention import vq_attention_decode_pallas
 from repro.distributed.quantization import PackedAssignment, QTensor
 
 
@@ -487,21 +482,3 @@ def _context_ell_bwd(args, g):
 
 
 _context_ell_kernel.defvjp(_context_ell_fwd, _context_ell_bwd)
-
-
-def flash_attention(q, k, v, *, causal: bool = True):
-    if _use_pallas() and q.shape[2] % 128 == 0 and q.shape[-1] % 8 == 0:
-        return flash_attention_pallas(
-            q, k, v, causal=causal, interpret=interpret_mode())
-    return ref.flash_attention(q, k, v, causal=causal)
-
-
-def vq_attention_decode(q, cb_k, cb_v, mass, win_k, win_v, win_mask):
-    if _use_pallas():
-        return vq_attention_decode_pallas(
-            q, cb_k, cb_v, mass, win_k, win_v, win_mask,
-            interpret=interpret_mode())
-    return jax.vmap(
-        lambda qq, ck, cv, m, wk, wv, wm: ref.vq_attention_decode(
-            qq, ck, cv, m, wk, wv, wm)
-    )(q, cb_k, cb_v, mass, win_k, win_v, win_mask)

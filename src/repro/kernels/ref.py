@@ -108,54 +108,3 @@ def context_ell(out_ids: jax.Array, out_vals: jax.Array,
     if w_t is not None:
         out = out @ w_t.astype(jnp.float32)
     return out
-
-
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True,
-                    sm_scale: float | None = None) -> jax.Array:
-    """Plain softmax attention.  q: [b, h, sq, d], k/v: [b, h, skv, d]."""
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    s = jnp.einsum('bhqd,bhkd->bhqk', q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * sm_scale
-    if causal:
-        sq, skv = q.shape[2], k.shape[2]
-        qi = jnp.arange(sq)[:, None] + (skv - sq)
-        ki = jnp.arange(skv)[None, :]
-        s = jnp.where(ki <= qi, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum('bhqk,bhkd->bhqd', p, v.astype(jnp.float32)
-                      ).astype(q.dtype)
-
-
-def vq_attention_decode(q: jax.Array, cb_k: jax.Array, cb_v: jax.Array,
-                        mass: jax.Array, win_k: jax.Array, win_v: jax.Array,
-                        win_mask: jax.Array, *,
-                        sm_scale: float | None = None) -> jax.Array:
-    """One decode step of VQ-Attention (paper Eq. 6 on the token graph).
-
-    The out-of-window context is represented by ``k`` codewords with cluster
-    masses m_v; a cluster of m identical keys contributes m * exp(q.k~) =
-    exp(q.k~ + log m) to the softmax denominator -- exactly the paper's
-    row-normalization trick (App. E: pad a ones column, normalize after).
-
-    q:        [g, d]      (q heads sharing this KV group)
-    cb_k/v:   [k, d]      codeword keys / values
-    mass:     [k]         cluster sizes (float)
-    win_k/v:  [w, d]      exact recent window
-    win_mask: [w]         1.0 for valid window slots
-    returns   [g, d]
-    """
-    if sm_scale is None:
-        sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    q32 = q.astype(jnp.float32) * sm_scale
-    s_cb = q32 @ cb_k.astype(jnp.float32).T \
-        + jnp.log(jnp.maximum(mass, 1e-9))[None, :]        # [g, k]
-    s_cb = jnp.where(mass[None, :] > 0, s_cb, -jnp.inf)
-    s_w = q32 @ win_k.astype(jnp.float32).T                # [g, w]
-    s_w = jnp.where(win_mask[None, :] > 0, s_w, -jnp.inf)
-    s = jnp.concatenate([s_cb, s_w], axis=1)
-    p = jax.nn.softmax(s, axis=-1)
-    out = p[:, :cb_k.shape[0]] @ cb_v.astype(jnp.float32) \
-        + p[:, cb_k.shape[0]:] @ win_v.astype(jnp.float32)
-    return out.astype(q.dtype)

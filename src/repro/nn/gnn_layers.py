@@ -304,7 +304,7 @@ class GraphTransformer:
 
     Sampling methods cannot scale this (O(n^2) messages, no sparsity to
     sample); VQ-GNN reduces it to attention over b in-batch nodes + k
-    codewords (the same machinery the LM-side VQ-Attention uses).
+    codewords.
     Requires a full-width codebook (f_prod = f_in); see DESIGN.md.
     """
     name = "transformer"

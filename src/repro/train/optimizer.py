@@ -2,16 +2,15 @@
 
 Adam + RMSprop (the paper's App. E: the EMA-smoothed gradient statistics of
 VQ-GNN interact badly with Adam's cumulative moments -- RMSprop is the
-prescribed optimizer for VQ-GNN; Adam is used for the baselines), plus
-gradient clipping, weight decay, and LR schedules.
+prescribed optimizer for VQ-GNN; Adam is used for the baselines), each with
+a constant learning rate.
 
-States are pytrees mirroring the params, so they shard with the params under
-pjit (ZeRO-1/3 comes from the sharding rules, not from optimizer code --
-see repro/distributed/sharding.py).
+States are pytrees mirroring the params, so the data-parallel executors
+place them exactly as they place the params.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -30,70 +29,28 @@ class Optimizer(NamedTuple):
     update: Callable[[PyTree, OptState, PyTree], tuple[PyTree, OptState]]
 
 
-def global_norm(tree: PyTree) -> jax.Array:
-    return jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
-                        for x in jax.tree_util.tree_leaves(tree)))
-
-
-def clip_by_global_norm(tree: PyTree, max_norm: float) -> PyTree:
-    norm = global_norm(tree)
-    scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
-    return jax.tree_util.tree_map(lambda x: x * scale, tree)
-
-
 def _zeros_like_f32(tree: PyTree) -> PyTree:
     return jax.tree_util.tree_map(
         lambda x: jnp.zeros(x.shape, jnp.float32), tree)
 
 
-def warmup_cosine(base_lr: float, warmup: int, total: int,
-                  min_frac: float = 0.1) -> Callable[[jax.Array], jax.Array]:
-    def sched(step):
-        step = step.astype(jnp.float32)
-        warm = step / jnp.maximum(warmup, 1)
-        prog = jnp.clip((step - warmup) / jnp.maximum(total - warmup, 1),
-                        0.0, 1.0)
-        cos = min_frac + (1 - min_frac) * 0.5 * (1 + jnp.cos(jnp.pi * prog))
-        return base_lr * jnp.where(step < warmup, warm, cos)
-    return sched
-
-
-def constant_lr(base_lr: float) -> Callable[[jax.Array], jax.Array]:
-    return lambda step: jnp.asarray(base_lr, jnp.float32)
-
-
-def adam(lr: float | Callable = 1e-3, b1: float = 0.9, b2: float = 0.999,
-         eps: float = 1e-8, weight_decay: float = 0.0,
-         clip_norm: Optional[float] = None,
-         moment_dtype=jnp.float32) -> Optimizer:
-    """moment_dtype=bfloat16 halves optimizer HBM (the 405B-class configs
-    need it to fit a single pod; see EXPERIMENTS.md memory table)."""
-    sched = lr if callable(lr) else constant_lr(lr)
-
-    def _zeros(params):
-        return jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, moment_dtype), params)
-
+def adam(lr: float = 1e-3, b1: float = 0.9, b2: float = 0.999,
+         eps: float = 1e-8) -> Optimizer:
     def init(params):
-        return OptState(jnp.zeros((), jnp.int32), _zeros(params),
-                        _zeros(params))
+        return OptState(jnp.zeros((), jnp.int32), _zeros_like_f32(params),
+                        _zeros_like_f32(params))
 
     def update(grads, state, params):
-        if clip_norm is not None:
-            grads = clip_by_global_norm(grads, clip_norm)
         step = state.step + 1
         t = step.astype(jnp.float32)
-        lr_t = sched(step) * jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
+        lr_t = lr * jnp.sqrt(1 - b2 ** t) / (1 - b1 ** t)
 
         def upd(g, m, v, p):
             g32 = g.astype(jnp.float32)
-            m2 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
-            v2 = b2 * v.astype(jnp.float32) + (1 - b2) * g32 * g32
+            m2 = b1 * m + (1 - b1) * g32
+            v2 = b2 * v + (1 - b2) * g32 * g32
             delta = lr_t * m2 / (jnp.sqrt(v2) + eps)
-            if weight_decay and p.ndim >= 2:
-                delta = delta + sched(step) * weight_decay * p.astype(jnp.float32)
-            return ((p.astype(jnp.float32) - delta).astype(p.dtype),
-                    m2.astype(moment_dtype), v2.astype(moment_dtype))
+            return (p.astype(jnp.float32) - delta).astype(p.dtype), m2, v2
 
         # three passes (XLA CSEs the shared math); tuple-unzip via tree_map
         # is unsafe because NamedTuple params are themselves tuples
@@ -111,28 +68,20 @@ def adam(lr: float | Callable = 1e-3, b1: float = 0.9, b2: float = 0.999,
     return Optimizer(init, update)
 
 
-def rmsprop(lr: float | Callable = 3e-3, alpha: float = 0.99,
-            eps: float = 1e-8, weight_decay: float = 0.0,
-            clip_norm: Optional[float] = None) -> Optimizer:
+def rmsprop(lr: float = 3e-3, alpha: float = 0.99,
+            eps: float = 1e-8) -> Optimizer:
     """RMSprop(alpha=0.99), the paper's optimizer for VQ-GNN (App. F)."""
-    sched = lr if callable(lr) else constant_lr(lr)
-
     def init(params):
         return OptState(jnp.zeros((), jnp.int32), _zeros_like_f32(params),
                         _zeros_like_f32(params))
 
     def update(grads, state, params):
-        if clip_norm is not None:
-            grads = clip_by_global_norm(grads, clip_norm)
         step = state.step + 1
-        lr_t = sched(step)
 
         def upd(g, v, p):
             g32 = g.astype(jnp.float32)
             v2 = alpha * v + (1 - alpha) * g32 * g32
-            delta = lr_t * g32 / (jnp.sqrt(v2) + eps)
-            if weight_decay and p.ndim >= 2:
-                delta = delta + lr_t * weight_decay * p.astype(jnp.float32)
+            delta = lr * g32 / (jnp.sqrt(v2) + eps)
             return (p.astype(jnp.float32) - delta).astype(p.dtype), v2
 
         new_p = jax.tree_util.tree_map(
@@ -142,6 +91,3 @@ def rmsprop(lr: float | Callable = 3e-3, alpha: float = 0.99,
         return new_p, OptState(step, state.mu, new_v)
 
     return Optimizer(init, update)
-
-
-OPTIMIZERS = {"adam": adam, "rmsprop": rmsprop}

@@ -118,7 +118,7 @@ def test_repro002_one_hot_in_hot_module():
     assert _rules(_sub_findings(src, "src/repro/core/codebook.py")) == \
         {"REPRO002"}
     # fine outside the hot modules
-    assert _sub_findings(src, "src/repro/nn/ffn.py") == []
+    assert _sub_findings(src, "src/repro/graph/datasets.py") == []
 
 
 def test_repro002_one_hot_inside_kernel_body_allowed():

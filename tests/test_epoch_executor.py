@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 from numpy.testing import assert_allclose
 
+from repro.configs.scenarios import MATRIX_BACKBONES
 from repro.core.codebook import CodebookConfig
 from repro.core.conv import init_layer_vq_state
 from repro.graph.batching import (build_epoch_plan, epoch_slices,
@@ -41,9 +42,8 @@ def g():
     return synthetic_arxiv(n=300, seed=0)
 
 
-@pytest.fixture(scope="module")
-def setup(g):
-    cfg = GNNConfig(backbone="gcn", f_in=g.f, hidden=32,
+def _setup(g, backbone):
+    cfg = GNNConfig(backbone=backbone, f_in=g.f, hidden=32,
                     n_out=g.num_classes, n_layers=2,
                     codebook=CodebookConfig(k=32, f_prod=4))
     ops = full_operands(g)
@@ -56,6 +56,20 @@ def setup(g):
                 labels=jnp.asarray(g.labels), tm_np=tm,
                 tm=jnp.asarray(tm), params=params, vq=vq, opt=opt,
                 ost=opt.init(params), plan=build_epoch_plan(g))
+
+
+@pytest.fixture(scope="module", params=MATRIX_BACKBONES)
+def setup(g, request):
+    """Every backbone, for the tests whose subject is a training path."""
+    return _setup(g, request.param)
+
+
+@pytest.fixture(scope="module")
+def gcn_setup(g):
+    """GCN only, for the tests whose subject has no backbone (plans, mesh
+    rejection) and for the two-device parity, whose subprocess runs one
+    case."""
+    return _setup(g, "gcn")
 
 
 # ---------------------------------------------------------------------------
@@ -89,17 +103,17 @@ def test_pack_rows_empty_graph():
     assert nbr.shape == (4, 3) and not mask.any() and (pos == -1).all()
 
 
-def test_plan_batch_matches_make_pack(g, setup):
+def test_plan_batch_matches_make_pack(g, gcn_setup):
     ids = np.random.default_rng(0).permutation(g.n)[:64]
     host = make_pack(g, ids)
-    jit_pack = jax.jit(plan_batch)(setup["plan"],
+    jit_pack = jax.jit(plan_batch)(gcn_setup["plan"],
                                    jnp.asarray(ids.astype(np.int32)))
     for name in PACK_FIELDS:
         assert np.array_equal(np.asarray(getattr(host, name)),
                               np.asarray(getattr(jit_pack, name))), name
 
 
-def test_plan_batch_duplicate_ids_take_first_slot(g, setup):
+def test_plan_batch_duplicate_ids_take_first_slot(g, gcn_setup):
     """Serve requests repeat ids: the host packer, the in-jit scatter and
     the sharded executor's sort-based positions all send a duplicated
     node's messages to its FIRST slot (the slot decides where a message
@@ -111,7 +125,7 @@ def test_plan_batch_duplicate_ids_take_first_slot(g, setup):
     ids = np.concatenate([ids, ids[rng.integers(0, 48, 16)]])
     rng.shuffle(ids)
     host = make_pack(g, ids)
-    jit_pack = jax.jit(plan_batch)(setup["plan"],
+    jit_pack = jax.jit(plan_batch)(gcn_setup["plan"],
                                    jnp.asarray(ids.astype(np.int32)))
     for name in PACK_FIELDS:
         assert np.array_equal(np.asarray(getattr(host, name)),
@@ -204,14 +218,15 @@ def test_scan_epoch_matches_per_step_loop(g, setup):
         assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=1e-6)
 
 
-def test_trainer_rejects_mesh_without_epoch_executor(g, setup, monkeypatch):
+def test_trainer_rejects_mesh_without_epoch_executor(g, gcn_setup,
+                                                     monkeypatch):
     """An explicit data-parallel request must never silently fall back to
     single-device training."""
     from repro.distributed.data_parallel import graph_dp_mesh
     from repro.train.gnn_trainer import train_vq
     monkeypatch.setenv("REPRO_EPOCH_EXECUTOR", "0")
     with pytest.raises(ValueError, match="epoch executor"):
-        train_vq(g, setup["cfg"], epochs=1, batch_size=128,
+        train_vq(g, gcn_setup["cfg"], epochs=1, batch_size=128,
                  mesh=graph_dp_mesh(1))
 
 
@@ -322,7 +337,7 @@ def test_graph_dp_mesh_rejects_overprovisioning():
 @pytest.mark.skipif(len(jax.devices()) < 2,
                     reason="needs >= 2 devices (XLA_FLAGS="
                     "--xla_force_host_platform_device_count=2)")
-def test_dp_two_device_mesh_matches_vmap_oracle(g, setup):
+def test_dp_two_device_mesh_matches_vmap_oracle(g, gcn_setup):
     """shard_map over a 2-device mesh == the same body under
     jax.vmap(axis_name=...): all cross-replica math (grad psum, codebook
     stats psum, assignment all_gather) agrees with the collective-free
@@ -330,7 +345,7 @@ def test_dp_two_device_mesh_matches_vmap_oracle(g, setup):
     from repro.distributed.data_parallel import (graph_dp_mesh,
                                                  vq_train_epoch_dp)
     from repro.models.gnn import _vq_epoch_body
-    s = setup
+    s = gcn_setup
     bids, smask = epoch_slices(
         np.random.default_rng(7).permutation(g.n), 128)
     bids_d = jnp.asarray(bids.astype(np.int32))

@@ -124,9 +124,9 @@ def test_dtype_nbits_sub_byte_and_hlo_names():
     assert dtype_nbits(jnp.uint8) == 8
     assert dtype_nbits(FP8) == 8
     assert dtype_nbits(jnp.float32) == 32
-    assert dtype_nbits("f8e4m3fn") == 8     # HLO short names (dryrun)
-    assert dtype_nbits("u4") == 4
-    assert dtype_nbits("pred") == 8
+    assert dtype_nbits("float8_e4m3fn") == 8     # dtype names
+    assert dtype_nbits("uint4") == 4
+    assert dtype_nbits("bool") == 8
 
 
 # ---------------------------------------------------------------------------
@@ -385,7 +385,6 @@ def test_quantize_vq_states_tiers_and_guards():
 # ---------------------------------------------------------------------------
 
 def test_gather_from_shards_fp8_bit_exact():
-    from functools import partial
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.collectives import gather_from_shards

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 from numpy.testing import assert_allclose
 
+from repro.configs.scenarios import MATRIX_BACKBONES
 from repro.core import codebook as cbm
 from repro.core.codebook import CodebookConfig
 from repro.graph.batching import (build_epoch_plan, epoch_slices,
@@ -29,9 +30,8 @@ def g():
     return synthetic_arxiv(n=300, seed=0)
 
 
-@pytest.fixture(scope="module")
-def setup(g):
-    cfg = GNNConfig(backbone="gcn", f_in=g.f, hidden=32,
+def _setup(g, backbone):
+    cfg = GNNConfig(backbone=backbone, f_in=g.f, hidden=32,
                     n_out=g.num_classes, n_layers=2,
                     codebook=CodebookConfig(k=32, f_prod=4))
     ops = full_operands(g)
@@ -39,6 +39,19 @@ def setup(g):
                 params=init_gnn(jax.random.PRNGKey(0), cfg),
                 vq=init_vq_states(jax.random.PRNGKey(1), cfg, g.n),
                 plan=build_epoch_plan(g, full_ops=ops))
+
+
+@pytest.fixture(scope="module", params=MATRIX_BACKBONES)
+def setup(g, request):
+    """Every backbone, for the tests whose subject is an inference or
+    serving path."""
+    return _setup(g, request.param)
+
+
+@pytest.fixture(scope="module")
+def gcn_setup(g):
+    """GCN only, for the tests whose subject has no backbone."""
+    return _setup(g, "gcn")
 
 
 def _both_paths(g, setup, batch, monkeypatch, **kw):
@@ -221,14 +234,14 @@ def test_gnn_server_serve_and_drain(g, setup):
     assert rep["request_p99_ms"] >= rep["request_p50_ms"]
 
 
-def test_gnn_server_rejects_indivisible_mesh(g, setup):
+def test_gnn_server_rejects_indivisible_mesh(g, gcn_setup):
     from repro.launch.serve_gnn import GNNServer
 
     class _StubMesh:
         shape = {"data": 2}
     with pytest.raises(ValueError, match="divisible"):
-        GNNServer(g, setup["cfg"], setup["params"], setup["vq"],
-                  batch=33, mesh=_StubMesh())
+        GNNServer(g, gcn_setup["cfg"], gcn_setup["params"],
+                  gcn_setup["vq"], batch=33, mesh=_StubMesh())
 
 
 # ---------------------------------------------------------------------------

@@ -14,10 +14,8 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.spmm_ell import spmm_ell_pallas
 from repro.kernels.vq_assign import vq_assign_pallas
-from repro.kernels.vq_attention import vq_attention_decode_pallas
 
 
 # ---------------------------------------------------------------------------
@@ -86,74 +84,3 @@ def test_spmm_ell_padding_zero_vals():
     got = spmm_ell_pallas(idx, val, x, interpret=True)
     want = jnp.stack([x[5], 0.5 * x[2]])
     assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# flash_attention
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("b,h,s,d", [(1, 1, 128, 32), (2, 3, 256, 64),
-                                     (1, 2, 512, 128)])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_sweep(b, h, s, d, causal):
-    key = jax.random.PRNGKey(s + d)
-    q, k, v = (jax.random.normal(kk, (b, h, s, d))
-               for kk in jax.random.split(key, 3))
-    got = flash_attention_pallas(q, k, v, causal=causal, bq=128, bk=128,
-                                 interpret=True)
-    want = ref.flash_attention(q, k, v, causal=causal)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
-
-
-def test_flash_attention_bf16():
-    key = jax.random.PRNGKey(0)
-    q, k, v = (jax.random.normal(kk, (1, 2, 256, 64), jnp.bfloat16)
-               for kk in jax.random.split(key, 3))
-    got = flash_attention_pallas(q, k, v, causal=True, bq=128, bk=128,
-                                 interpret=True)
-    want = ref.flash_attention(q, k, v, causal=True)
-    assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
-                    rtol=5e-2, atol=5e-2)
-
-
-# ---------------------------------------------------------------------------
-# vq_attention decode
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("n,g,d,kcb,w", [(1, 1, 8, 4, 4), (4, 2, 32, 16, 8),
-                                         (6, 4, 64, 128, 32)])
-def test_vq_attention_decode_sweep(n, g, d, kcb, w):
-    key = jax.random.PRNGKey(n * 17 + kcb)
-    ks = jax.random.split(key, 6)
-    q = jax.random.normal(ks[0], (n, g, d))
-    cbk = jax.random.normal(ks[1], (n, kcb, d))
-    cbv = jax.random.normal(ks[2], (n, kcb, d))
-    mass = jnp.abs(jax.random.normal(ks[3], (n, kcb))) + 0.1
-    wk = jax.random.normal(ks[4], (n, w, d))
-    wv = jax.random.normal(ks[5], (n, w, d))
-    wm = jnp.ones((n, w))
-    got = vq_attention_decode_pallas(q, cbk, cbv, mass, wk, wv, wm,
-                                     interpret=True)
-    want = jax.vmap(lambda *a: ref.vq_attention_decode(*a))(
-        q, cbk, cbv, mass, wk, wv, wm)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
-
-
-def test_vq_attention_decode_masked_window():
-    """Masked window slots and zero-mass clusters must not contribute."""
-    n, g, d, kcb, w = 2, 2, 16, 8, 4
-    key = jax.random.PRNGKey(3)
-    ks = jax.random.split(key, 6)
-    q = jax.random.normal(ks[0], (n, g, d))
-    cbk = jax.random.normal(ks[1], (n, kcb, d))
-    cbv = jax.random.normal(ks[2], (n, kcb, d))
-    mass = jnp.zeros((n, kcb)).at[:, 0].set(2.0)
-    wk = jax.random.normal(ks[4], (n, w, d))
-    wv = jax.random.normal(ks[5], (n, w, d))
-    wm = jnp.zeros((n, w)).at[:, 0].set(1.0)
-    got = vq_attention_decode_pallas(q, cbk, cbv, mass, wk, wv, wm,
-                                     interpret=True)
-    want = jax.vmap(lambda *a: ref.vq_attention_decode(*a))(
-        q, cbk, cbv, mass, wk, wv, wm)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
-    assert np.isfinite(np.asarray(got)).all()

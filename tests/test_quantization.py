@@ -1,14 +1,9 @@
-"""int8 weight-only serving quantization: accuracy + size contracts."""
+"""int8 per-channel tensor quantization (``quantize_tensor``): accuracy
+contracts."""
 import jax
 import jax.numpy as jnp
-import numpy as np
-from numpy.testing import assert_allclose
 
-from repro.configs.registry import get_smoke
-from repro.distributed.quantization import (QTensor, dequantize_tree,
-                                            quantize_tensor, quantize_tree,
-                                            tree_bytes)
-from repro.models.lm import init_lm, init_serve_cache, serve_step
+from repro.distributed.quantization import quantize_tensor
 
 
 def test_tensor_roundtrip_error_bounded():
@@ -27,24 +22,3 @@ def test_matmul_relative_error_small():
     yq = x @ (t.q.astype(jnp.float32) * t.scale)
     rel = float(jnp.abs(y - yq).mean() / jnp.abs(y).mean())
     assert rel < 0.01, rel
-
-
-def test_params_tree_halves_and_serves():
-    cfg = get_smoke("granite-3-8b")
-    params = init_lm(jax.random.PRNGKey(0), cfg)
-    qparams = quantize_tree(params)
-    # >= 2D weights dominate: int8 + scales < 55% of f32 original
-    assert tree_bytes(jax.tree_util.tree_map(
-        lambda t: t.q if isinstance(t, QTensor) else t, qparams,
-        is_leaf=lambda x: isinstance(x, QTensor))) < \
-        0.55 * tree_bytes(params)
-    deq = dequantize_tree(qparams, jnp.float32)
-    # serving path runs unmodified on dequantized weights with close logits
-    cache = init_serve_cache(cfg, 2, 32)
-    tok = jnp.zeros((2, 1), jnp.int32)
-    l0, _ = serve_step(params, tok, cache, cfg)
-    l1, _ = serve_step(deq, tok, cache, cfg)
-    top_match = (jnp.argsort(l0, -1)[:, -5:] ==
-                 jnp.argsort(l1, -1)[:, -5:]).mean()
-    assert float(top_match) > 0.7
-    assert_allclose(np.asarray(l0), np.asarray(l1), rtol=0.3, atol=0.3)

@@ -43,16 +43,24 @@ def g():
     return synthetic_arxiv(n=300, seed=0)
 
 
-@pytest.fixture(scope="module")
-def cfg(g):
-    return GNNConfig(backbone="gcn", f_in=g.f, hidden=32,
+def _cfg(g, backbone):
+    return GNNConfig(backbone=backbone, f_in=g.f, hidden=32,
                      n_out=g.num_classes, n_layers=2,
                      codebook=CodebookConfig(k=32, f_prod=4))
 
 
+@pytest.fixture(scope="module")
+def cfg(g):
+    return _cfg(g, "gcn")
+
+
+# The transformer is left out: its two paths disagree by up to 3.4e-3
+# relative on the epoch-1 loss, on every sampler (ROADMAP.md, design debts).
+@pytest.mark.parametrize("backbone", ("gcn", "sage", "gat", "gin"))
 @pytest.mark.parametrize("method", SAMPLER_METHODS)
-def test_executor_matches_host_loop(g, cfg, method, monkeypatch):
+def test_executor_matches_host_loop(g, backbone, method, monkeypatch):
     """Same rng -> identical loss trace and final params on both paths."""
+    cfg = _cfg(g, backbone)
     kw = dict(epochs=2, batch_size=64, eval_every=2, seed=5)
     if method == "cluster-gcn":
         kw["n_parts"] = 8

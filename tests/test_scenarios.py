@@ -1,7 +1,6 @@
-"""Scenario-matrix registry tests (ISSUE 6): pin the GNN backbone set,
-pin the scale-method axis, and enforce the LM-config quarantine -- the
-llama/whisper/moe seeds of ``configs.registry`` must never enumerate as
-matrix cells."""
+"""Scenario-matrix registry tests: pin the GNN backbone set and the
+scale-method axis, and run every backbone through the ``train_scenario``
+dispatch front."""
 import pytest
 
 from repro.configs.scenarios import (MATRIX_BACKBONES, MATRIX_TASKS,
@@ -30,20 +29,9 @@ def test_matrix_cells_enumerate_gnn_only():
     cells = matrix_cells(tasks=("node",))
     assert len(cells) == len(MATRIX_BACKBONES) * len(SCALE_METHODS)
     backbones = {b for b, _, _ in cells}
-    assert_gnn_only(backbones)            # no LM arch ids leaked
-
-
-def test_lm_archs_quarantined():
-    """Every id of the generic LM/speech/vision registry must FAIL the
-    GNN-only guard -- the quarantine the scenario matrix depends on."""
-    from repro.configs.registry import ARCHS, LM_ARCHS
-    assert ARCHS is LM_ARCHS              # back-compat alias intact
-    assert len(LM_ARCHS) >= 10
-    for name in LM_ARCHS:
-        with pytest.raises(ValueError, match="leaked|unknown"):
-            assert_gnn_only([name])
-    # and none of them collides with a GNN backbone name
-    assert not set(LM_ARCHS) & set(MATRIX_BACKBONES)
+    assert_gnn_only(backbones)            # no unknown backbone
+    with pytest.raises(ValueError, match="unknown"):
+        assert_gnn_only(["gcn", "mlp"])
 
 
 def test_knobs_documented():
@@ -53,15 +41,16 @@ def test_knobs_documented():
         assert knob in SCENARIO_KNOBS
 
 
-def test_train_scenario_smoke():
+@pytest.mark.parametrize("backbone", MATRIX_BACKBONES)
+def test_train_scenario_smoke(backbone):
     """One tiny end-to-end cell per trainer family through the dispatch
-    front (full / vq / one sampler / hybrid)."""
+    front (full / vq / one sampler / hybrid), for every backbone."""
     from repro.graph.datasets import synthetic_arxiv
     from repro.models.gnn import GNNConfig
     from repro.core.codebook import CodebookConfig
     from repro.train.gnn_trainer import train_scenario
     g = synthetic_arxiv(n=200, seed=0)
-    cfg = GNNConfig(backbone="gcn", f_in=g.f, hidden=16,
+    cfg = GNNConfig(backbone=backbone, f_in=g.f, hidden=16,
                     n_out=g.num_classes, n_layers=2,
                     codebook=CodebookConfig(k=16, f_prod=4))
     for method in ("full", "vq", "saint", "hybrid"):

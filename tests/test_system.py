@@ -1,6 +1,5 @@
-"""End-to-end behaviour tests: the paper's training regimes learn, the LM
-stack learns, VQ inference agrees with exact inference."""
-import jax
+"""End-to-end behaviour tests: the paper's training regimes learn and VQ
+inference agrees with exact inference."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -74,21 +73,3 @@ def test_link_prediction_path():
                     codebook=CodebookConfig(k=64, f_prod=4))
     r = train_vq(g, cfg, epochs=15, batch_size=400, eval_every=15)
     assert r["final"]["val"] > 0.1    # hits@50 well above random
-
-
-def test_lm_training_loss_decreases():
-    from repro.configs.registry import get_smoke
-    from repro.train.loop import train
-    cfg = get_smoke("granite-3-8b")
-    out = train(cfg, steps=80, batch=8, seq_len=64, lr=3e-3, log_every=20)
-    losses = [h["loss"] for h in out["history"]]
-    assert losses[-1] < losses[0] - 0.4, losses
-
-
-def test_lm_vq_attention_training_loss_decreases():
-    from repro.configs.registry import get_smoke
-    from repro.train.loop import train
-    cfg = get_smoke("granite-3-8b").with_vq(k=16, window=16)
-    out = train(cfg, steps=80, batch=8, seq_len=64, lr=3e-3, log_every=20)
-    losses = [h["loss"] for h in out["history"]]
-    assert losses[-1] < losses[0] - 0.4, losses

@@ -168,10 +168,12 @@ def test_vq_assign_update_compiles(sds, k, emit):
         sds((NB, B, 2 * F_BLK)), sds((NB, k, 2 * F_BLK))))
 
 
-def test_training_step_grad_compiles(sds, kernels_on):
-    """jax.grad of one Alg. 1 step at the paper's widths, kernels on: the
-    SpMM and context custom VJPs stand in for the transpose rule a
-    pallas_call lacks (a smaller graph keeps the compile short)."""
+@pytest.mark.parametrize("backbone", ("gcn", "sage"))
+def test_training_step_grad_compiles(sds, kernels_on, backbone):
+    """jax.grad of one Alg. 1 step at the paper's widths, kernels on, for
+    both of the benchmark's training backbones: the SpMM and context
+    custom VJPs stand in for the transpose rule a pallas_call lacks (a
+    smaller graph keeps the compile short)."""
     from repro.core.codebook import CodebookConfig
     from repro.core.conv import MinibatchPack
     from repro.models.gnn import (GNNConfig, _vq_step_body, init_gnn,
@@ -179,7 +181,7 @@ def test_training_step_grad_compiles(sds, kernels_on):
     from repro.train.optimizer import rmsprop
 
     n, b = 8192, 2048
-    cfg = GNNConfig(backbone="gcn", f_in=128, hidden=128, n_out=40,
+    cfg = GNNConfig(backbone=backbone, f_in=128, hidden=128, n_out=40,
                     n_layers=3, codebook=CodebookConfig(k=K, f_prod=4))
     opt = rmsprop(3e-3)
     key = jax.random.PRNGKey(0)
